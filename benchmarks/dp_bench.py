@@ -94,6 +94,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import stats as stats_mod
 from repro.core.dp import build_tables, solve_budgeted_dp
 from repro.core.incremental import solve_budgeted_dp_warm, warm_carry_init
@@ -766,6 +767,7 @@ def main() -> None:
     ap.add_argument("--max-regression", type=float, default=2.0,
                     help="fail when mean_ms exceeds baseline by this factor")
     args = ap.parse_args()
+    enable_compile_cache()
     configs = ([c for c in CONFIGS if c["name"] in SMOKE_NAMES]
                if args.smoke else CONFIGS)
     if args.smoke:  # CI sizes: keep only the B=8 fleet leg

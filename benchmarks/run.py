@@ -12,6 +12,8 @@ from __future__ import annotations
 
 import sys
 
+from repro.compile_cache import enable_compile_cache
+
 from . import asw, overhead, roofline_bench, scenarios_bench, sensitivity
 
 ALL = [
@@ -30,6 +32,7 @@ ALL = [
 
 
 def main() -> None:
+    enable_compile_cache()
     smoke = "--smoke" in sys.argv
     filters = [a for a in sys.argv[1:] if not a.startswith("-")]
     rows: list[tuple] = []

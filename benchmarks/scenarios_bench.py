@@ -40,6 +40,7 @@ import pathlib
 import sys
 import time
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.baselines import (hswf_factory, msr_greedy_factory,
                                   msr_index_factory)
 from repro.core.esdp import esdp_factory
@@ -430,6 +431,7 @@ def main() -> None:
                          "the ~100k-arrival horizon the 5x acceptance bar "
                          "targets — refreshes results/BENCH_engine.json")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.fault_smoke:
         rec = fault_injection_check()
         if rec["rate"] <= 0.0:

@@ -7,10 +7,12 @@ degrade mid-run (straggler brownout) — ESDP learns and routes around it.
 """
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.sched import ClusterSim, JobType, Slice, build_instance, rate_matrix
 
 
 def main():
+    enable_compile_cache()
     slices = [
         Slice("pod-a", "v5e", 256, 32, 4),
         Slice("pod-b", "v5e", 256, 32, 4),

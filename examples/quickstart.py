@@ -8,6 +8,7 @@ social welfare + regret — the headline numbers of Fig. 2.
 """
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import (build_tables, generate_instance, make_esdp_policy,
                         make_hswf_policy, make_lcf_policy, make_lwtf_policy,
                         simulate)
@@ -15,6 +16,7 @@ from repro.core.stats import g_logt_only
 
 
 def main():
+    enable_compile_cache()
     inst = generate_instance(seed=0)          # |L|=8, |R|=40, Table-2 defaults
     tables = build_tables(inst.A, inst.c)
     T = 2000

@@ -11,6 +11,7 @@ Demonstrates the two levels of batching in ``repro.experiments``:
 """
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import build_tables, generate_instance
 from repro.core.baselines import hswf_factory
 from repro.core.esdp import esdp_factory
@@ -23,6 +24,7 @@ SEEDS = (0, 1, 2)
 
 
 def main():
+    enable_compile_cache()
     # -- 1. registry sweep: every named regime, one spec each ---------------
     # paper-literal HSWF (tiebreak=0), as in the paper's Fig.-2 comparison
     policies = {"esdp": esdp_factory(g_fn=g_logt_only),

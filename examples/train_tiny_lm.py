@@ -6,11 +6,13 @@ mid-run failure + restart — the fault-tolerance path, end to end.
 """
 import jax
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config
 from repro.launch.train import main as train_main
 
 
 def main():
+    enable_compile_cache()
     cfg = get_config("qwen2.5-32b", reduced=True).replace(
         n_layers=8, d_model=512, n_heads=8, n_kv_heads=4, head_dim=64,
         d_ff=2048, vocab=8192)

@@ -140,8 +140,9 @@ class Solver:
         u_max: int | None = None,
     ):
         """``u_max`` is an optional static bound on max Υ̂ (e.g. from
-        ``stats.u_max_for_horizon``); the Pallas backends use it to shrink
-        the kernel's shift scratch, the reference backend ignores it.
+        ``stats.u_max_for_horizon``); the Pallas backends clamp shifts to
+        it and size their tiled halos from it, the reference backend
+        ignores it.
 
         Backends with ``accepts_batch`` carry a custom batching rule on
         the solve core: ``jax.vmap`` of this call dispatches all mapped

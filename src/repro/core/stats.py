@@ -113,14 +113,14 @@ def _delta_at_host(T: int, delta_fn=delta_default) -> float:
     """δ(T) evaluated host-side in float64.
 
     Registered schedules use their pure-``math`` mirrors; custom schedules
-    are evaluated under ``jax.experimental.enable_x64`` so a python-int
+    are evaluated under ``jax.enable_x64(True)`` so a python-int
     horizon survives intact (``jnp.float32(T)`` is exact only below 2²⁴ —
     the old f32 path made the T ↦ ξ(T) map constant across ≈ 2¹⁷-wide
     plateaus near t_max and mislocated every threshold inside one)."""
     host = _DELTA_HOST.get(delta_fn)
     if host is not None:
         return host(float(T))
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         return float(delta_fn(jnp.float64(T)))
 
 

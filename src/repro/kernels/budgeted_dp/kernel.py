@@ -1,25 +1,31 @@
 """Pallas TPU kernel for the ESDP budgeted DP (paper Algorithm 2).
 
 TPU-native design (DESIGN.md §4):
-  * the whole (S × C) value plane lives in VMEM (default sizes ≈ 80 KB);
+  * the whole (S × C) value plane lives in VMEM, padded to whole (8, 128)
+    vreg tiles (S to a multiple of 8 sublanes, C to a multiple of 128
+    lanes) so every vector op and every shift acts on native tiles;
   * the edge loop runs INSIDE one pallas_call via fori_loop;
-  * BOTH per-edge gathers are uniform shifts read from one padded VMEM
-    scratch with a dynamic-START static-SIZE slice (pl.ds) — no gather op
-    and no matmul at all:
-      - the s-shift V[max(s−Υ_e, 0)] shifts along the budget (sublane) axis
-        through U_MAX clamp rows holding V[0];
+  * BOTH per-edge gathers are uniform shifts done in registers with
+    ``pltpu.roll`` by the edge's dynamic distance, plus a mask — no gather
+    op, no matmul, and no unaligned dynamic slice (the TPU compiler only
+    accepts dynamic sublane/lane offsets it can prove tile-aligned):
       - the capacity transition next(c) = c − offset_e (the mixed-radix
-        offset identity validated in core.dp.build_tables) shifts along the
-        state (lane) axis through OFF_MAX pad columns; reads landing in the
-        pad are exactly the states with c < offset_e, which are infeasible
-        and masked to NEG.
+        offset identity validated in core.dp.build_tables) rolls the plane
+        right along the state (lane) axis; columns c < offset_e wrap
+        around, and those are exactly the states that cannot take edge e —
+        infeasible and masked to NEG;
+      - the s-shift V[max(s−Υ_e, 0)] rolls the lane-shifted plane down the
+        budget (sublane) axis, and rows s < Υ_e (which wrapped) take the
+        clamp row V[0] instead.
     The former (E, C, C) one-hot transition operand — 4·E·C² bytes and an
-    O(S·C²) MXU matmul per edge — is now an (E,) int32 offset vector and an
+    O(S·C²) MXU matmul per edge — is an (E,) int32 offset vector and an
     O(S·C) VPU update, which is what lets large capacity spaces fit VMEM;
   * backtrack decisions are BIT-PACKED into int32 lanes: word ⌊e/32⌋ of the
     (⌈E/32⌉, S, C) output holds bit (e mod 32) for edge e.  At production
     sizes the unpacked (E, S, C) f32 tensor dominated VMEM (E=64, S=512,
     C=256 ⇒ 32 MB — over the ~16 MB/core budget); packing is 32× smaller.
+  * per-edge scalars (Υ̂, Σ̂², offsets, eligibility) live in SMEM and are
+    read one element at a time.
 
 When even the (S, C) value plane outgrows VMEM, ``block_c`` switches to a
 C-BLOCKED pipeline: a lax.scan over edges, each edge one pallas_call gridded
@@ -35,36 +41,38 @@ pipeline to a 2-D (S-tile × C-tile) grid.  The s-shift only ever reads UP
 (towards smaller budgets, by at most u_max ≤ block_s rows), so each tile
 needs an up-neighbor halo of u_max rows on top of the left-neighbor halo —
 four BlockSpec views of the same plane per grid step ((i−1, j−1), (i−1, j),
-(i, j−1), (i, j)) assembled into one (u_max + block_s, 2·block_c) scratch.
-Tile row 0 has no up neighbor and replicates the plane's clamp row V[0]
-instead, exactly like the whole-plane kernel's clamp rows.  Per-tile VMEM
-is then independent of BOTH plane extents, which is what lets S ≳ 4096
-with large C run at all; ``choose_tiling`` picks the largest (block_s,
-block_c) pair that fits the VMEM budget.
+(i, j−1), (i, j)).  Both shifts roll the tile and its halo by the same
+distance and splice them with a mask (:func:`_lane_shift`,
+:func:`_row_shift`).  Tile row 0 has no up neighbor and replicates the
+plane's clamp row V[0] instead, exactly like the whole-plane kernel.
+Per-tile VMEM is then independent of BOTH plane extents, which is what lets
+S ≳ 4096 with large C run at all; ``choose_tiling`` picks the largest
+(block_s, block_c) pair that fits the VMEM budget.
 
 ``block_e`` FUSES the edge loop into that grid — a temporal blocking of
 the DP recurrence.  The per-edge-scan pipelines above re-stream the whole
 value plane HBM↔VMEM once per edge; the fused pipeline runs one
 pallas_call per chunk of ``block_e`` consecutive edges, and each tile stays
-VMEM-resident (in the shift scratch's body region) across the whole chunk,
-cutting plane traffic ``block_e``-fold.  The price is the halo: by the
-time tile (i, j) runs, its up/left neighbors have already advanced through
-ALL ``block_e`` edges of the chunk, so their boundary values at each
-*intermediate* edge must be preserved.  Two persistent VMEM scratch
-buffers carry exactly that history across grid steps (the TPU grid is
-sequential, and scratch survives grid iterations):
+VMEM-resident across the whole chunk, cutting plane traffic
+``block_e``-fold.  The price is the halo: by the time tile (i, j) runs,
+its up/left neighbors have already advanced through ALL ``block_e`` edges
+of the chunk, so their boundary values at each *intermediate* edge must be
+preserved.  Two persistent VMEM scratch buffers carry exactly that history
+across grid steps (the TPU grid is sequential, and scratch survives grid
+iterations):
 
-  * ``lefth`` — (block_e, block_s, off_max): the last off_max columns of
-    the previous C-tile *before* each edge of the chunk.  Each tile reads
-    its left halo for edge k from ``lefth[k]``, then overwrites it with
-    its own pre-edge-k boundary for the next tile (read-then-write within
-    one grid step, so a single buffer suffices along C);
-  * ``rowh`` — (2 × block_e, u_max, C_padded): the bottom u_max rows of
-    every tile of the previous S-row, per edge, double-banked by S-row
-    parity — tile (i, j) reads bank (i−1) mod 2 (up halo at columns of
-    tiles j−1 and j, the j−1 part being the up-left corner) and writes
-    bank i mod 2, so row i's writes never clobber the corner history row
-    i+1 still needs.
+  * ``lefth`` — (block_e, block_s, LW): the last LW columns of the
+    previous C-tile *before* each edge of the chunk, LW = off_max rounded
+    up to whole lanes (≤ block_c).  Each tile reads its left halo for edge
+    k from ``lefth[k]``, then overwrites it with its own pre-edge-k
+    boundary for the next tile (read-then-write within one grid step, so a
+    single buffer suffices along C);
+  * ``rowh`` — (2 × block_e, UH, C_padded): the bottom UH rows (u_max
+    rounded up to whole sublanes, ≤ block_s) of every tile of the previous
+    S-row, per edge, double-banked by S-row parity — tile (i, j) reads bank
+    (i−1) mod 2 (up halo at columns of tiles j−1 and j, the j−1 part being
+    the up-left corner) and writes bank i mod 2, so row i's writes never
+    clobber the corner history row i+1 still needs.
 
 Decision bits for the whole chunk pack into ONE (S, C) int32 word-plane
 per tile (bit Υ = global edge id mod 32 — legal because block_e ≤ 32 keeps
@@ -73,27 +81,22 @@ the packed (⌈E/32⌉, S, C) decision planes through static per-chunk word
 masks, which also handles chunks straddling a 32-bit word boundary.
 
 ``dp_forward_pallas_batched`` runs a FLEET of B independent solves in one
-pallas_call.  The batch rides the grid: ``block_b`` instances advance
-together per grid step, the shared operands (feasibility plane, offsets,
-v0) are loaded through index maps that ignore the batch index — one copy
-in HBM, never replicated B-fold the way folding per-instance eligibility
-into the feasibility plane under ``jax.vmap`` replicates it — and the
-per-instance inputs (Υ̂, Σ̂², ``allowed``) stream as (block_b, E) SMEM
-rows.  Inside a step the edge loop is VECTORIZED across the block's
-instances: the per-instance budget shift V[max(s−Υ̂_e, 0)] becomes
-⌈log₂(u_max+1)⌉ static slice-concat stages selected per instance by the
-bits of Υ̂_e (clamped shifts compose exactly: T_b∘T_a = T_{a+b}), so the
-kernel stays gather-free with a batch-varying shift.  ``block_b = 1``
-degenerates to the single-instance schedule (one dynamic-start read, no
-log-shift stages) — bit-identical either way.  Ragged batches pad with
-inert instances (``allowed ≡ 0`` masks every edge to NEG, so the pads
-compute v0 and zero decisions).  When the per-instance plane outgrows
-VMEM the batch instead becomes the OUTERMOST grid dimension of the
-edge-fused pipeline (block_b pinned to 1): each instance re-initializes
-the halo-history scratches at its own (i=0, j=0) corner, so the fused
-kernel body is reused unchanged.  ``choose_tiling(..., batch=B)``
-resolves the whole (block_b, block_e, block_s, block_c) split, shrinking
-the batch axis BEFORE the plane axes.
+pallas_call.  The batch rides the grid: ``block_b`` instances advance per
+grid step (an in-kernel loop over the block's instances), the shared
+operands (feasibility plane, offsets, v0) are loaded through index maps
+that ignore the batch index — one copy in HBM, never replicated B-fold the
+way folding per-instance eligibility into the feasibility plane under
+``jax.vmap`` replicates it — and the per-instance inputs (Υ̂, Σ̂²,
+``allowed``) stream as (block_b, 1, E) SMEM rows.  The single-instance
+whole-plane solve is this kernel at B = 1.  Ragged batches pad with inert
+instances (``allowed ≡ 0`` masks every edge to NEG, so the pads compute v0
+and zero decisions).  When the per-instance plane outgrows VMEM the batch
+instead becomes the OUTERMOST grid dimension of the edge-fused pipeline
+(block_b pinned to 1): each instance re-initializes the halo-history
+scratches at its own (i=0, j=0) corner, so the fused kernel body is reused
+unchanged.  ``choose_tiling(..., batch=B)`` resolves the whole (block_b,
+block_e, block_s, block_c) split, shrinking the batch axis BEFORE the plane
+axes.
 
 Arithmetic is f32 with integer values; exactness holds for values < 2²⁴
 (ops.py enforces the bound — see core/stats.py for why defaults are ≪ 2²⁴).
@@ -102,11 +105,15 @@ Backend resolution: ``interpret=None`` (the default) compiles on TPU and
 falls back to the Pallas interpreter elsewhere — the kernel is never
 silently interpreted on real TPU hardware.  Pass an explicit bool to force
 either mode (``interpret=True`` is how the differential tests exercise the
-kernel logic on CPU CI).
+kernel logic on CPU CI).  Compiled tiled pipelines need tile-aligned tiles
+(block_c a multiple of 128, block_s of 8); ``choose_tiling`` only picks
+those, and a forced misaligned tile raises instead of reaching the
+compiler.
 """
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -123,12 +130,16 @@ __all__ = ["NEG", "VMEM_BUDGET_BYTES", "MAX_BLOCK_E", "resolve_interpret",
 
 NEG = -float(2 ** 24)
 
-# conservative share of the ~16 MB/core VMEM left to this kernel
+# share of the TPU's default 16 MiB scoped-VMEM limit left to this kernel's
+# modeled footprint; the rest covers what the model does not count (the
+# compiler's internal scratch and spilled temporaries)
 VMEM_BUDGET_BYTES = 12 * 2 ** 20
 
 # fused chunks pack their decision bits into ONE int32 word-plane, so
 # in-chunk bit positions (global edge id mod 32) must be distinct
 MAX_BLOCK_E = 32
+
+_SUBLANES, _LANES = 8, 128  # one 32-bit vreg tile
 
 
 def resolve_interpret(
@@ -151,69 +162,89 @@ def packed_words(n_edges: int) -> int:
     return (n_edges + 31) // 32
 
 
+def _pad_to(n: int, unit: int) -> int:
+    return -(-n // unit) * unit
+
+
+def _tile_words(rows: int, cols: int) -> int:
+    """4-byte words one 2-D VMEM buffer occupies: the compiler lays it out
+    in whole (8, 128) tiles."""
+    return _pad_to(max(rows, 1), _SUBLANES) * _pad_to(max(cols, 1), _LANES)
+
+
+def _halo_widths(block_s: int, block_c: int, u_max: int, off_max: int):
+    """(UH, LW): rows of up-halo history and columns of left-halo history a
+    fused tile keeps — u_max / off_max rounded up to whole sublanes /
+    lanes, capped at the tile (block_s ≥ u_max and block_c ≥ off_max, so
+    the cap never cuts below the halo)."""
+    uh = min(_pad_to(max(u_max, 1), _SUBLANES), block_s)
+    lw = min(_pad_to(max(off_max, 1), _LANES), block_c)
+    return uh, lw
+
+
 def unblocked_vmem_bytes(S: int, C: int, n_edges: int, u_max: int, off_max: int) -> int:
-    """VMEM footprint of the whole-plane kernel: v0 + V + packed decisions +
-    the (u_max+S, off_max+C) shift scratch + the (E, C) feasibility plane +
-    the three (E,) operand vectors, all 4-byte."""
-    W = packed_words(n_edges)
-    return 4 * ((2 + W) * S * C + (u_max + S) * (off_max + C)
-                + n_edges * (C + 3))
+    """VMEM footprint of the whole-plane kernel on one instance (see
+    :func:`batched_vmem_bytes` — the single solve is its B = 1 case)."""
+    return batched_vmem_bytes(S, C, n_edges, u_max, off_max, 1)
 
 
 def c_blocked_tile_vmem_bytes(S: int, block_c: int, u_max: int) -> int:
     """Per-grid-step VMEM of the C-blocked (full-height) pipeline: two
-    haloed (S, block_c) input views + two output tiles + the
-    (u_max + S, 2·block_c) shift scratch + the feasibility tile, 4-byte."""
-    return 4 * (4 * S * block_c + (u_max + S) * 2 * block_c + block_c)
+    haloed (S, block_c) input views + two output tiles, double-buffered,
+    + the feasibility tile, plus four plane-sized temporaries of the
+    shifted read (both rolls, the take, the decision)."""
+    tile = _tile_words(S, block_c)
+    return 4 * (2 * (4 * tile + _tile_words(1, block_c)) + 4 * tile)
 
 
 def tiled_vmem_bytes(block_s: int, block_c: int, u_max: int) -> int:
     """Per-grid-step VMEM of the 2-D (S-tile × C-tile) pipeline: four
-    haloed (block_s, block_c) input views + two output tiles + the
-    (u_max + block_s, 2·block_c) shift scratch + the feasibility tile —
+    haloed (block_s, block_c) input views + two output tiles,
+    double-buffered, + the feasibility tile, plus the shift temporaries —
     independent of both plane extents."""
-    return 4 * (6 * block_s * block_c
-                + (u_max + block_s) * 2 * block_c + block_c)
+    tile = _tile_words(block_s, block_c)
+    return 4 * (2 * (6 * tile + _tile_words(1, block_c)) + 6 * tile)
 
 
 def fused_tile_vmem_bytes(
     block_e: int, block_s: int, block_c: int, u_max: int, off_max: int, S: int, C: int
 ) -> int:
-    """Per-grid-step VMEM of the edge-fused pipeline: one (block_s, block_c)
-    input tile + two output tiles (value + chunk bits) + the
-    (u_max + block_s, off_max + block_c) shift scratch + the per-chunk
-    feasibility block + the two persistent halo-history scratches —
-    ``lefth`` (block_e, block_s, off_max) and the double-banked ``rowh``
-    (2·block_e, u_max, C_padded), the only term that scales with the plane
+    """Per-grid-step VMEM of the edge-fused pipeline: the (block_s,
+    block_c) input tile and two output tiles (value + chunk bits) plus the
+    per-chunk feasibility block, double-buffered; four tile-sized shift
+    temporaries; and the two persistent halo-history scratches —
+    ``lefth`` (block_e, block_s, LW) and the double-banked ``rowh``
+    (2·block_e, UH, C_padded), the only term that scales with the plane
     width.  A single-S-row grid (block_s ≥ S, i.e. full-height tiles) has
     no up neighbors: ``rowh`` is neither allocated nor charged, which is
     what keeps large fused chunks affordable at very large C.  All
-    4-byte."""
-    Cp = -(-C // block_c) * block_c
-    rowh = 0 if block_s >= S else 2 * block_e * max(u_max, 1) * Cp
-    return 4 * (3 * block_s * block_c
-                + (u_max + block_s) * (off_max + block_c)
-                + block_e * block_c  # feasibility chunk
-                + rowh  # rowh banks
-                + block_e * block_s * max(off_max, 1)  # lefth
-                + 4 * block_e)  # SMEM scalars
+    4-byte, in whole (8, 128) tiles."""
+    uh, lw = _halo_widths(block_s, block_c, u_max, off_max)
+    Cp = _pad_to(C, block_c)
+    tile = _tile_words(block_s, block_c)
+    rowh = 0 if block_s >= S else 2 * block_e * _tile_words(uh, Cp)
+    return 4 * (2 * (3 * tile + _tile_words(block_e, block_c))
+                + 4 * tile
+                + rowh
+                + block_e * _tile_words(block_s, lw))
 
 
 def batched_vmem_bytes(
     S: int, C: int, n_edges: int, u_max: int, off_max: int, block_b: int
 ) -> int:
-    """VMEM footprint of one grid step of the whole-plane BATCHED kernel:
-    the per-instance value plane + packed decision words + shift scratch +
-    the three (E,) operand rows, all charged × ``block_b``, plus the
-    SHARED v0 plane, feasibility plane, and offset vector (loaded once per
-    step regardless of the batch).  ``block_b = 1`` keeps the
-    single-instance clamp-row scratch geometry (u_max extra rows); the
-    vectorized path (block_b > 1) shifts through log₂ stages instead and
-    drops them.  All 4-byte."""
+    """VMEM footprint of one grid step of the whole-plane kernel over
+    ``block_b`` instances: each instance's value plane and packed decision
+    words (charged × ``block_b``), the SHARED v0 plane and feasibility
+    plane (loaded once per step regardless of the batch), all
+    double-buffered, plus four plane-sized temporaries of the shifted
+    read (the instances run one after another, so these are charged
+    once).  Per-edge scalars sit in SMEM and cost no VMEM.  All 4-byte, in
+    whole (8, 128) tiles."""
     W = packed_words(n_edges)
-    pad_rows = u_max if block_b == 1 else 0
-    per = (1 + W) * S * C + (pad_rows + S) * (off_max + C) + 3 * n_edges
-    return 4 * (block_b * per + S * C + n_edges * (C + 1))
+    plane = _tile_words(S, C)
+    per = (1 + W) * plane
+    shared = plane + _tile_words(n_edges, C)
+    return 4 * (2 * (block_b * per + shared) + 4 * plane)
 
 
 def batched_fused_tile_vmem_bytes(
@@ -227,22 +258,16 @@ def batched_fused_tile_vmem_bytes(
     block_b: int,
 ) -> int:
     """Per-grid-step VMEM of the BATCHED edge-fused pipeline: the shared
-    per-chunk feasibility block and offset/bit-position rows load once;
-    everything per-instance — the plane tile, the shift scratch, both
-    halo-history scratches, and the (1, block_e) Υ̂/Σ̂²/allowed rows —
-    charges × ``block_b``.  The batched driver pins ``block_b = 1`` on
-    this path (one instance per grid step — the per-instance halo
-    histories are what overflowed the budget in the first place), but the
-    model keeps the general form so the batched decision rule charges the
-    batch axis uniformly.  All 4-byte."""
-    Cp = -(-C // block_c) * block_c
-    rowh = 0 if block_s >= S else 2 * block_e * max(u_max, 1) * Cp
-    per = (3 * block_s * block_c
-           + (u_max + block_s) * (off_max + block_c)
-           + rowh
-           + block_e * block_s * max(off_max, 1)
-           + 3 * block_e)  # Υ̂/Σ̂²/allowed SMEM rows
-    shared = block_e * block_c + 2 * block_e  # feas chunk + offs/bitpos
+    per-chunk feasibility block loads once; everything per-instance — the
+    plane tiles, the shift temporaries and both halo-history scratches —
+    charges × ``block_b``.  The batched driver pins ``block_b = 1`` on this
+    path (one instance per grid step — the per-instance halo histories are
+    what overflowed the budget in the first place), but the model keeps
+    the general form so the batched decision rule charges the batch axis
+    uniformly.  All 4-byte, in whole (8, 128) tiles."""
+    shared = 2 * _tile_words(block_e, block_c)
+    per = fused_tile_vmem_bytes(block_e, block_s, block_c, u_max, off_max,
+                                S, C) // 4 - shared
     return 4 * (block_b * per + shared)
 
 
@@ -315,12 +340,15 @@ def batched_modeled_hbm_bytes(
 
 
 def _tile_candidates(extent: int, unit: int, floor: int) -> list:
-    """Descending tile widths for one axis: the full extent plus every
-    power-of-two multiple of ``unit`` below it, all ≥ ``floor`` (the halo
-    legality bound — off_max along C, u_max along S)."""
-    cands = {extent}
+    """Descending tile widths for one axis: the full extent rounded up to
+    whole ``unit``s plus every power-of-two multiple of ``unit`` below it,
+    all ≥ ``floor`` (the halo legality bound — off_max along C, u_max along
+    S).  Every candidate is a multiple of ``unit``, so the compiled
+    kernels only ever see tile-aligned blocks."""
+    full = _pad_to(extent, unit)
+    cands = {full}
     width = unit
-    while width < extent:
+    while width < full:
         if width >= floor:
             cands.add(width)
         width *= 2
@@ -363,9 +391,10 @@ def choose_tiling(
     when even a 1-edge chunk's history scratch overflows the budget.
 
     Tiles respect the halo floors (block_c ≥ off_max, block_s ≥ u_max) and
-    the VPU lane/sublane units (128 along C, 8 along S) wherever the
-    floors allow; if even the smallest legal pair exceeds the budget it is
-    returned anyway — no smaller tiling exists.
+    are whole multiples of the vreg tile (128 lanes along C, 8 sublanes
+    along S), as the compiled kernels require; if even the smallest legal
+    pair exceeds the budget it is returned anyway — no smaller tiling
+    exists.
     """
     if batch is not None:
         if batch < 1:
@@ -377,18 +406,18 @@ def choose_tiling(
         return (1,) + choose_tiling(S, C, n_edges, u_max, off_max, budget)
     if unblocked_vmem_bytes(S, C, n_edges, u_max, off_max) <= budget:
         return None, None, None
-    c_cands = _tile_candidates(C, 128, off_max)
+    c_cands = _tile_candidates(C, _LANES, off_max)
     block_s = block_c = None
     for bc in c_cands:  # widest full-height first
         if c_blocked_tile_vmem_bytes(S, bc, u_max) <= budget:
             block_c = bc
             break
     if block_c is None:
-        s_cands = _tile_candidates(S, 8, max(u_max, 1))
+        s_cands = _tile_candidates(S, _SUBLANES, max(u_max, 1))
         best = None
         for bs in s_cands:
             for bc in c_cands:
-                if bs == S and bc == C:
+                if bs == s_cands[0] and bc == c_cands[0]:
                     continue  # that is the whole plane
                 if tiled_vmem_bytes(bs, bc, u_max) > budget:
                     continue
@@ -398,7 +427,7 @@ def choose_tiling(
         if best is None:
             best = (s_cands[-1], c_cands[-1])  # floor pair: best possible
         block_s, block_c = best
-    bs_eff = S if block_s is None else block_s
+    bs_eff = _pad_to(S, _SUBLANES) if block_s is None else block_s
     for be in range(min(MAX_BLOCK_E, max(n_edges, 1)), 0, -1):
         if fused_tile_vmem_bytes(be, bs_eff, block_c, u_max, off_max,
                                  S, C) <= budget:
@@ -406,82 +435,70 @@ def choose_tiling(
     return None, block_s, block_c
 
 
+# ---------------------------------------------------------------------------
+# the shifted read, in registers
+# ---------------------------------------------------------------------------
+
+def _roll(x, shift, axis: int):
+    """y[i] = x[(i − shift) mod n] along ``axis`` for a dynamic shift ≥ 0."""
+    return pltpu.roll(x, shift % x.shape[axis], axis)
+
+
+def _lane_shift(x, left, off):
+    """y[:, c] = plane[:, c − off] for a tile ``x`` of the plane.
+
+    Columns c < off read the left neighbor: ``left`` holds its last LW ≥
+    off columns, rolled by the same distance.  With ``left=None`` (a
+    whole-plane or first-column tile) those columns wrap around instead —
+    they are the states c < offset_e, infeasible, and masked to NEG by the
+    caller."""
+    y = _roll(x, off, 1)
+    if left is None:
+        return y
+    lw = left.shape[1]
+    cols = jax.lax.broadcasted_iota(jnp.int32, left.shape, 1)
+    head = jnp.where(cols < off, _roll(left, off, 1), y[:, :lw])
+    if lw == x.shape[1]:
+        return head
+    return jnp.concatenate([head, y[:, lw:]], axis=1)
+
+
+def _row_shift(y, up, u):
+    """z[s] = plane[max(s − u, 0)] for a (lane-shifted) tile ``y``.
+
+    Rows s < u read above the tile: ``up`` holds the UH ≥ u plane rows
+    directly above it (already lane-shifted), rolled by the same distance.
+    With ``up=None`` the tile starts at plane row 0, and rows s < u take
+    the clamp row y[0] — budgets below 0 clamp to V[0]."""
+    z = _roll(y, u, 0)
+    if up is None:
+        rows = jax.lax.broadcasted_iota(jnp.int32, y.shape, 0)
+        return jnp.where(rows < u, y[0:1, :], z)
+    uh = up.shape[0]
+    rows = jax.lax.broadcasted_iota(jnp.int32, up.shape, 0)
+    head = jnp.where(rows < u, _roll(up, u, 0), z[:uh])
+    if uh == y.shape[0]:
+        return head
+    return jnp.concatenate([head, z[uh:]], axis=0)
+
+
+def _clamp_or(first_row, y0, up):
+    """The up halo of an S-tile: ``up`` below the first tile row, else
+    (first_row) the replicated clamp row ``y0`` of the tile itself."""
+    return jnp.where(first_row, jnp.broadcast_to(y0, up.shape), up)
+
+
+def _edge_update(cur, shifted, sig, live):
+    """One edge of the recurrence on a tile: returns (V′, decision)."""
+    take = jnp.where(live, shifted + sig, NEG)
+    return jnp.maximum(cur, take), take > cur
+
+
+# ---------------------------------------------------------------------------
+# whole-plane kernel (single solve = a batch of one)
+# ---------------------------------------------------------------------------
+
 def _dp_kernel(
-    ups_ref,
-    sig_ref,
-    offs_ref,
-    feas_ref,
-    v0_ref,
-    vout_ref,
-    dec_ref,
-    vpad_ref,
-    *,
-    n_edges: int,
-    u_max: int,
-    off_max: int,
-):
-    S, C = v0_ref.shape
-    W = dec_ref.shape[0]
-    vout_ref[:, :] = v0_ref[:, :]
-    dec_ref[:, :, :] = jnp.zeros((W, S, C), jnp.int32)
-    if off_max:
-        # pad columns: read only for states with c < offset_e, all infeasible
-        # and masked to NEG below — NEG keeps the reads inert either way
-        vpad_ref[:, :off_max] = jnp.full((u_max + S, off_max), NEG,
-                                         jnp.float32)
-
-    def edge_step(j, _):
-        e = n_edges - 1 - j
-        u = jnp.minimum(ups_ref[e], u_max)  # clamp: never read past pad
-        off = jnp.minimum(offs_ref[e], off_max)
-        sig = sig_ref[e].astype(jnp.float32)
-
-        V = vout_ref[:, :]
-        # padded shift buffer: rows [0, u_max) = clamp row V[0], then V;
-        # the value plane sits at columns [off_max, off_max + C)
-        vpad_ref[:u_max, off_max:] = jnp.broadcast_to(V[0:1, :], (u_max, C))
-        vpad_ref[pl.ds(u_max, S), off_max:] = V
-        # one 2-D shifted read: V[max(s-u, 0), c - off]
-        take = vpad_ref[pl.ds(u_max - u, S), pl.ds(off_max - off, C)] + sig
-
-        feas = feas_ref[e, :]  # (C,) 0/1
-        take = jnp.where(feas[None, :] > 0, take, NEG)
-        dec = (take > V).astype(jnp.int32)
-        # OR edge e's decision bit into its int32 word (bit = e mod 32;
-        # multiply by the power of two — exact, and 1<<31 wraps to the sign
-        # bit whose pattern is still the bit we want)
-        bit = jnp.left_shift(jnp.int32(1), e % 32)
-        word = dec_ref[pl.ds(e // 32, 1), :, :]
-        dec_ref[pl.ds(e // 32, 1), :, :] = word | (dec * bit)[None]
-        vout_ref[:, :] = jnp.maximum(V, take)
-        return 0
-
-    jax.lax.fori_loop(0, n_edges, edge_step, 0)
-
-
-def _shift_rows_clamped(x, u, u_max: int):
-    """Per-instance clamped budget shift: y[b, s, c] = x[b, max(s − u[b], 0), c].
-
-    Decomposed into ⌈log₂(u_max + 1)⌉ STATIC slice-concat stages, stage k
-    applied only to instances with bit k set in u — legal because clamped
-    shifts compose exactly (T_b ∘ T_a = T_{a+b}: clamping at 0 is
-    idempotent under further down-shifts).  Keeps the batch-varying shift
-    gather-free and lane-contiguous on the VPU."""
-    bb, S, C = x.shape
-    shift = 1
-    while shift <= u_max:
-        if shift < S:
-            rolled = jnp.concatenate(
-                [jnp.broadcast_to(x[:, :1], (bb, shift, C)), x[:, :S - shift]],
-                axis=1)
-        else:
-            rolled = jnp.broadcast_to(x[:, :1], (bb, S, C))
-        x = jnp.where((u & shift).astype(bool)[:, None, None], rolled, x)
-        shift *= 2
-    return x
-
-
-def _dp_kernel_batched(
     ups_ref,
     sig_ref,
     alw_ref,
@@ -490,67 +507,111 @@ def _dp_kernel_batched(
     v0_ref,
     vout_ref,
     dec_ref,
-    vpad_ref,
     *,
     n_edges: int,
     u_max: int,
     off_max: int,
 ):
-    """Whole-plane DP forward over ``block_b`` instances per grid step.
+    """Whole-plane DP forward over the ``block_b`` instances of one grid step.
 
-    Per-instance operands arrive as (block_b, E) SMEM rows; the
+    Per-instance operands arrive as (block_b, 1, E) SMEM rows; the
     feasibility plane and v0 are the SHARED blocks (their index maps
-    ignore the batch index).  Per-instance eligibility multiplies into
-    the mask HERE (``live = feasible ∧ allowed``) instead of being folded
-    into per-instance feasibility copies on the host.  The edge loop runs
-    per 32-edge word with the decision word accumulated in registers and
-    written back once per word (static-index write).  ``block_b == 1``
-    reduces the budget shift to the single-instance schedule — one
-    dynamic-start read through u_max clamp rows, bit-identical to
-    :func:`_dp_kernel`; ``block_b > 1`` vectorizes it through
-    :func:`_shift_rows_clamped` on a clamp-row-free scratch."""
-    block_b, S, C = vout_ref.shape
+    ignore the batch index).  Per-instance eligibility multiplies into the
+    mask HERE (``live = feasible ∧ allowed``) instead of being folded into
+    per-instance feasibility copies on the host.  Instances run one after
+    another; each walks its edges word-major, ORing every decision into
+    its static-index 32-edge word."""
+    block_b = vout_ref.shape[0]
     W = dec_ref.shape[1]
-    vout_ref[:, :, :] = jnp.broadcast_to(v0_ref[:, :][None], (block_b, S, C))
-    if off_max:
-        # pad columns: read only by states with c < offset_e, all
-        # infeasible and masked to NEG below — inert either way
-        vpad_ref[:, :, :off_max] = jnp.full(
-            (block_b, vpad_ref.shape[1], off_max), NEG, jnp.float32)
 
-    for w in range(W - 1, -1, -1):  # edges E-1 … 0, word-major
-        e_lo = w * 32
-        e_hi = min(e_lo + 32, n_edges)
+    def instance(b, _):
+        vout_ref[b] = v0_ref[...]
+        for w in range(W - 1, -1, -1):  # edges E-1 … 0, word-major
+            e_lo = w * 32
+            e_hi = min(e_lo + 32, n_edges)
+            dec_ref[b, w] = jnp.zeros(dec_ref.shape[2:], jnp.int32)
 
-        def edge_step(jj, word, e_hi=e_hi):
-            e = e_hi - 1 - jj
-            u = jnp.minimum(ups_ref[:, pl.ds(e, 1)][:, 0], u_max)
-            off = jnp.minimum(offs_ref[e], off_max)
-            sig = sig_ref[:, pl.ds(e, 1)].astype(jnp.float32)[:, :, None]
-            alw = alw_ref[:, pl.ds(e, 1)][:, :, None]
-            V = vout_ref[:, :, :]
-            if block_b == 1:
-                # single-instance schedule: scalar shift through clamp rows
-                vpad_ref[:, :u_max, off_max:] = jnp.broadcast_to(
-                    V[:, 0:1, :], (1, u_max, C))
-                vpad_ref[:, pl.ds(u_max, S), off_max:] = V
-                take = vpad_ref[:, pl.ds(u_max - u[0], S),
-                                pl.ds(off_max - off, C)]
-            else:
-                vpad_ref[:, :, off_max:] = V
-                shifted = vpad_ref[:, :, pl.ds(off_max - off, C)]
-                take = _shift_rows_clamped(shifted, u, u_max)
-            take = take + sig
-            live = (feas_ref[pl.ds(e, 1), :][None] > 0) & (alw > 0)
-            take = jnp.where(live, take, NEG)
-            dec = (take > V).astype(jnp.int32)
-            vout_ref[:, :, :] = jnp.maximum(V, take)
-            return word | (dec * jnp.left_shift(jnp.int32(1), e % 32))
+            def edge_step(jj, _, e_hi=e_hi, w=w):
+                e = e_hi - 1 - jj
+                u = jnp.minimum(ups_ref[b, 0, e], u_max)
+                off = jnp.minimum(offs_ref[e], off_max)
+                sig = sig_ref[b, 0, e].astype(jnp.float32)
+                live = ((feas_ref[pl.ds(e, 1), :] > 0)
+                        & (alw_ref[b, 0, e] > 0))
+                V = vout_ref[b]
+                take = _row_shift(_lane_shift(V, None, off), None, u)
+                Vn, dec = _edge_update(V, take, sig, live)
+                vout_ref[b] = Vn
+                bit = jnp.left_shift(jnp.int32(1), e % 32)
+                dec_ref[b, w] = dec_ref[b, w] | jnp.where(dec, bit, 0)
+                return 0
 
-        word = jax.lax.fori_loop(0, e_hi - e_lo, edge_step,
-                                 jnp.zeros((block_b, S, C), jnp.int32))
-        dec_ref[:, w] = word
+            jax.lax.fori_loop(0, e_hi - e_lo, edge_step, 0)
+        return 0
 
+    jax.lax.fori_loop(0, block_b, instance, 0)
+
+
+def _whole_plane(
+    upsilon,
+    sigma2,
+    allowed,
+    feasible,
+    offsets,
+    v0,
+    *,
+    n_edges,
+    u_max,
+    off_max,
+    block_b,
+    interpret,
+):
+    """(B, E) per-instance rows → (V (B, S, C), decisions (B, W, S, C)) on
+    the whole-plane kernel, the plane padded to whole vreg tiles (pad
+    states are infeasible; pad rows sit below every real row and the
+    shifts only read upward, so both are inert and sliced away)."""
+    B = upsilon.shape[0]
+    S, C = v0.shape
+    Sp, Cp = _pad_to(S, _SUBLANES), _pad_to(C, _LANES)
+    W = packed_words(n_edges)
+    bb = block_b
+    Bp = _pad_to(B, bb)
+    pad = ((0, Bp - B), (0, 0))
+    upsilon = jnp.pad(upsilon, pad)
+    sigma2 = jnp.pad(sigma2, pad)
+    allowed = jnp.pad(allowed, pad)  # allowed ≡ 0 ⇒ inert
+    v0p = jnp.pad(v0, ((0, Sp - S), (0, Cp - C)), constant_values=NEG)
+    feas_p = jnp.pad(feasible, ((0, 0), (0, Cp - C)))
+    kernel = functools.partial(_dp_kernel, n_edges=n_edges, u_max=u_max,
+                               off_max=off_max)
+    # per-instance rows ride SMEM as (B, 1, E): the block's last two dims
+    # then span the whole array, which any block_b satisfies
+    inst = pl.BlockSpec((bb, 1, n_edges), lambda g: (g, 0, 0),
+                        memory_space=pltpu.SMEM)
+    V, dec = pl.pallas_call(
+        kernel,
+        grid=(Bp // bb,),
+        out_shape=(jax.ShapeDtypeStruct((Bp, Sp, Cp), jnp.float32),
+                   jax.ShapeDtypeStruct((Bp, W, Sp, Cp), jnp.int32)),
+        in_specs=[
+            inst,  # Υ̂ rows
+            inst,  # Σ̂² rows
+            inst,  # allowed rows
+            pl.BlockSpec(memory_space=pltpu.SMEM),  # shared offsets
+            pl.BlockSpec((n_edges, Cp), lambda g: (0, 0)),
+            pl.BlockSpec((Sp, Cp), lambda g: (0, 0)),
+        ],
+        out_specs=(pl.BlockSpec((bb, Sp, Cp), lambda g: (g, 0, 0)),
+                   pl.BlockSpec((bb, W, Sp, Cp), lambda g: (g, 0, 0, 0))),
+        interpret=interpret,
+    )(upsilon[:, None], sigma2[:, None], allowed[:, None], offsets, feas_p,
+      v0p)
+    return V[:B, :S, :C], dec[:B, :, :S, :C]
+
+
+# ---------------------------------------------------------------------------
+# per-edge-scan pipelines (one pallas_call per edge)
+# ---------------------------------------------------------------------------
 
 def _edge_tile_kernel(
     u_ref,
@@ -561,7 +622,6 @@ def _edge_tile_kernel(
     vcur_ref,
     vout_ref,
     bits_ref,
-    vpad_ref,
     *,
     u_max: int,
 ):
@@ -569,25 +629,17 @@ def _edge_tile_kernel(
 
     ``vleft``/``vcur`` are two views of the SAME value plane: the tile and
     its left neighbor (tile 0 reads itself — those columns are c < offset_e,
-    infeasible, masked).  The concatenated (u_max+S, 2B) scratch makes both
-    shifts single dynamic-start reads, exactly like the whole-plane kernel.
+    infeasible, masked).  Both shifts are rolls of the tile, spliced with
+    the rolled neighbor, exactly like the whole-plane kernel.
     """
-    S, B = vcur_ref.shape
     u = jnp.minimum(u_ref[0], u_max)
-    off = jnp.minimum(off_ref[0], B)
+    off = off_ref[0]
     sig = sig_ref[0].astype(jnp.float32)
-    left = vleft_ref[:, :]
-    cur = vcur_ref[:, :]
-
-    vpad_ref[:u_max, :B] = jnp.broadcast_to(left[0:1, :], (u_max, B))
-    vpad_ref[:u_max, B:] = jnp.broadcast_to(cur[0:1, :], (u_max, B))
-    vpad_ref[pl.ds(u_max, S), :B] = left
-    vpad_ref[pl.ds(u_max, S), B:] = cur
-    take = vpad_ref[pl.ds(u_max - u, S), pl.ds(B - off, B)] + sig
-
-    take = jnp.where(feas_ref[0:1, :] > 0, take, NEG)
-    bits_ref[:, :] = (take > cur).astype(jnp.int32)
-    vout_ref[:, :] = jnp.maximum(cur, take)
+    cur = vcur_ref[...]
+    take = _row_shift(_lane_shift(cur, vleft_ref[...], off), None, u)
+    Vn, dec = _edge_update(cur, take, sig, feas_ref[...] > 0)
+    bits_ref[...] = dec.astype(jnp.int32)
+    vout_ref[...] = Vn
 
 
 def _edge_stile_kernel(
@@ -601,44 +653,27 @@ def _edge_stile_kernel(
     vcur_ref,
     vout_ref,
     bits_ref,
-    vpad_ref,
     *,
     u_max: int,
 ):
     """One edge update on one (block_s, block_c) tile of the 2-D grid.
 
     The four ``v*`` refs are views of the SAME value plane: the tile, its
-    left neighbor, and the up-neighbor row of both (S-tile 0 reads itself
-    upward and substitutes the plane's clamp row V[0] — budgets below 0
-    clamp to V[0], exactly the whole-plane kernel's clamp rows; C-tile 0
-    reads itself leftward — those columns are c < offset_e, infeasible,
-    masked).  The (u_max + block_s, 2·block_c) scratch makes both shifts
-    single dynamic-start reads."""
-    Bs, Bc = vcur_ref.shape
+    left neighbor, and the up-neighbor row of both (S-tile 0 substitutes
+    the plane's clamp row V[0] — budgets below 0 clamp to V[0], exactly the
+    whole-plane kernel's clamp rows; C-tile 0 reads itself leftward —
+    those columns are c < offset_e, infeasible, masked)."""
     u = jnp.minimum(u_ref[0], u_max)
-    off = jnp.minimum(off_ref[0], Bc)
+    off = off_ref[0]
     sig = sig_ref[0].astype(jnp.float32)
-    left = vleft_ref[:, :]
-    cur = vcur_ref[:, :]
-
-    if u_max:
-        # halo rows [0, u_max): last u_max rows of the up-neighbor tile,
-        # or the replicated clamp row V[0] on the first S tile (u_max ≤
-        # block_s keeps the halo inside ONE up neighbor)
-        first = pl.program_id(0) == 0
-        vpad_ref[:u_max, :Bc] = jnp.where(
-            first, jnp.broadcast_to(left[0:1, :], (u_max, Bc)),
-            vup_left_ref[Bs - u_max:, :])
-        vpad_ref[:u_max, Bc:] = jnp.where(
-            first, jnp.broadcast_to(cur[0:1, :], (u_max, Bc)),
-            vup_cur_ref[Bs - u_max:, :])
-    vpad_ref[pl.ds(u_max, Bs), :Bc] = left
-    vpad_ref[pl.ds(u_max, Bs), Bc:] = cur
-    take = vpad_ref[pl.ds(u_max - u, Bs), pl.ds(Bc - off, Bc)] + sig
-
-    take = jnp.where(feas_ref[0:1, :] > 0, take, NEG)
-    bits_ref[:, :] = (take > cur).astype(jnp.int32)
-    vout_ref[:, :] = jnp.maximum(cur, take)
+    cur = vcur_ref[...]
+    x = _lane_shift(cur, vleft_ref[...], off)
+    up = _lane_shift(vup_cur_ref[...], vup_left_ref[...], off)
+    take = _row_shift(x, _clamp_or(pl.program_id(0) == 0, x[0:1, :], up),
+                      u)
+    Vn, dec = _edge_update(cur, take, sig, feas_ref[...] > 0)
+    bits_ref[...] = dec.astype(jnp.int32)
+    vout_ref[...] = Vn
 
 
 def _edge_call(
@@ -665,8 +700,6 @@ def _edge_call(
             ],
             out_specs=(pl.BlockSpec((Sp, block_c), lambda j: (0, j)),
                        pl.BlockSpec((Sp, block_c), lambda j: (0, j))),
-            scratch_shapes=[pltpu.VMEM((u_max + Sp, 2 * block_c),
-                                       jnp.float32)],
             interpret=interpret,
         )(u1, off1, sig1, feas_e, V, V)
     kernel = functools.partial(_edge_stile_kernel, u_max=u_max)
@@ -688,11 +721,13 @@ def _edge_call(
         ],
         out_specs=(pl.BlockSpec((block_s, block_c), lambda i, j: (i, j)),
                    pl.BlockSpec((block_s, block_c), lambda i, j: (i, j))),
-        scratch_shapes=[pltpu.VMEM((u_max + block_s, 2 * block_c),
-                                   jnp.float32)],
         interpret=interpret,
     )(u1, off1, sig1, feas_e, V, V, V, V)
 
+
+# ---------------------------------------------------------------------------
+# edge-fused pipeline (one pallas_call per chunk of block_e edges)
+# ---------------------------------------------------------------------------
 
 def _fused_chunk_kernel(
     ups_ref,
@@ -703,7 +738,6 @@ def _fused_chunk_kernel(
     vin_ref,
     vout_ref,
     bits_ref,
-    vpad_ref,
     rowh_ref,
     lefth_ref,
     *,
@@ -716,94 +750,73 @@ def _fused_chunk_kernel(
 ):
     """``n_chunk`` consecutive edges on one (block_s, block_c) tile.
 
-    The tile lives in the BODY region of ``vpad`` (rows [u_max:], columns
-    [off_max:]) for the whole chunk — loaded from HBM once, written back
-    once.  Per edge k the halo regions refresh from the persistent history
-    scratches (see the module docstring): ``lefth[k]`` holds the left
-    neighbor's last off_max columns *before* edge k (read, then overwritten
-    with this tile's own pre-edge-k boundary for the next C-tile), and
-    ``rowh`` holds the previous S-row's bottom u_max rows per edge,
-    double-banked by row parity so the up-left corner read never races the
-    current row's writes.  S-row 0 replicates the clamp row V[0] (= body
-    row 0, and the left halo's row 0 for the corner columns) exactly like
-    the unfused kernels; C-tile 0's left halo is garbage by construction —
-    every read landing there is a state c < offset_e, infeasible, masked
-    to NEG.  Decision bits of the whole chunk OR into one int32 word plane
-    at bit ``bitpos[k]`` (global edge id mod 32).
+    The tile lives in ``vout`` for the whole chunk — loaded from HBM once,
+    written back once.  Per edge k the halos come from the persistent
+    history scratches (see the module docstring): ``lefth[k]`` holds the
+    left neighbor's last LW columns *before* edge k (read, then
+    overwritten with this tile's own pre-edge-k boundary for the next
+    C-tile), and ``rowh`` holds the previous S-row's bottom UH rows per
+    edge, double-banked by row parity so the up-left corner read never
+    races the current row's writes.  S-row 0 replicates the clamp row V[0]
+    (the lane-shifted tile's row 0, whose corner columns came from the
+    left halo) exactly like the unfused kernels; C-tile 0's left halo is
+    garbage by construction — every read landing there is a state c <
+    offset_e, infeasible, masked to NEG.  Decision bits of the whole chunk
+    OR into one int32 word plane at bit ``bitpos[k]`` (global edge id mod
+    32).
 
     Batched reuse: the batched pipeline prepends the batch as grid axis 0
     (``grid_base=1`` shifts the (i, j) grid ids right) and passes the
     per-instance eligibility row as ``alw_ref`` — everything else is
-    byte-identical, because each instance re-initializes the body, bits,
-    and halo state at its own (i=0, j=0) corner: the body reloads from
-    ``vin`` every step, the clamp-row branch covers i=0 without reading
-    ``rowh``, and the j=0 ``lefth`` columns are only ever read by
-    infeasible (masked) states."""
-    Bs = vin_ref.shape[0]
-    Bc = vin_ref.shape[1]
+    identical, because each instance re-initializes the tile, bits, and
+    halo state at its own (i=0, j=0) corner: the tile reloads from ``vin``
+    every step, the clamp-row branch covers i=0 without using ``rowh``,
+    and the j=0 ``lefth`` columns are only ever read by infeasible
+    (masked) states."""
+    Bs, Bc = vin_ref.shape
+    lw = lefth_ref.shape[-1]
     i = pl.program_id(grid_base)
+    j = pl.program_id(grid_base + 1)
     rd = (i + 1) % 2  # rowh bank written by S-row i-1
     wr = i % 2
-    j = pl.program_id(grid_base + 1)
-    vpad_ref[pl.ds(u_max, Bs), pl.ds(off_max, Bc)] = vin_ref[:, :]
-    bits_ref[:, :] = jnp.zeros((Bs, Bc), jnp.int32)
+    col = pl.multiple_of(j * Bc, Bc)
+    # up-left corner: the last LW columns of tile j-1 (j == 0 clamps to
+    # garbage that only infeasible states c < offset_e ever read)
+    corner = pl.multiple_of(jnp.maximum(j * Bc - lw, 0), math.gcd(Bc, lw))
+    vout_ref[...] = vin_ref[...]
+    bits_ref[...] = jnp.zeros((Bs, Bc), jnp.int32)
 
     def edge_step(k, _):
         u = jnp.minimum(ups_ref[k], u_max)
         off = jnp.minimum(offs_ref[k], off_max)
         sig = sig_ref[k].astype(jnp.float32)
         bit = jnp.left_shift(jnp.int32(1), bitpos_ref[k])
-
-        if off_max:
-            # left halo for edge k, then this tile's own boundary history
-            # (pre-edge-k values) — read-then-write keeps one buffer legal
-            vpad_ref[pl.ds(u_max, Bs), :off_max] = \
-                lefth_ref[pl.ds(k, 1)][0]
-            lefth_ref[pl.ds(k, 1)] = \
-                vpad_ref[pl.ds(u_max, Bs), pl.ds(Bc, off_max)][None]
-        if u_max and multi_row:
-            @pl.when(i > 0)
-            def _up_from_history():
-                bank = rd * n_chunk + k
-                vpad_ref[:u_max, pl.ds(off_max, Bc)] = \
-                    rowh_ref[pl.ds(bank, 1), :, pl.ds(j * Bc, Bc)][0]
-                if off_max:
-                    # up-left corner: bottom-right of tile (i-1, j-1);
-                    # j == 0 clamps to garbage that only infeasible
-                    # states (c < offset_e) ever read
-                    start = jnp.maximum(j * Bc - off_max, 0)
-                    vpad_ref[:u_max, :off_max] = \
-                        rowh_ref[pl.ds(bank, 1), :, pl.ds(start, off_max)][0]
-
-            @pl.when(i == 0)
-            def _up_from_clamp_row():
-                # budgets below 0 clamp to V[0] — body row 0 across the
-                # full scratch width (the corner columns got the left
-                # halo's row 0, written just above)
-                vpad_ref[:u_max, :] = jnp.broadcast_to(
-                    vpad_ref[pl.ds(u_max, 1), :], (u_max, off_max + Bc))
+        X = vout_ref[...]
+        # left halo for edge k, then this tile's own boundary history
+        # (pre-edge-k values) — read-then-write keeps one buffer legal
+        left = lefth_ref[k]
+        lefth_ref[k] = X[:, Bc - lw:]
+        x = _lane_shift(X, left, off)
+        if multi_row:
+            uh = rowh_ref.shape[1]
+            bank = rd * n_chunk + k
+            up = _lane_shift(rowh_ref[bank, :, pl.ds(col, Bc)],
+                             rowh_ref[bank, :, pl.ds(corner, lw)], off)
             # bottom-rows history (pre-edge-k) for S-row i+1
-            rowh_ref[pl.ds(wr * n_chunk + k, 1), :, pl.ds(j * Bc, Bc)] = \
-                vpad_ref[pl.ds(Bs, u_max), pl.ds(off_max, Bc)][None]
-        elif u_max:
+            rowh_ref[wr * n_chunk + k, :, pl.ds(col, Bc)] = X[Bs - uh:, :]
+            take = _row_shift(x, _clamp_or(i == 0, x[0:1, :], up), u)
+        else:
             # single-S-row grid: no up neighbors exist, no history to keep
-            # — every tile just replicates its clamp row V[0]
-            vpad_ref[:u_max, :] = jnp.broadcast_to(
-                vpad_ref[pl.ds(u_max, 1), :], (u_max, off_max + Bc))
-
-        cur = vpad_ref[pl.ds(u_max, Bs), pl.ds(off_max, Bc)]
-        take = vpad_ref[pl.ds(u_max - u, Bs), pl.ds(off_max - off, Bc)] + sig
-        take = jnp.where(feas_ref[k, :][None, :] > 0, take, NEG)
+            take = _row_shift(x, None, u)
+        live = feas_ref[pl.ds(k, 1), :] > 0
         if alw_ref is not None:
-            take = jnp.where(alw_ref[k] > 0, take, NEG)
-        dec = (take > cur).astype(jnp.int32)
-        bits_ref[:, :] = bits_ref[:, :] | (dec * bit)
-        vpad_ref[pl.ds(u_max, Bs), pl.ds(off_max, Bc)] = \
-            jnp.maximum(cur, take)
+            live = live & (alw_ref[k] > 0)
+        Xn, dec = _edge_update(X, take, sig, live)
+        bits_ref[...] = bits_ref[...] | jnp.where(dec, bit, 0)
+        vout_ref[...] = Xn
         return 0
 
     jax.lax.fori_loop(0, n_chunk, edge_step, 0)
-    vout_ref[:, :] = vpad_ref[pl.ds(u_max, Bs), pl.ds(off_max, Bc)]
 
 
 def _chunk_word_masks(n_edges: int, block_e: int) -> np.ndarray:
@@ -821,6 +834,24 @@ def _chunk_word_masks(n_edges: int, block_e: int) -> np.ndarray:
     return masks.view(np.int32)
 
 
+def _check_block_e(block_e: int) -> None:
+    if not 1 <= block_e <= MAX_BLOCK_E:
+        raise ValueError(
+            f"block_e={block_e} outside [1, {MAX_BLOCK_E}]: a fused chunk "
+            "packs its decision bits into one int32 word plane, so "
+            "in-chunk bit positions (edge id mod 32) must stay distinct")
+
+
+def _fused_scratch(block_e, bs, block_c, u_max, off_max, Cp, multi_row):
+    """The halo-history scratches: ``rowh`` (a token buffer on a
+    single-S-row grid, which never reads it — so the large-C fused regime
+    is not charged 2·block_e·UH·Cp for it) and ``lefth``."""
+    uh, lw = _halo_widths(bs, block_c, u_max, off_max)
+    rowh_shape = (2 * block_e, uh, Cp) if multi_row else (1, 1, 1)
+    return [pltpu.VMEM(rowh_shape, jnp.float32),
+            pltpu.VMEM((block_e, bs, lw), jnp.float32)]
+
+
 def _dp_forward_fused(
     upsilon,
     sigma2,
@@ -836,14 +867,10 @@ def _dp_forward_fused(
     block_c: int,
     interpret: bool,
 ):
-    if not 1 <= block_e <= MAX_BLOCK_E:
-        raise ValueError(
-            f"block_e={block_e} outside [1, {MAX_BLOCK_E}]: a fused chunk "
-            "packs its decision bits into one int32 word plane, so "
-            "in-chunk bit positions (edge id mod 32) must stay distinct")
+    _check_block_e(block_e)
     S, C = v0.shape
     Cp = -(-C // block_c) * block_c
-    bs = S if block_s is None else block_s
+    bs = _pad_to(S, _SUBLANES) if block_s is None else block_s
     Sp = -(-S // bs) * bs
     V0 = jnp.pad(v0, ((0, Sp - S), (0, Cp - C)), constant_values=NEG)
     feas_p = jnp.pad(feasible, ((0, 0), (0, Cp - C)))  # pad states masked
@@ -874,9 +901,6 @@ def _dp_forward_fused(
                                u_max=u_max, off_max=off_max,
                                multi_row=multi_row)
     scalar_specs = [pl.BlockSpec(memory_space=pltpu.SMEM)] * 4
-    # a single-S-row grid never reads rowh — allocate a token buffer so
-    # the large-C fused regime is not charged 2·block_e·u_max·Cp for it
-    rowh_shape = (2 * block_e, max(u_max, 1), Cp) if multi_row else (1, 1, 1)
     call = pl.pallas_call(
         kernel,
         grid=(Sp // bs, Cp // block_c),
@@ -888,11 +912,8 @@ def _dp_forward_fused(
         ],
         out_specs=(pl.BlockSpec((bs, block_c), lambda i, j: (i, j)),
                    pl.BlockSpec((bs, block_c), lambda i, j: (i, j))),
-        scratch_shapes=[
-            pltpu.VMEM((u_max + bs, off_max + block_c), jnp.float32),
-            pltpu.VMEM(rowh_shape, jnp.float32),
-            pltpu.VMEM((block_e, bs, max(off_max, 1)), jnp.float32),
-        ],
+        scratch_shapes=_fused_scratch(block_e, bs, block_c, u_max, off_max,
+                                      Cp, multi_row),
         interpret=interpret,
     )
 
@@ -932,6 +953,11 @@ class _Lead0:
         self._ref[self._at(idx)] = val
 
 
+def _row(ref):
+    """The (block_e,) view of a (1, 1, block_e) per-instance SMEM row."""
+    return _Lead0(_Lead0(ref))
+
+
 def _batched_fused_kernel(
     ups_ref,
     offs_ref,
@@ -942,7 +968,6 @@ def _batched_fused_kernel(
     vin_ref,
     vout_ref,
     bits_ref,
-    vpad_ref,
     rowh_ref,
     lefth_ref,
     *,
@@ -956,12 +981,12 @@ def _batched_fused_kernel(
     fixed-leading-index views, with the (i, j) grid ids shifted one axis
     right (batch is the outermost grid dimension) and the per-instance
     ``allowed`` row masking every edge.  Scratches are per-instance state
-    and stay 2-D."""
+    and stay unbatched."""
     _fused_chunk_kernel(
-        _Lead0(ups_ref), offs_ref, _Lead0(sig_ref), bitpos_ref, feas_ref,
-        _Lead0(vin_ref), _Lead0(vout_ref), _Lead0(bits_ref), vpad_ref,
-        rowh_ref, lefth_ref, n_chunk=n_chunk, u_max=u_max, off_max=off_max,
-        multi_row=multi_row, grid_base=1, alw_ref=_Lead0(alw_ref))
+        _row(ups_ref), offs_ref, _row(sig_ref), bitpos_ref, feas_ref,
+        _Lead0(vin_ref), _Lead0(vout_ref), _Lead0(bits_ref), rowh_ref,
+        lefth_ref, n_chunk=n_chunk, u_max=u_max, off_max=off_max,
+        multi_row=multi_row, grid_base=1, alw_ref=_row(alw_ref))
 
 
 def _dp_forward_fused_batched(
@@ -980,15 +1005,11 @@ def _dp_forward_fused_batched(
     block_c: int,
     interpret: bool,
 ):
-    if not 1 <= block_e <= MAX_BLOCK_E:
-        raise ValueError(
-            f"block_e={block_e} outside [1, {MAX_BLOCK_E}]: a fused chunk "
-            "packs its decision bits into one int32 word plane, so "
-            "in-chunk bit positions (edge id mod 32) must stay distinct")
+    _check_block_e(block_e)
     B = upsilon.shape[0]
     S, C = v0.shape
     Cp = -(-C // block_c) * block_c
-    bs = S if block_s is None else block_s
+    bs = _pad_to(S, _SUBLANES) if block_s is None else block_s
     Sp = -(-S // bs) * bs
     V0 = jnp.broadcast_to(
         jnp.pad(v0, ((0, Sp - S), (0, Cp - C)), constant_values=NEG)[None],
@@ -1005,9 +1026,9 @@ def _dp_forward_fused_batched(
         return jnp.pad(arr[rev], pad_width).reshape((n_chunks, block_e)
                                                     + arr.shape[1:])
 
-    def _inst_chunks(arr):  # (B, E) → (n_chunks, B, block_e)
+    def _inst_chunks(arr):  # (B, E) → (n_chunks, B, 1, block_e)
         return (jnp.pad(arr[:, rev], ((0, 0), (0, pad_e)))
-                .reshape(B, n_chunks, block_e).transpose(1, 0, 2))
+                .reshape(B, n_chunks, 1, block_e).transpose(1, 0, 2, 3))
 
     e_ids = jnp.arange(n_edges - 1, -1, -1, dtype=jnp.int32)
     xs = (_inst_chunks(upsilon),
@@ -1022,8 +1043,7 @@ def _dp_forward_fused_batched(
     kernel = functools.partial(_batched_fused_kernel, n_chunk=block_e,
                                u_max=u_max, off_max=off_max,
                                multi_row=multi_row)
-    rowh_shape = (2 * block_e, max(u_max, 1), Cp) if multi_row else (1, 1, 1)
-    inst_row = pl.BlockSpec((1, block_e), lambda b, i, j: (b, 0),
+    inst_row = pl.BlockSpec((1, 1, block_e), lambda b, i, j: (b, 0, 0),
                             memory_space=pltpu.SMEM)
     call = pl.pallas_call(
         kernel,
@@ -1042,11 +1062,8 @@ def _dp_forward_fused_batched(
         out_specs=(
             pl.BlockSpec((1, bs, block_c), lambda b, i, j: (b, i, j)),
             pl.BlockSpec((1, bs, block_c), lambda b, i, j: (b, i, j))),
-        scratch_shapes=[
-            pltpu.VMEM((u_max + bs, off_max + block_c), jnp.float32),
-            pltpu.VMEM(rowh_shape, jnp.float32),
-            pltpu.VMEM((block_e, bs, max(off_max, 1)), jnp.float32),
-        ],
+        scratch_shapes=_fused_scratch(block_e, bs, block_c, u_max, off_max,
+                                      Cp, multi_row),
         interpret=interpret,
     )
 
@@ -1075,17 +1092,10 @@ def _dp_forward_blocked(
     block_c: int,
     interpret: bool,
 ):
-    if block_c < off_max:
-        raise ValueError(
-            f"block_c={block_c} < off_max={off_max}: the offset shift would "
-            "reach past the left-neighbor halo tile")
-    if block_s is not None and block_s < u_max:
-        raise ValueError(
-            f"block_s={block_s} < u_max={u_max}: the budget shift would "
-            "reach past the up-neighbor halo tile")
     S, C = v0.shape
     Cp = -(-C // block_c) * block_c
-    Sp = S if block_s is None else -(-S // block_s) * block_s
+    Sp = (_pad_to(S, _SUBLANES) if block_s is None
+          else -(-S // block_s) * block_s)
     # pad rows/columns sit at the high end of each axis: both shifts read
     # towards SMALLER indices, so real entries never read a pad entry (pad
     # rows/states compute garbage that is sliced away at the end)
@@ -1102,8 +1112,8 @@ def _dp_forward_blocked(
         V, dec = carry
         u, off, sig, feas_e, e = x
         Vn, bits = _edge_call(
-            V, feas_e[None, :], u[None], off[None], sig[None],
-            u_max=u_max, block_s=block_s, block_c=block_c,
+            V, feas_e[None, :], u[None], jnp.minimum(off, off_max)[None],
+            sig[None], u_max=u_max, block_s=block_s, block_c=block_c,
             interpret=interpret)
         w = e // 32
         word = jax.lax.dynamic_slice(dec, (w, 0, 0), (1, Sp, Cp))
@@ -1112,6 +1122,31 @@ def _dp_forward_blocked(
 
     (V, dec), _ = jax.lax.scan(body, (V0, dec0), xs)
     return V[:S, :C], dec[:, :S, :C]
+
+
+def _check_tiling(block_s, block_c, u_max: int, off_max: int, interpret: bool) -> None:
+    """Halo floors (always) and vreg-tile alignment (compiled mode)."""
+    if block_s is not None and block_c is None:
+        raise ValueError(
+            "block_s tiles the budget axis of the blocked pipeline and "
+            "needs block_c (pass block_c=C for a single full-width tile)")
+    if block_c is None:
+        return
+    if block_c < off_max:
+        raise ValueError(
+            f"block_c={block_c} < off_max={off_max}: the offset shift "
+            "would reach past the left-neighbor halo")
+    if block_s is not None and block_s < u_max:
+        raise ValueError(
+            f"block_s={block_s} < u_max={u_max}: the budget shift "
+            "would reach past the up-neighbor halo")
+    if not interpret and (block_c % _LANES
+                          or (block_s is not None and block_s % _SUBLANES)):
+        raise ValueError(
+            f"tiling (block_s={block_s}, block_c={block_c}) is not "
+            f"vreg-aligned: the compiled kernel needs block_c a multiple "
+            f"of {_LANES} and block_s a multiple of {_SUBLANES} "
+            "(choose_tiling only returns aligned tilings)")
 
 
 @functools.partial(jax.jit, static_argnames=("n_edges", "u_max", "off_max",
@@ -1147,23 +1182,12 @@ def dp_forward_pallas(
     ``interpret=None`` resolves via :func:`resolve_interpret` (compiled on
     TPU, interpreter elsewhere)."""
     interp = resolve_interpret(interpret)
-    if block_s is not None and block_c is None:
-        raise ValueError(
-            "block_s tiles the budget axis of the blocked pipeline and "
-            "needs block_c (pass block_c=C for a single full-width tile)")
     if block_e is not None and block_c is None:
         raise ValueError(
             "block_e fuses edges into the blocked pipeline's grid and "
             "needs block_c (pass block_c=C for a single full-width tile)")
+    _check_tiling(block_s, block_c, u_max, off_max, interp)
     if block_c is not None:
-        if block_c < off_max:
-            raise ValueError(
-                f"block_c={block_c} < off_max={off_max}: the offset shift "
-                "would reach past the left-neighbor halo")
-        if block_s is not None and block_s < u_max:
-            raise ValueError(
-                f"block_s={block_s} < u_max={u_max}: the budget shift "
-                "would reach past the up-neighbor halo")
         if block_e is not None:
             return _dp_forward_fused(
                 upsilon, sigma2, feasible, offsets, v0, n_edges=n_edges,
@@ -1173,26 +1197,11 @@ def dp_forward_pallas(
             upsilon, sigma2, feasible, offsets, v0, n_edges=n_edges,
             u_max=u_max, off_max=off_max, block_s=block_s, block_c=block_c,
             interpret=interp)
-    S, C = v0.shape
-    W = packed_words(n_edges)
-    kernel = functools.partial(_dp_kernel, n_edges=n_edges, u_max=u_max,
-                               off_max=off_max)
-    return pl.pallas_call(
-        kernel,
-        out_shape=(jax.ShapeDtypeStruct((S, C), jnp.float32),
-                   jax.ShapeDtypeStruct((W, S, C), jnp.int32)),
-        in_specs=[
-            pl.BlockSpec((n_edges,), lambda: (0,)),
-            pl.BlockSpec((n_edges,), lambda: (0,)),
-            pl.BlockSpec((n_edges,), lambda: (0,)),
-            pl.BlockSpec((n_edges, C), lambda: (0, 0)),
-            pl.BlockSpec((S, C), lambda: (0, 0)),
-        ],
-        out_specs=(pl.BlockSpec((S, C), lambda: (0, 0)),
-                   pl.BlockSpec((W, S, C), lambda: (0, 0, 0))),
-        scratch_shapes=[pltpu.VMEM((u_max + S, off_max + C), jnp.float32)],
-        interpret=interp,
-    )(upsilon, sigma2, offsets, feasible, v0)
+    V, dec = _whole_plane(
+        upsilon[None], sigma2[None], jnp.ones((1, n_edges), jnp.int32),
+        feasible, offsets, v0, n_edges=n_edges, u_max=u_max,
+        off_max=off_max, block_b=1, interpret=interp)
+    return V[0], dec[0]
 
 
 @functools.partial(jax.jit, static_argnames=("n_edges", "u_max", "off_max",
@@ -1238,14 +1247,11 @@ def dp_forward_pallas_batched(
             f"block_b={bb} outside [1, {B}]: the batch grid advances "
             "block_b instances per step and cannot exceed the batch")
     allowed = jnp.asarray(allowed, jnp.int32)
-    if block_s is not None and block_c is None:
-        raise ValueError(
-            "block_s tiles the budget axis of the blocked pipeline and "
-            "needs block_c (pass block_c=C for a single full-width tile)")
     if block_e is not None and block_c is None:
         raise ValueError(
             "block_e fuses edges into the blocked pipeline's grid and "
             "needs block_c (pass block_c=C for a single full-width tile)")
+    _check_tiling(block_s, block_c, u_max, off_max, interp)
     if block_c is not None:
         if block_e is None:
             raise ValueError(
@@ -1260,48 +1266,10 @@ def dp_forward_pallas_batched(
                 "outermost grid dimension with one instance per grid "
                 "step (block_b=1) — the per-instance halo histories are "
                 "what overflowed the VMEM budget in the first place")
-        if block_c < off_max:
-            raise ValueError(
-                f"block_c={block_c} < off_max={off_max}: the offset "
-                "shift would reach past the left-neighbor halo")
-        if block_s is not None and block_s < u_max:
-            raise ValueError(
-                f"block_s={block_s} < u_max={u_max}: the budget shift "
-                "would reach past the up-neighbor halo")
         return _dp_forward_fused_batched(
             upsilon, sigma2, allowed, feasible, offsets, v0,
             n_edges=n_edges, u_max=u_max, off_max=off_max, block_e=block_e,
             block_s=block_s, block_c=block_c, interpret=interp)
-    S, C = v0.shape
-    W = packed_words(n_edges)
-    Bp = -(-B // bb) * bb
-    pad = Bp - B
-    upsilon = jnp.pad(upsilon, ((0, pad), (0, 0)))
-    sigma2 = jnp.pad(sigma2, ((0, pad), (0, 0)))
-    allowed = jnp.pad(allowed, ((0, pad), (0, 0)))  # allowed ≡ 0 ⇒ inert
-    scratch = (pltpu.VMEM((1, u_max + S, off_max + C), jnp.float32)
-               if bb == 1
-               else pltpu.VMEM((bb, S, off_max + C), jnp.float32))
-    kernel = functools.partial(_dp_kernel_batched, n_edges=n_edges,
-                               u_max=u_max, off_max=off_max)
-    inst = pl.BlockSpec((bb, n_edges), lambda g: (g, 0),
-                        memory_space=pltpu.SMEM)
-    V, dec = pl.pallas_call(
-        kernel,
-        grid=(Bp // bb,),
-        out_shape=(jax.ShapeDtypeStruct((Bp, S, C), jnp.float32),
-                   jax.ShapeDtypeStruct((Bp, W, S, C), jnp.int32)),
-        in_specs=[
-            inst,  # Υ̂ rows
-            inst,  # Σ̂² rows
-            inst,  # allowed rows
-            pl.BlockSpec(memory_space=pltpu.SMEM),  # shared offsets
-            pl.BlockSpec((n_edges, C), lambda g: (0, 0)),
-            pl.BlockSpec((S, C), lambda g: (0, 0)),
-        ],
-        out_specs=(pl.BlockSpec((bb, S, C), lambda g: (g, 0, 0)),
-                   pl.BlockSpec((bb, W, S, C), lambda g: (g, 0, 0, 0))),
-        scratch_shapes=[scratch],
-        interpret=interp,
-    )(upsilon, sigma2, allowed, offsets, feasible, v0)
-    return V[:B], dec[:B]
+    return _whole_plane(upsilon, sigma2, allowed, feasible, offsets, v0,
+                        n_edges=n_edges, u_max=u_max, off_max=off_max,
+                        block_b=bb, interpret=interp)

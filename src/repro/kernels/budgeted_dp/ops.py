@@ -184,17 +184,17 @@ def _check_value_bound(sigma2, tables: DPTables) -> None:
 
 
 def _check_u_max(upsilon, u_max: int) -> None:
-    """The kernel clamps shifts at u_max for memory safety, which would
-    SILENTLY corrupt values if any Υ̂ exceeded it — turn a contract breach
-    into an error whenever the statistics are concrete (traced calls are
-    covered by the u_max_for_horizon bound test)."""
+    """The kernel clamps shifts at u_max (its tiled halos hold u_max
+    rows), which would SILENTLY corrupt values if any Υ̂ exceeded it — turn
+    a contract breach into an error whenever the statistics are concrete
+    (traced calls are covered by the u_max_for_horizon bound test)."""
     if isinstance(upsilon, jax.core.Tracer):
         return
     top = int(np.max(np.asarray(upsilon))) if np.size(upsilon) else 0
     if top > u_max:
         raise ValueError(
-            f"max Υ̂ = {top} exceeds u_max = {u_max}: the shift scratch is "
-            "too short and the kernel would clamp (wrong values). Pass "
+            f"max Υ̂ = {top} exceeds u_max = {u_max}: the kernel would "
+            "clamp the shift (wrong values). Pass "
             "u_max ≥ max Υ̂ (stats.u_max_for_horizon bounds the default "
             "schedules) or leave u_max=None.")
 
@@ -430,10 +430,10 @@ def solve_budgeted_dp_pallas(
       s_cap: static bound on s (value-row height − 1).
       s_limit: dynamic ξ(t)·m budget mask (s values beyond it are ignored
         by the eq.-17 selection).
-      u_max: static bound on max Υ̂ used to size the kernel's shift
-        scratch.  ``None`` uses the always-safe ``s_cap + 1`` padding;
-        callers that know the schedule bound
-        (``stats.u_max_for_horizon``) should pass it — the scratch shrinks
+      u_max: static bound on max Υ̂: the kernel clamps shifts to it and
+        sizes its tiled halos from it.  ``None`` uses the always-safe
+        ``s_cap + 1``; callers that know the schedule bound
+        (``stats.u_max_for_horizon``) should pass it — the halos shrink
         m-fold.  An undersized concrete bound raises instead of clamping.
       allowed: optional (E,) bool eligibility mask (arrival ∧ aliveness).
       interpret: ``None`` auto-resolves (compiled on TPU, Pallas

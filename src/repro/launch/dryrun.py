@@ -1,5 +1,8 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+# a virtual CPU mesh: never take an accelerator, so on a TPU host no cell
+# (nor the --all children, which re-run this module) competes for the chip
+os.environ["JAX_PLATFORMS"] = "cpu"
 # ^ MUST precede every other import (jax locks device count on first init).
 
 _DOC = """Multi-pod dry-run: lower + compile every (arch × shape × mesh) cell.
@@ -78,8 +81,6 @@ def _compile_cell(cfg, shape, mesh, rules, remat: str, microbatches: int):
 
 def _extract_costs(compiled, n_dev) -> dict:
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):  # older jax wraps it per-partition
-        cost = cost[0] if cost else {}
     coll = parse_collective_bytes(compiled.as_text(), n_dev)
     vals = {k: float(cost.get(k, 0.0)) for k in COST_KEYS}
     for kind, b in coll["by_kind"].items():

@@ -8,13 +8,8 @@ __all__ = ["make_production_mesh", "make_mesh_shape"]
 
 
 def _make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
-    # jax.sharding.AxisType (and make_mesh's axis_types kwarg) only exist on
-    # newer jax; older releases default every axis to Auto anyway.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -11,6 +11,7 @@ from __future__ import annotations
 
 import argparse
 import json
+import os
 import pathlib
 import subprocess
 import sys
@@ -100,7 +101,8 @@ def main():
                    "--arch", arch, "--shape", shape, "--mesh", mesh,
                    "--tag", tag] + extra
             print(">>", tag, flush=True)
-            subprocess.run(cmd)
+            # dry-run cells are virtual CPU meshes: keep them off the chip
+            subprocess.run(cmd, env=dict(os.environ, JAX_PLATFORMS="cpu"))
 
 
 if __name__ == "__main__":

@@ -545,32 +545,35 @@ class DispatchEngine:
             self._jit_cache["stream"] = fn
         return fn
 
+    def stream_arg_shapes(self, T: "int | None" = None, sharding=None):
+        """``(carry0, xs, salt)`` of the stream scan as ShapeDtypeStructs
+        at horizon ``T`` (default: the engine's), each placed on
+        ``sharding`` when given — what ``jax.jit(...).lower`` takes to
+        trace or compile the stream program without any data."""
+        T = self.T if T is None else int(T)
+        inst = self.inst
+
+        def spec(shape, dtype):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+        carry0 = jax.tree_util.tree_map(lambda a: spec(a.shape, a.dtype),
+                                        self._carry0())
+        xs = {"arrived": spec((T, inst.n_ports), jnp.bool_),
+              "noise": spec((T, inst.n_edges), jnp.float32),
+              "tb": spec((T, inst.n_edges), jnp.float32),
+              "speed": spec((T, inst.n_servers), jnp.float32),
+              "alive": spec((T, inst.n_servers), jnp.bool_),
+              "t": spec((T,), jnp.int32)}
+        return carry0, xs, spec((), jnp.uint32)
+
     def make_stream_jaxpr(self, T: int):
         """The traced (unjitted) stream jaxpr at horizon ``T`` — the
         launch-count test inspects it: one ``scan`` eqn regardless of T."""
-        save = self.T
-        try:
-            self.T = int(T)
-            xs = {"arrived": jax.ShapeDtypeStruct(
-                      (T, self.inst.n_ports), jnp.bool_),
-                  "noise": jax.ShapeDtypeStruct(
-                      (T, self.inst.n_edges), jnp.float32),
-                  "tb": jax.ShapeDtypeStruct(
-                      (T, self.inst.n_edges), jnp.float32),
-                  "speed": jax.ShapeDtypeStruct(
-                      (T, self.inst.n_servers), jnp.float32),
-                  "alive": jax.ShapeDtypeStruct(
-                      (T, self.inst.n_servers), jnp.bool_),
-                  "t": jax.ShapeDtypeStruct((T,), jnp.int32)}
+        def run_scan(carry0, xs, salt):
+            return jax.lax.scan(
+                lambda c, x: self._scan_body(c, x, salt), carry0, xs)
 
-            def run_scan(carry0, xs, salt):
-                return jax.lax.scan(
-                    lambda c, x: self._scan_body(c, x, salt), carry0, xs)
-
-            return jax.make_jaxpr(run_scan)(
-                self._carry0(), xs, jnp.uint32(0))
-        finally:
-            self.T = save
+        return jax.make_jaxpr(run_scan)(*self.stream_arg_shapes(T))
 
     def _outputs(self, ys, carry, mode, solve_stats=None, failures=None):
         ys = {k: np.asarray(v) for k, v in ys.items()}

@@ -94,7 +94,8 @@ _PP_SCRIPT = textwrap.dedent("""
 
 
 def test_gpipe_matches_sequential():
-    env = dict(os.environ, PYTHONPATH="src")
+    # a virtual CPU mesh: the child never takes an accelerator
+    env = dict(os.environ, PYTHONPATH="src", JAX_PLATFORMS="cpu")
     out = subprocess.run([sys.executable, "-c", _PP_SCRIPT], env=env,
                          capture_output=True, text=True, timeout=600,
                          cwd=os.path.dirname(os.path.dirname(__file__)))
@@ -116,7 +117,8 @@ _MESH_SCRIPT = textwrap.dedent("""
 
 
 def test_production_meshes_build():
-    env = dict(os.environ, PYTHONPATH="src")
+    # a virtual CPU mesh: the child never takes an accelerator
+    env = dict(os.environ, PYTHONPATH="src", JAX_PLATFORMS="cpu")
     out = subprocess.run([sys.executable, "-c", _MESH_SCRIPT], env=env,
                          capture_output=True, text=True, timeout=600,
                          cwd=os.path.dirname(os.path.dirname(__file__)))

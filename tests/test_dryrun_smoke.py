@@ -29,7 +29,8 @@ _SCRIPT = textwrap.dedent("""
 
 
 def test_dryrun_cell_multi_pod_reduced_depth():
-    env = dict(os.environ, PYTHONPATH="src")
+    # a virtual CPU mesh: the child never takes an accelerator
+    env = dict(os.environ, PYTHONPATH="src", JAX_PLATFORMS="cpu")
     out = subprocess.run([sys.executable, "-c", _SCRIPT], env=env,
                          capture_output=True, text=True, timeout=1500,
                          cwd=os.path.dirname(os.path.dirname(__file__)))

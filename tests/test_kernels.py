@@ -1,5 +1,6 @@
 """Per-kernel allclose sweeps against the pure-jnp oracles."""
 import jax
+import jax.extend.core as jex_core
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -504,14 +505,18 @@ def _iter_eqns(jaxpr):
         for val in eqn.params.values():
             vals = val if isinstance(val, (tuple, list)) else (val,)
             for v in vals:
-                if isinstance(v, jax.core.ClosedJaxpr):
+                if isinstance(v, jex_core.ClosedJaxpr):
                     yield from _iter_eqns(v.jaxpr)
-                elif isinstance(v, jax.core.Jaxpr):
+                elif isinstance(v, jex_core.Jaxpr):
                     yield from _iter_eqns(v)
 
 
 def _pallas_calls(jaxpr):
     return [e for e in _iter_eqns(jaxpr) if e.primitive.name == "pallas_call"]
+
+
+def _lanes(C):
+    return -(-C // 128) * 128
 
 
 def test_batched_vmap_emits_single_launch_with_shared_tables():
@@ -541,9 +546,10 @@ def test_batched_vmap_emits_single_launch_with_shared_tables():
     calls = _pallas_calls(jaxpr.jaxpr)
     assert len(calls) == 1
     shapes = [tuple(v.aval.shape) for v in calls[0].invars]
-    assert (E, C) in shapes  # feasibility plane, shared
-    assert (B, E, C) not in shapes  # never replicated per seed
-    assert (B, E) in shapes  # per-instance statistics
+    Cp = _lanes(C)  # the kernel's plane is padded to whole 128-lane tiles
+    assert (E, Cp) in shapes  # feasibility plane, shared
+    assert (B, E, Cp) not in shapes  # never replicated per seed
+    assert (B, 1, E) in shapes  # per-instance statistics (SMEM rows)
 
 
 def test_simulate_batch_one_launch_per_slot():
@@ -568,9 +574,9 @@ def test_simulate_batch_one_launch_per_slot():
         env_mod._instance_arrays(inst), keys, params)
     calls = _pallas_calls(jaxpr.jaxpr)
     assert len(calls) == 1
-    E, C = inst.n_edges, tables.n_states
+    E, Cp = inst.n_edges, _lanes(tables.n_states)
     shapes = [tuple(v.aval.shape) for v in calls[0].invars]
-    assert (E, C) in shapes and (B, E, C) not in shapes
+    assert (E, Cp) in shapes and (B, E, Cp) not in shapes
 
 
 def test_choose_tiling_batched_decision_table():
@@ -586,9 +592,9 @@ def test_choose_tiling_batched_decision_table():
     # a degenerate fleet of one stays on the whole-plane kernel
     assert choose_tiling(110, 27, 40, 9, 13, batch=1) == \
         (1, None, None, None)
-    # taller planes: the fleet splits (4, then 2, then 1 per step) while
+    # taller planes: the fleet splits (8, then 4, then 1 per step) while
     # every instance's plane stays whole — batch shrinks FIRST
-    for S, bb_want in ((256, 4), (512, 2), (1024, 1)):
+    for S, bb_want in ((128, 8), (256, 4), (512, 1)):
         bb, be, bs, bc = choose_tiling(S, 512, 16, 4, 73, batch=32)
         assert (bb, be, bs, bc) == (bb_want, None, None, None)
         assert batched_vmem_bytes(S, 512, 16, 4, 73, bb) <= \
