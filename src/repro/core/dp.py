@@ -191,18 +191,23 @@ def solve_budgeted_dp(
     next_state = jnp.asarray(tables.next_state)
     E = upsilon.shape[0]
 
-    V, decisions = _dp_forward(upsilon, sigma2, feasible, next_state, s_cap)
+    # the esdp.* scopes name the stages as the Pallas backend's do
+    with jax.named_scope("esdp.forward"):
+        V, decisions = _dp_forward(upsilon, sigma2, feasible, next_state,
+                                   s_cap)
 
-    v_row = V[:, tables.full_state]  # (S,)
-    s_vals = jnp.arange(s_cap + 1, dtype=jnp.int32)
-    # feasible ⇔ value ≥ 0: Σ̂² ≥ 0 so reachable values are non-negative,
-    # while NEG-seeded chains stay < 0 for any partial sum < 2²⁹ (same
-    # classification the Pallas backend uses — keeps s* bit-identical).
-    ok = (v_row >= 0) & (s_vals <= s_limit)
-    score = s_vals.astype(jnp.float32) + jnp.sqrt(
-        jnp.maximum(v_row, 0).astype(jnp.float32))
-    score = jnp.where(ok, score, FNEG)
-    s_star = jnp.argmax(score).astype(jnp.int32)
+    with jax.named_scope("esdp.select"):
+        v_row = V[:, tables.full_state]  # (S,)
+        s_vals = jnp.arange(s_cap + 1, dtype=jnp.int32)
+        # feasible ⇔ value ≥ 0: Σ̂² ≥ 0 so reachable values are
+        # non-negative, while NEG-seeded chains stay < 0 for any partial
+        # sum < 2²⁹ (same classification the Pallas backend uses — keeps
+        # s* bit-identical).
+        ok = (v_row >= 0) & (s_vals <= s_limit)
+        score = s_vals.astype(jnp.float32) + jnp.sqrt(
+            jnp.maximum(v_row, 0).astype(jnp.float32))
+        score = jnp.where(ok, score, FNEG)
+        s_star = jnp.argmax(score).astype(jnp.int32)
 
     def back_body(e, carry):
         s, cs, x = carry
@@ -213,8 +218,9 @@ def solve_budgeted_dp(
         return (jnp.where(d, s_new, s), jnp.where(d, cs_new, cs), x)
 
     x0 = jnp.zeros(E, dtype=jnp.int32)
-    _, _, x = jax.lax.fori_loop(
-        0, E, back_body, (s_star, jnp.int32(tables.full_state), x0))
+    with jax.named_scope("esdp.backtrack"):
+        _, _, x = jax.lax.fori_loop(
+            0, E, back_body, (s_star, jnp.int32(tables.full_state), x0))
     return x, {"s_star": s_star, "value_row": v_row}
 
 
@@ -224,6 +230,11 @@ def oracle_knapsack(values, tables: DPTables, take_allowed):
     ``take_allowed`` masks edges of ports with no arrival (constraint (2)).
     Exact DP over capacity states × edges; float32 objective.
     """
+    with jax.named_scope("esdp.oracle"):
+        return _oracle_knapsack(values, tables, take_allowed)
+
+
+def _oracle_knapsack(values, tables: DPTables, take_allowed):
     feasible = jnp.asarray(tables.feasible)
     next_state = jnp.asarray(tables.next_state)
     E = values.shape[0]

@@ -604,6 +604,7 @@ def _whole_plane(
         out_specs=(pl.BlockSpec((bb, Sp, Cp), lambda g: (g, 0, 0)),
                    pl.BlockSpec((bb, W, Sp, Cp), lambda g: (g, 0, 0, 0))),
         interpret=interpret,
+        name="dp_forward",
     )(upsilon[:, None], sigma2[:, None], allowed[:, None], offsets, feas_p,
       v0p)
     return V[:B, :S, :C], dec[:B, :, :S, :C]
@@ -701,6 +702,7 @@ def _edge_call(
             out_specs=(pl.BlockSpec((Sp, block_c), lambda j: (0, j)),
                        pl.BlockSpec((Sp, block_c), lambda j: (0, j))),
             interpret=interpret,
+            name="dp_forward_edge",
         )(u1, off1, sig1, feas_e, V, V)
     kernel = functools.partial(_edge_stile_kernel, u_max=u_max)
 
@@ -722,6 +724,7 @@ def _edge_call(
         out_specs=(pl.BlockSpec((block_s, block_c), lambda i, j: (i, j)),
                    pl.BlockSpec((block_s, block_c), lambda i, j: (i, j))),
         interpret=interpret,
+        name="dp_forward_edge_tiled",
     )(u1, off1, sig1, feas_e, V, V, V, V)
 
 
@@ -915,6 +918,7 @@ def _dp_forward_fused(
         scratch_shapes=_fused_scratch(block_e, bs, block_c, u_max, off_max,
                                       Cp, multi_row),
         interpret=interpret,
+        name="dp_forward_fused",
     )
 
     def body(carry, x):
@@ -1065,6 +1069,7 @@ def _dp_forward_fused_batched(
         scratch_shapes=_fused_scratch(block_e, bs, block_c, u_max, off_max,
                                       Cp, multi_row),
         interpret=interpret,
+        name="dp_forward_fused_batched",
     )
 
     def body(carry, x):

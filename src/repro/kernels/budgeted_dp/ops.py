@@ -223,19 +223,24 @@ def _solve(
     S = s_cap + 1
     v0 = jnp.full((S, feasible.shape[1]), NEG, jnp.float32).at[0, :].set(0.0)
 
-    V, decisions = dp_forward_pallas(
-        upsilon, sigma2, feasible, offsets, v0,
-        n_edges=E, u_max=u_max, off_max=off_max, interpret=interpret,
-        block_c=block_c, block_s=block_s, block_e=block_e)
+    with jax.named_scope("esdp.forward"):
+        V, decisions = dp_forward_pallas(
+            upsilon, sigma2, feasible, offsets, v0,
+            n_edges=E, u_max=u_max, off_max=off_max, interpret=interpret,
+            block_c=block_c, block_s=block_s, block_e=block_e)
 
-    v_row = V[:, full_state]
-    s_vals = jnp.arange(S, dtype=jnp.int32)
-    # feasible ⇔ value ≥ 0: Σ̂² ≥ 0 so reachable values are non-negative,
-    # while NEG-seeded chains stay < 0 for any partial sum < 2²⁴ (the
-    # VALUE_BOUND contract) — sharper than thresholding at NEG/2.
-    ok = (v_row >= 0) & (s_vals <= s_limit)
-    score = s_vals.astype(jnp.float32) + jnp.sqrt(jnp.maximum(v_row, 0.0))
-    s_star = jnp.argmax(jnp.where(ok, score, -jnp.inf)).astype(jnp.int32)
+    with jax.named_scope("esdp.select"):
+        v_row = V[:, full_state]
+        s_vals = jnp.arange(S, dtype=jnp.int32)
+        # feasible ⇔ value ≥ 0: Σ̂² ≥ 0 so reachable values are
+        # non-negative, while NEG-seeded chains stay < 0 for any partial
+        # sum < 2²⁴ (the VALUE_BOUND contract) — sharper than
+        # thresholding at NEG/2.
+        ok = (v_row >= 0) & (s_vals <= s_limit)
+        score = s_vals.astype(jnp.float32) + jnp.sqrt(
+            jnp.maximum(v_row, 0.0))
+        s_star = jnp.argmax(jnp.where(ok, score, -jnp.inf)).astype(
+            jnp.int32)
 
     # backtrack on offset arithmetic: the per-edge constants (Υ̂, offset,
     # word id, bit id) stream in as scan inputs, so the loop body is scalar
@@ -253,9 +258,10 @@ def _solve(
         cs = jnp.where(taken, cs - off, cs)
         return (s, cs), d
 
-    (_, _), x = jax.lax.scan(
-        back, (s_star, jnp.int32(full_state)),
-        (upsilon, offsets, e_ids // 32, e_ids % 32))
+    with jax.named_scope("esdp.backtrack"):
+        (_, _), x = jax.lax.scan(
+            back, (s_star, jnp.int32(full_state)),
+            (upsilon, offsets, e_ids // 32, e_ids % 32))
     return x, s_star, v_row
 
 
@@ -292,18 +298,21 @@ def _solve_batched(
     S = s_cap + 1
     v0 = jnp.full((S, feasible.shape[1]), NEG, jnp.float32).at[0, :].set(0.0)
 
-    V, decisions = dp_forward_pallas_batched(
-        upsilon, sigma2, allowed, feasible, offsets, v0,
-        n_edges=E, u_max=u_max, off_max=off_max, interpret=interpret,
-        block_b=block_b, block_c=block_c, block_s=block_s, block_e=block_e)
+    with jax.named_scope("esdp.forward"):
+        V, decisions = dp_forward_pallas_batched(
+            upsilon, sigma2, allowed, feasible, offsets, v0,
+            n_edges=E, u_max=u_max, off_max=off_max, interpret=interpret,
+            block_b=block_b, block_c=block_c, block_s=block_s,
+            block_e=block_e)
 
-    v_row = V[:, :, full_state]  # (B, S)
-    s_vals = jnp.arange(S, dtype=jnp.int32)
-    ok = (v_row >= 0) & (s_vals[None, :] <= s_limit[:, None])
-    score = (s_vals[None, :].astype(jnp.float32)
-             + jnp.sqrt(jnp.maximum(v_row, 0.0)))
-    s_star = jnp.argmax(jnp.where(ok, score, -jnp.inf),
-                        axis=1).astype(jnp.int32)
+    with jax.named_scope("esdp.select"):
+        v_row = V[:, :, full_state]  # (B, S)
+        s_vals = jnp.arange(S, dtype=jnp.int32)
+        ok = (v_row >= 0) & (s_vals[None, :] <= s_limit[:, None])
+        score = (s_vals[None, :].astype(jnp.float32)
+                 + jnp.sqrt(jnp.maximum(v_row, 0.0)))
+        s_star = jnp.argmax(jnp.where(ok, score, -jnp.inf),
+                            axis=1).astype(jnp.int32)
 
     e_ids = jnp.arange(E, dtype=jnp.int32)
 
@@ -319,9 +328,10 @@ def _solve_batched(
         cs = jnp.where(taken, cs - off, cs)
         return (s, cs), d
 
-    (_, _), x = jax.lax.scan(
-        back, (s_star, jnp.full((B,), full_state, jnp.int32)),
-        (upsilon.T, offsets, e_ids // 32, e_ids % 32))
+    with jax.named_scope("esdp.backtrack"):
+        (_, _), x = jax.lax.scan(
+            back, (s_star, jnp.full((B,), full_state, jnp.int32)),
+            (upsilon.T, offsets, e_ids // 32, e_ids % 32))
     return x.T, s_star, v_row
 
 

@@ -48,6 +48,7 @@ from __future__ import annotations
 
 import copy
 import dataclasses
+import itertools
 from typing import Callable, Optional
 
 import jax
@@ -60,6 +61,7 @@ from ..core.dp import oracle_knapsack
 from ..core.env import Scenario
 from ..core.graph import Instance
 from ..core.solvers import Solver, get_solver
+from . import telemetry
 
 __all__ = ["BACKPRESSURE_POLICIES", "LOCKSTEP_POLICIES", "VariantSpec",
            "EngineConfig", "EngineOutput", "DispatchEngine",
@@ -267,6 +269,7 @@ class DispatchEngine:
                     v.solver.scope = v.name  # per-variant stats scoping
                 self._solvers.append(v.solver)
         self._jit_cache: dict = {}
+        self._calls = itertools.count()  # call ids of the telemetry spans
 
     # -- host-side randomness ------------------------------------------
     def _streams(self, seed: "int | None" = None):
@@ -287,16 +290,21 @@ class DispatchEngine:
             (self.T, inst.n_edges)).astype(np.float32)
         return arrivals, noise, tb
 
-    def _xs(self, streams):
+    def _inputs(self, streams, salt):
+        """The scan's ``(xs, salt)`` put on the device.  A leading seed
+        axis on the three streams and on ``salt`` rides through (the
+        schedule and the slot index are shared).  Counts the bytes put
+        under ``engine.h2d_bytes``."""
         arrivals, noise, tb = streams
-        return {
-            "arrived": jnp.asarray(arrivals),
-            "noise": jnp.asarray(noise),
-            "tb": jnp.asarray(tb),
-            "speed": jnp.asarray(self.speed),
-            "alive": jnp.asarray(self.alive),
-            "t": jnp.arange(self.T, dtype=jnp.int32),
-        }
+        host = {"arrived": arrivals, "noise": noise, "tb": tb,
+                "speed": self.speed, "alive": self.alive,
+                "salt": np.asarray(salt, np.uint32)}
+        if telemetry.recording():
+            telemetry.count("engine.h2d_bytes", telemetry.host_bytes(host))
+        xs = {k: jnp.asarray(v) for k, v in host.items()}
+        salt = xs.pop("salt")
+        xs["t"] = jnp.arange(self.T, dtype=jnp.int32)
+        return xs, salt
 
     def _carry0(self):
         inst, V = self.inst, len(self.config.variants)
@@ -423,9 +431,10 @@ class DispatchEngine:
         A, c, port, server, cost, mu, sigma, port_ok, cum_w = self._consts()
         spec = self.config.variants[v]
         if spec.kind == "esdp":
-            ups, sig, _, s_lim = stats_mod.scale_statistics(
-                vhat_v, n_v, (t0 + 1).astype(jnp.float32), self.m,
-                g_fn=self.g_fn)
+            with jax.named_scope("esdp.statistics"):
+                ups, sig, _, s_lim = stats_mod.scale_statistics(
+                    vhat_v, n_v, (t0 + 1).astype(jnp.float32), self.m,
+                    g_fn=self.g_fn)
             x, _ = self._solvers[v](ups, sig, self.tables, self.s_cap,
                                     s_lim, allowed=elig_v, u_max=self.u_max)
             return x
@@ -514,20 +523,25 @@ class DispatchEngine:
 
     # -- stream mode ----------------------------------------------------
     def _scan_body(self, carry, xs_t, salt):
+        # the esdp.* scopes name each stage's ops in the compiled program
+        # (op_name metadata), so a device profile splits the slot by stage
         V = len(self.config.variants)
         suspicious = jnp.zeros(self.inst.n_servers, bool)
-        queue2, counts, age, elig, vhat = self._slot_pre(
-            carry["queue"], carry["n"], carry["sumz"], xs_t["arrived"],
-            xs_t["alive"], suspicious, xs_t["t"], salt)
+        with jax.named_scope("esdp.admission"):
+            queue2, counts, age, elig, vhat = self._slot_pre(
+                carry["queue"], carry["n"], carry["sumz"], xs_t["arrived"],
+                xs_t["alive"], suspicious, xs_t["t"], salt)
         x_raw = jnp.stack([
             self._variant_x(v, elig[v], vhat[v], carry["n"][v], age,
                             xs_t["tb"], xs_t["t"])
             for v in range(V)])
-        xv, x, served, queue3, load2, qlen = self._slot_dispatch(
-            queue2, carry["load"], x_raw, elig, vhat, age)
-        n2, sumz2, sw, sw_v, regret, regret_v, share = self._slot_account(
-            carry["n"], carry["sumz"], xv, elig, xs_t["noise"],
-            xs_t["speed"])
+        with jax.named_scope("esdp.packing"):
+            xv, x, served, queue3, load2, qlen = self._slot_dispatch(
+                queue2, carry["load"], x_raw, elig, vhat, age)
+        with jax.named_scope("esdp.account"):  # esdp.oracle nests inside
+            n2, sumz2, sw, sw_v, regret, regret_v, share = (
+                self._slot_account(carry["n"], carry["sumz"], xv, elig,
+                                   xs_t["noise"], xs_t["speed"]))
         carry2 = {"queue": queue3, "n": n2, "sumz": sumz2, "load": load2}
         ys = dict(counts, sw=sw, sw_v=sw_v, regret=regret,
                   regret_v=regret_v, share=share, qlen=qlen,
@@ -535,13 +549,16 @@ class DispatchEngine:
                   dispatched_v=jnp.sum(xv, axis=1))
         return carry2, ys
 
-    def _stream_fn(self):
+    def _stream_scan(self, carry0, xs, salt):
+        return jax.lax.scan(lambda c, x: self._scan_body(c, x, salt),
+                            carry0, xs)
+
+    def _stream_fn(self) -> "_StreamCall":
+        """The stream program: the jitted ``(carry, xs, salt) -> (carry,
+        ys)`` scan, timed per call as ``engine.launch``."""
         fn = self._jit_cache.get("stream")
         if fn is None:
-            def run_scan(carry0, xs, salt):
-                return jax.lax.scan(
-                    lambda c, x: self._scan_body(c, x, salt), carry0, xs)
-            fn = jax.jit(run_scan)
+            fn = _StreamCall(jax.jit(self._stream_scan), self._calls)
             self._jit_cache["stream"] = fn
         return fn
 
@@ -569,11 +586,7 @@ class DispatchEngine:
     def make_stream_jaxpr(self, T: int):
         """The traced (unjitted) stream jaxpr at horizon ``T`` — the
         launch-count test inspects it: one ``scan`` eqn regardless of T."""
-        def run_scan(carry0, xs, salt):
-            return jax.lax.scan(
-                lambda c, x: self._scan_body(c, x, salt), carry0, xs)
-
-        return jax.make_jaxpr(run_scan)(*self.stream_arg_shapes(T))
+        return jax.make_jaxpr(self._stream_scan)(*self.stream_arg_shapes(T))
 
     def _outputs(self, ys, carry, mode, solve_stats=None, failures=None):
         ys = {k: np.asarray(v) for k, v in ys.items()}
@@ -622,15 +635,28 @@ class DispatchEngine:
         if streams is None:
             streams = self._streams(seed)
         salt = self._route_salt(seed)
-        if mode == "stream":
-            if self.failures is not None:
-                raise ValueError("failure settlement is host-side: use "
-                                 'mode="lockstep" (or "auto")')
-            carry, ys = self._stream_fn()(self._carry0(), self._xs(streams),
-                                          jnp.uint32(salt))
-            return self._outputs(ys, carry, "stream",
-                                 solve_stats=self._wrapper_stats())
-        return self._run_lockstep(streams, salt)
+        if mode == "lockstep":
+            return self._run_lockstep(streams, salt)
+        if self.failures is not None:
+            raise ValueError("failure settlement is host-side: use "
+                             'mode="lockstep" (or "auto")')
+        # spans: engine.run > inputs (explicit puts), launch (dispatch),
+        # wait (device), fetch (read-back and assembly)
+        call = next(self._calls)
+        with telemetry.span("engine.run", call):
+            with telemetry.span("engine.inputs", call):
+                carry0 = self._carry0()
+                xs, salt = self._inputs(streams, salt)
+            carry, ys = self._stream_fn()(carry0, xs, salt, call=call)
+            with telemetry.span("engine.wait", call):
+                jax.block_until_ready((carry, ys))
+            with telemetry.span("engine.fetch", call):
+                if telemetry.recording():
+                    telemetry.count("engine.d2h_bytes", sum(
+                        a.nbytes for a in jax.tree_util.tree_leaves(
+                            (ys, carry["n"], carry["sumz"]))))
+                return self._outputs(ys, carry, "stream",
+                                     solve_stats=self._wrapper_stats())
 
     def run_batch(self, seeds, mode: str = "stream") -> "list[EngineOutput]":
         """One trace per seed, fleet-batched: ONE vmapped jitted scan, so
@@ -646,28 +672,19 @@ class DispatchEngine:
                                       "and single-seed; loop run()")
         seeds = [int(s) for s in seeds]
         streams = [self._streams(s) for s in seeds]
-        xs = {
-            "arrived": jnp.asarray(np.stack([s[0] for s in streams])),
-            "noise": jnp.asarray(np.stack([s[1] for s in streams])),
-            "tb": jnp.asarray(np.stack([s[2] for s in streams])),
-            "speed": jnp.asarray(self.speed),
-            "alive": jnp.asarray(self.alive),
-            "t": jnp.arange(self.T, dtype=jnp.int32),
-        }
+        xs, salts = self._inputs(
+            [np.stack(k) for k in zip(*streams)],
+            [self._route_salt(s) for s in seeds])
         fn = self._jit_cache.get("stream_batch")
         if fn is None:
-            def run_scan(carry0, xs, salt):
-                return jax.lax.scan(
-                    lambda c, x: self._scan_body(c, x, salt), carry0, xs)
             fn = jax.jit(jax.vmap(
-                run_scan,
+                self._stream_scan,
                 in_axes=(0, {"arrived": 0, "noise": 0, "tb": 0,
                              "speed": None, "alive": None, "t": None}, 0)))
             self._jit_cache["stream_batch"] = fn
         B = len(seeds)
         carry0 = jax.tree_util.tree_map(
             lambda a: jnp.broadcast_to(a, (B,) + a.shape), self._carry0())
-        salts = jnp.asarray([self._route_salt(s) for s in seeds], jnp.uint32)
         carry, ys = fn(carry0, xs, salts)
         return [self._outputs(
                     jax.tree_util.tree_map(lambda a: a[b], ys),
@@ -839,6 +856,27 @@ class DispatchEngine:
                   "sumz": jnp.asarray(sumz2), "load": load2}
         return (float(sw_v.sum()), sw_v, float(regret), regret_v, share,
                 carry2, fr.suspicious.copy())
+
+
+class _StreamCall:
+    """The jitted stream scan, each call timed as ``engine.launch``: the
+    implicit copy of host-array arguments plus the dispatch, up to the
+    return (the device may still be running).  ``call`` is the span id;
+    a call of its own draws the engine's next.  ``lower`` is the jitted
+    scan's."""
+
+    def __init__(self, fn, calls):
+        self._fn, self._calls = fn, calls
+        self.lower = fn.lower
+
+    def __call__(self, carry0, xs, salt, call=None):
+        if call is None:
+            call = next(self._calls)
+        if telemetry.recording():
+            telemetry.count("engine.h2d_bytes",
+                            telemetry.host_bytes((carry0, xs, salt)))
+        with telemetry.span("engine.launch", call):
+            return self._fn(carry0, xs, salt)
 
 
 # ----------------------------------------------------------------------

@@ -139,3 +139,24 @@ def test_engine_stream_program_compiles_for_v5e(one_chip, compiled_pallas):
         compiled = eng._stream_fn().lower(
             *eng.stream_arg_shapes(sharding=one_chip)).compile()
         assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_engine_stream_program_names_its_stages_for_v5e(one_chip, compiled_pallas):
+    """At Fig. 5's largest graph (the fused S-tiled kernel grid, T = 100)
+    the compiled stream program names the kernel ``dp_forward_fused`` and
+    carries every stage's ``esdp.*`` scope in its op_name metadata, so a
+    profile of the chip splits a slot by stage."""
+    from repro.core.graph import generate_instance
+    from repro.sched import DispatchEngine, EngineConfig, VariantSpec
+
+    inst = generate_instance(seed=1, n_ports=16, n_servers=160,
+                             edge_prob=0.1)
+    eng = DispatchEngine(inst, 100, EngineConfig(
+        variants=(VariantSpec("esdp", solver=compiled_pallas),)))
+    text = eng._stream_fn().lower(
+        *eng.stream_arg_shapes(sharding=one_chip)).compile().as_text()
+    kernels = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert kernels and all("/dp_forward_fused/" in ln for ln in kernels)
+    stages = ("admission", "statistics", "forward", "select", "backtrack",
+              "packing", "account", "oracle")
+    assert not [s for s in stages if f"/esdp.{s}/" not in text]
