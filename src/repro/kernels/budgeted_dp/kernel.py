@@ -77,8 +77,12 @@ iterations):
 Decision bits for the whole chunk pack into ONE (S, C) int32 word-plane
 per tile (bit Υ = global edge id mod 32 — legal because block_e ≤ 32 keeps
 in-chunk bit positions distinct); the host scan ORs each chunk word into
-the packed (⌈E/32⌉, S, C) decision planes through static per-chunk word
-masks, which also handles chunks straddling a 32-bit word boundary.
+only the packed word planes that chunk owns, one (S, C) plane read and
+written in place through a dynamic slice of the scan's (⌈E/32⌉·S, C)
+carry.
+When block_e divides 32 the inert pad edges take the top edge ids, so every
+chunk lies inside one 32-bit word; otherwise a chunk may straddle two words
+and the scan merges into both, through static per-chunk word masks.
 
 ``dp_forward_pallas_batched`` runs a FLEET of B independent solves in one
 pallas_call.  The batch rides the grid: ``block_b`` instances advance per
@@ -280,7 +284,8 @@ def modeled_hbm_bytes(
     blocks read/written by the pallas pipeline, the per-step feasibility
     blocks, and the host-side merge of decision bits into the packed
     (⌈E/32⌉, S, C) words (a read-modify-write of one word plane per edge
-    for the scan pipelines, of all W planes per chunk for the fused one).
+    for the scan pipelines, of the one or two word planes each chunk owns
+    for the fused one — see :func:`_chunk_layout`).
     The whole-plane kernel streams everything exactly once.  This is the
     ``hbm_bytes_streamed`` model `benchmarks/dp_bench.py` records — a
     traffic model for the perf trend, not a measurement.
@@ -301,10 +306,12 @@ def modeled_hbm_bytes(
         views = 2 if block_s is None else 4
         per_edge = (views + 2) * plane + 2 * plane + 4 * Cp
         return n_edges * per_edge
-    # fused: each chunk streams the plane in/out ONCE, plus the chunk's
-    # bits plane and the W-word packed-decision merge
-    n_chunks = -(-n_edges // block_e)
-    per_chunk = (1 + 2) * plane + (1 + 2 * W) * plane + 4 * block_e * Cp
+    # fused: each chunk streams the plane in/out ONCE and writes its bits
+    # plane; the merge reads those bits and read-modify-writes each word
+    # plane the chunk owns (3 planes per owned word)
+    _, _, words, _ = _chunk_layout(n_edges, block_e)
+    n_chunks, owned = words.shape
+    per_chunk = (1 + 2) * plane + 3 * owned * plane + 4 * block_e * Cp
     return n_chunks * per_chunk
 
 
@@ -822,19 +829,66 @@ def _fused_chunk_kernel(
     jax.lax.fori_loop(0, n_chunk, edge_step, 0)
 
 
-def _chunk_word_masks(n_edges: int, block_e: int) -> np.ndarray:
-    """(n_chunks, ⌈E/32⌉) int32: word w's bits owned by chunk c.
+def _chunk_layout(n_edges: int, block_e: int):
+    """Static edge layout of the fused pipeline: ``(pads, bitpos, words,
+    masks)``.
 
-    Edges are processed in reverse (E-1 … 0) in chunks of ``block_e``; a
-    chunk's bits land at positions e mod 32 of its single word plane, and
-    these masks route them into the packed word e // 32 — including chunks
-    that straddle a word boundary (their two words get disjoint masks)."""
+    Edges are processed in reverse (E-1 … 0) in chunks of ``block_e``,
+    padded up to whole chunks with inert edges (feasible ≡ 0 masks them to
+    NEG everywhere: they leave V unchanged and set no bit).  When
+    ``block_e`` divides 32 the pad edges take the ids E … above edge E-1
+    (still under ⌈E/32⌉·32), so every chunk starts on a multiple of
+    ``block_e`` and lies inside ONE 32-bit word; otherwise the pad follows
+    edge 0 and a chunk may straddle two words.  ``pads`` is (pad edges
+    before E-1, pad edges after 0).
+
+    ``bitpos`` (n_chunks, block_e) int32 is each slot's bit (edge id mod
+    32; trailing pads read 0).  ``words`` and ``masks`` (n_chunks, k)
+    int32 name the k word planes each chunk owns and its bits in each —
+    k = 1 when no chunk straddles, else 2, a one-word chunk repeating its
+    word (ORing the same bits twice is idempotent)."""
     W = packed_words(n_edges)
     n_chunks = -(-n_edges // block_e)
-    masks = np.zeros((n_chunks, W), np.uint32)
-    for idx, e in enumerate(range(n_edges - 1, -1, -1)):
-        masks[idx // block_e, e // 32] |= np.uint32(1) << np.uint32(e % 32)
-    return masks.view(np.int32)
+    pad = n_chunks * block_e - n_edges
+    top = pad if 32 % block_e == 0 else 0
+    bitpos = np.zeros(n_chunks * block_e, np.int32)
+    owned = np.zeros((n_chunks, W), np.uint32)
+    for idx, e in enumerate(range(n_edges - 1 + top, -1, -1)):
+        bitpos[idx] = e % 32
+        if e < n_edges:
+            owned[idx // block_e, e // 32] |= np.uint32(1) << np.uint32(e % 32)
+    per_chunk = [np.flatnonzero(row) for row in owned]
+    k = max(len(ws) for ws in per_chunk)
+    words = np.array([np.resize(ws, k) for ws in per_chunk], np.int32)
+    masks = np.take_along_axis(owned, words, axis=1).view(np.int32)
+    return (top, pad - top), bitpos.reshape(n_chunks, block_e), words, masks
+
+
+def _edge_chunks(arr, pads, block_e: int):
+    """A per-edge (E, …) operand in processing order (E-1 … 0), inert-padded
+    by ``pads`` and cut into (n_chunks, block_e, …) chunks."""
+    padded = jnp.pad(arr[::-1], (pads,) + ((0, 0),) * (arr.ndim - 1))
+    return padded.reshape((-1, block_e) + arr.shape[1:])
+
+
+def _merge_chunk_bits(dec, bits, words, masks):
+    """OR one fused chunk's decision bits into the word planes it owns.
+
+    ``dec`` (…, W·Sp, Cp) stacks the packed word planes along its rows,
+    ``bits`` is (…, Sp, Cp), and ``words``/``masks`` (k,) come from
+    :func:`_chunk_layout`.  Each owned word is sliced out, ORed with its
+    masked bits and written back in place: a chunk moves 3·k planes, never
+    all W.  The carry is flat, not (W, Sp, Cp), so that its layout stays
+    row-major whatever the consumer of the decisions prefers and one word
+    is a contiguous run of whole tiles: given a word axis, the TPU layout
+    assignment may tile it together with the budget axis, and every word
+    slice then turns strided."""
+    rows = bits.shape[-2]
+    for w, mask in zip(words, masks):
+        word = jax.lax.dynamic_slice_in_dim(dec, w * rows, rows, -2)
+        dec = jax.lax.dynamic_update_slice_in_dim(dec, word | (bits & mask),
+                                                  w * rows, -2)
+    return dec
 
 
 def _check_block_e(block_e: int) -> None:
@@ -878,26 +932,15 @@ def _dp_forward_fused(
     V0 = jnp.pad(v0, ((0, Sp - S), (0, Cp - C)), constant_values=NEG)
     feas_p = jnp.pad(feasible, ((0, 0), (0, Cp - C)))  # pad states masked
     W = packed_words(n_edges)
-    dec0 = jnp.zeros((W, Sp, Cp), jnp.int32)
+    dec0 = jnp.zeros((W * Sp, Cp), jnp.int32)
 
-    # edges processed E-1 … 0, padded up to whole chunks with inert edges
-    # (feasible ≡ 0 masks them to NEG everywhere, so dec ≡ 0)
-    n_chunks = -(-n_edges // block_e)
-    Ep = n_chunks * block_e
-    pad_e = Ep - n_edges
-    rev = slice(None, None, -1)
-
-    def _chunked(arr, pad_width):
-        return jnp.pad(arr[rev], pad_width).reshape((n_chunks, block_e)
-                                                    + arr.shape[1:])
-
-    e_ids = jnp.arange(n_edges - 1, -1, -1, dtype=jnp.int32)
-    xs = (_chunked(upsilon, (0, pad_e)),
-          _chunked(offsets, (0, pad_e)),
-          _chunked(sigma2, (0, pad_e)),
-          jnp.pad(e_ids % 32, (0, pad_e)).reshape(n_chunks, block_e),
-          _chunked(feas_p, ((0, pad_e), (0, 0))),
-          jnp.asarray(_chunk_word_masks(n_edges, block_e)))
+    # edges processed E-1 … 0 in whole chunks, inert pads laid out by
+    # _chunk_layout (top ids when block_e divides 32, else after edge 0)
+    pads, bitpos, words, masks = _chunk_layout(n_edges, block_e)
+    chunked = functools.partial(_edge_chunks, pads=pads, block_e=block_e)
+    xs = (chunked(upsilon), chunked(offsets), chunked(sigma2),
+          jnp.asarray(bitpos), chunked(feas_p), jnp.asarray(words),
+          jnp.asarray(masks))
 
     multi_row = Sp // bs > 1
     kernel = functools.partial(_fused_chunk_kernel, n_chunk=block_e,
@@ -923,13 +966,12 @@ def _dp_forward_fused(
 
     def body(carry, x):
         V, dec = carry
-        ups_c, offs_c, sig_c, bitpos_c, feas_c, mask_c = x
-        Vn, bits = call(ups_c, offs_c, sig_c, bitpos_c, feas_c, V)
-        dec = dec | (bits[None, :, :] & mask_c[:, None, None])
-        return (Vn, dec), None
+        *operands, words_c, masks_c = x
+        Vn, bits = call(*operands, V)
+        return (Vn, _merge_chunk_bits(dec, bits, words_c, masks_c)), None
 
     (V, dec), _ = jax.lax.scan(body, (V0, dec0), xs)
-    return V[:S, :C], dec[:, :S, :C]
+    return V[:S, :C], dec.reshape(W, Sp, Cp)[:, :S, :C]
 
 
 class _Lead0:
@@ -1020,28 +1062,17 @@ def _dp_forward_fused_batched(
         (B, Sp, Cp))
     feas_p = jnp.pad(feasible, ((0, 0), (0, Cp - C)))  # pad states masked
     W = packed_words(n_edges)
-    dec0 = jnp.zeros((B, W, Sp, Cp), jnp.int32)
+    dec0 = jnp.zeros((B, W * Sp, Cp), jnp.int32)
 
-    n_chunks = -(-n_edges // block_e)
-    pad_e = n_chunks * block_e - n_edges
-    rev = slice(None, None, -1)
+    pads, bitpos, words, masks = _chunk_layout(n_edges, block_e)
+    chunked = functools.partial(_edge_chunks, pads=pads, block_e=block_e)
 
-    def _shared_chunks(arr, pad_width):
-        return jnp.pad(arr[rev], pad_width).reshape((n_chunks, block_e)
-                                                    + arr.shape[1:])
+    def inst_chunked(arr):  # (B, E) → (n_chunks, B, 1, block_e)
+        return chunked(arr.T).transpose(0, 2, 1)[:, :, None, :]
 
-    def _inst_chunks(arr):  # (B, E) → (n_chunks, B, 1, block_e)
-        return (jnp.pad(arr[:, rev], ((0, 0), (0, pad_e)))
-                .reshape(B, n_chunks, 1, block_e).transpose(1, 0, 2, 3))
-
-    e_ids = jnp.arange(n_edges - 1, -1, -1, dtype=jnp.int32)
-    xs = (_inst_chunks(upsilon),
-          _shared_chunks(offsets, (0, pad_e)),
-          _inst_chunks(sigma2),
-          jnp.pad(e_ids % 32, (0, pad_e)).reshape(n_chunks, block_e),
-          _inst_chunks(allowed),
-          _shared_chunks(feas_p, ((0, pad_e), (0, 0))),
-          jnp.asarray(_chunk_word_masks(n_edges, block_e)))
+    xs = (inst_chunked(upsilon), chunked(offsets), inst_chunked(sigma2),
+          jnp.asarray(bitpos), inst_chunked(allowed), chunked(feas_p),
+          jnp.asarray(words), jnp.asarray(masks))
 
     multi_row = Sp // bs > 1
     kernel = functools.partial(_batched_fused_kernel, n_chunk=block_e,
@@ -1074,13 +1105,12 @@ def _dp_forward_fused_batched(
 
     def body(carry, x):
         V, dec = carry
-        ups_c, offs_c, sig_c, bitpos_c, alw_c, feas_c, mask_c = x
-        Vn, bits = call(ups_c, offs_c, sig_c, bitpos_c, alw_c, feas_c, V)
-        dec = dec | (bits[:, None] & mask_c[None, :, None, None])
-        return (Vn, dec), None
+        *operands, words_c, masks_c = x
+        Vn, bits = call(*operands, V)
+        return (Vn, _merge_chunk_bits(dec, bits, words_c, masks_c)), None
 
     (V, dec), _ = jax.lax.scan(body, (V0, dec0), xs)
-    return V[:, :S, :C], dec[:, :, :S, :C]
+    return V[:, :S, :C], dec.reshape(B, W, Sp, Cp)[:, :, :S, :C]
 
 
 def _dp_forward_blocked(

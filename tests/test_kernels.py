@@ -408,6 +408,41 @@ def test_budgeted_dp_fused_chunks_straddle_word_boundary(E):
     np.testing.assert_array_equal(np.asarray(dec_f), np.asarray(dec_r))
 
 
+def _fused_problem(seed, E, K=2):
+    """A forward problem with E edges over a small capacity space."""
+    rng = np.random.default_rng(seed)
+    A = np.minimum(rng.integers(1, 3, (K, E)), 2)
+    c = np.full(K, 2)
+    ups = rng.integers(0, 3, E).astype(np.int32)
+    sig = rng.integers(1, 3000, E).astype(np.int32)
+    tables = build_tables(A, c)
+    feas, offs = prepare_tables(tables)
+    v0 = jnp.full((int(ups.sum()) + 1, tables.n_states), NEG,
+                  jnp.float32).at[0, :].set(0.0)
+    return (jnp.asarray(ups), jnp.asarray(sig), jnp.asarray(feas),
+            jnp.asarray(offs), v0)
+
+
+@pytest.mark.parametrize("block_e", [8, 16, 32])
+@pytest.mark.parametrize("E", [60, 64])
+def test_budgeted_dp_fused_word_aligned_chunks_match_ref(E, block_e):
+    """block_e dividing 32 puts the inert pad edges at the top of the id
+    range, so every chunk lies inside one 32-bit word and the scan merges
+    into that word alone.  E=60 pads 4 ids above edge 59 (with the pad
+    after edge 0 instead, block_e=8 chunks would straddle bit 31); E=64
+    fills both words.  Values and packed words are bit-exact vs ref."""
+    ups, sig, feas, offs, v0 = _fused_problem(53 + E, E)
+    off_max, u_max = int(offs.max()), int(ups.max() + 1)
+    V_f, dec_f = dp_forward_pallas(
+        ups, sig, feas, offs, v0, n_edges=E, u_max=u_max, off_max=off_max,
+        interpret=True, block_c=off_max + 1, block_s=u_max + 5,
+        block_e=block_e)
+    V_r, dec_r = dp_forward_ref(ups, sig, feas, offs, v0)
+    assert dec_f.shape[0] == 2
+    np.testing.assert_array_equal(np.asarray(V_f), np.asarray(V_r))
+    np.testing.assert_array_equal(np.asarray(dec_f), np.asarray(dec_r))
+
+
 def test_budgeted_dp_fused_whole_chunk_masked():
     """An ``allowed`` mask can zero EVERY edge of a fused chunk: the chunk
     must be a no-op (the inert-edge argument the ragged pad also relies
@@ -679,6 +714,73 @@ def test_batched_contract_errors():
         solve_budgeted_dp_batched(ups, sig, tables, s_cap, s_cap,
                                   u_max=u_max, interpret=True,
                                   block_b=B + 1, block_c=None)
+
+
+@pytest.mark.parametrize("E,block_e", [(60, 8), (40, 5)],
+                         ids=["one_word", "two_words"])
+def test_budgeted_dp_fused_batched_merges_owned_words(E, block_e):
+    """The batched fused pipeline merges through the same owned-word
+    helper as the single solve: word-aligned chunks (block_e=8) and chunks
+    straddling a word boundary (block_e=5) both match the oracle per
+    instance, each with its own ``allowed`` row folded into the
+    feasibility plane."""
+    ups1, sig1, feas, offs, v0 = _fused_problem(61, E)
+    off_max, u_max = int(offs.max()), int(ups1.max() + 1)
+    rng = np.random.default_rng(61)
+    B = 3
+    ups = jnp.asarray(rng.integers(0, u_max, (B, E)), jnp.int32)
+    sig = jnp.asarray(rng.integers(1, 3000, (B, E)), jnp.int32)
+    alw = jnp.asarray(rng.integers(0, 2, (B, E)), jnp.int32)
+    V, dec = dp_forward_pallas_batched(
+        ups, sig, alw, feas, offs, v0, n_edges=E, u_max=u_max,
+        off_max=off_max, interpret=True, block_b=1, block_c=off_max + 1,
+        block_s=u_max + 5, block_e=block_e)
+    for b in range(B):
+        V_r, dec_r = dp_forward_ref(ups[b], sig[b],
+                                    feas * alw[b][:, None], offs, v0)
+        np.testing.assert_array_equal(np.asarray(V[b]), np.asarray(V_r))
+        np.testing.assert_array_equal(np.asarray(dec[b]), np.asarray(dec_r))
+
+
+@pytest.mark.parametrize("batched", [False, True], ids=["single", "batched"])
+@pytest.mark.parametrize("block_e,owned", [(8, 1), (5, 2)],
+                         ids=["one_word", "two_words"])
+def test_fused_chunk_scan_updates_only_owned_words(batched, block_e, owned):
+    """Structural guard on the fused chunk scan at E=72 (W=3 packed
+    words): its decision carry is updated only by dynamic_update_slice of
+    one (Sp, Cp) word plane per owned word — one for word-aligned chunks,
+    two for chunks that may straddle — and no other op of the scan body
+    writes a carry-sized array, so an OR over all W planes fails here."""
+    E = 72
+    ups, sig, feas, offs, v0 = _fused_problem(67, E)
+    off_max, u_max = int(offs.max()), int(ups.max() + 1)
+    kw = dict(n_edges=E, u_max=u_max, off_max=off_max, interpret=True,
+              block_c=off_max + 1, block_s=u_max + 5, block_e=block_e)
+    if batched:
+        alw = jnp.ones((2, E), jnp.int32)
+        jaxpr = jax.make_jaxpr(
+            lambda u, s: dp_forward_pallas_batched(
+                u, s, alw, feas, offs, v0, block_b=1, **kw))(
+            jnp.stack([ups, ups]), jnp.stack([sig, sig]))
+    else:
+        jaxpr = jax.make_jaxpr(
+            lambda u, s: dp_forward_pallas(u, s, feas, offs, v0, **kw))(
+            ups, sig)
+    scans = [e for e in _iter_eqns(jaxpr.jaxpr) if e.primitive.name == "scan"
+             and any(b.primitive.name == "pallas_call"
+                     for b in e.params["jaxpr"].jaxpr.eqns)]
+    assert len(scans) == 1
+    body = scans[0].params["jaxpr"].jaxpr
+    call = next(b for b in body.eqns if b.primitive.name == "pallas_call")
+    plane = call.outvars[1].aval.shape  # the chunk's bits, (…, Sp, Cp)
+    carry = plane[:-2] + (3 * plane[-2], plane[-1])
+    updates = [b for b in body.eqns
+               if b.primitive.name == "dynamic_update_slice"
+               and b.invars[0].aval.shape == carry]
+    assert [u.invars[1].aval.shape for u in updates] == [plane] * owned
+    writers = {b.primitive.name for b in body.eqns
+               for v in b.outvars if v.aval.shape == carry}
+    assert writers == {"dynamic_update_slice"}
 
 
 def test_batched_ragged_pad_instances_inert():

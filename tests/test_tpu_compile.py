@@ -14,7 +14,9 @@ worker imports this file.  The compiled-mode solver is built in the test
 (``interpret=False``), since the program's own auto-resolution sees the CPU
 here and would pick the interpreter.
 """
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -160,3 +162,46 @@ def test_engine_stream_program_names_its_stages_for_v5e(one_chip, compiled_palla
     stages = ("admission", "statistics", "forward", "select", "backtrack",
               "packing", "account", "oracle")
     assert not [s for s in stages if f"/esdp.{s}/" not in text]
+
+
+_HLO_DEF = re.compile(r"^\s*(?:ROOT\s+)?%(\S+) = (\w+)\[([0-9,]*)\]\S* "
+                      r"([\w-]+)\(([^)]*)\)", re.M)
+
+
+@pytest.mark.parametrize("block_e,owned", [(8, 1), (5, 2)],
+                         ids=["one_word", "two_words"])
+def test_fused_merge_writes_owned_words_for_v5e(one_chip, block_e, owned):
+    """Compiled for a v5e, the fused forward's decision carry (E=72, so
+    W=3 word planes) is written only in place: every int32 op the size of
+    the carry is a parameter, tuple element, the zero broadcast or a
+    ``dynamic-update-slice``, and each of those updates one (Sp, Cp) word
+    plane — no fusion or copy of all W planes per chunk, and no layout
+    copy of a word plane on its way in or out of the carry."""
+    E, S, C, Sp, Cp = 72, 256, 18, 256, 128
+    W = (E + 31) // 32
+
+    def fwd(ups, sig, feas, offs, v0):
+        return dp_forward_pallas(ups, sig, feas, offs, v0, n_edges=E,
+                                 u_max=8, off_max=17, interpret=False,
+                                 block_c=Cp, block_s=64, block_e=block_e)
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    text = jax.jit(fwd).lower(
+        spec((E,), jnp.int32), spec((E,), jnp.int32),
+        spec((E, C), jnp.float32), spec((E,), jnp.int32),
+        spec((S, C), jnp.float32)).compile().as_text()
+    defs = _HLO_DEF.findall(text)
+    sizes = {name: math.prod(int(d) for d in dims.split(",") if d)
+             for name, _, dims, _, _ in defs}
+    carry = [(op, args) for name, dtype, _, op, args in defs
+             if dtype == "s32" and sizes[name] == W * Sp * Cp]
+    assert {op for op, _ in carry} <= {"parameter", "get-tuple-element",
+                                       "broadcast", "dynamic-update-slice"}
+    updates = [args.split(", ")[1].lstrip("%") for op, args in carry
+               if op == "dynamic-update-slice"]
+    assert len(updates) == owned
+    assert all(sizes[u] == Sp * Cp for u in updates)
+    assert not [name for name, dtype, _, op, _ in defs
+                if dtype == "s32" and op == "copy" and sizes[name] == Sp * Cp]
