@@ -216,7 +216,7 @@ def _time_forward(
     kernel-vs-wrapper split (mean_ms − forward_ms ≈ s*-rule + backtrack)."""
     feas, offs = prepare_tables(tables)
     S, C = s_cap + 1, tables.n_states
-    v0 = jnp.full((S, C), NEG, jnp.float32).at[0, :].set(0.0)
+    v0 = jnp.full((S, C), NEG, jnp.int32).at[0, :].set(0)
     fn = jax.jit(lambda u, s: dp_forward_pallas(
         u, s, jnp.asarray(feas), jnp.asarray(offs), v0, n_edges=offs.shape[0],
         u_max=u_max, off_max=int(offs.max()),

@@ -170,8 +170,8 @@ def _make_pallas_solve(interpret: bool | None):
         x, info = solve_budgeted_dp_pallas(
             upsilon, sigma2, tables, s_cap, s_limit, u_max=u_max,
             allowed=allowed, interpret=interpret)
-        row = info["value_row"]  # f32, kernel NEG sentinel
-        row = jnp.where(row >= 0, row, float(NEG)).astype(jnp.int32)
+        row = info["value_row"]
+        row = jnp.where(row >= 0, row, NEG)
         return x, {"s_star": info["s_star"], "value_row": row}
 
     return solve
@@ -458,7 +458,7 @@ class FallbackSolver:
 
         import numpy as np
 
-        from ..kernels.budgeted_dp.ops import validate_value_row
+        from ..kernels.budgeted_dp.ops import VALUE_BOUND, validate_value_row
         from ..runtime.fault import InjectedFault, planned_fault
 
         call = self.stats["calls"]
@@ -486,11 +486,11 @@ class FallbackSolver:
                              jnp.asarray(slim), jnp.asarray(alw))
                 row = np.asarray(info["value_row"])
                 if fault == "corrupt":
-                    # poison out of the f32-exact domain: validation MUST
-                    # reject this row, proving the checks are live
+                    # poison out of the exact int32 domain: validation
+                    # MUST reject this row, proving the checks are live
                     self.stats["faults_injected"] += 1
                     row = row.copy()
-                    row[..., 0] = 2 ** 24
+                    row[..., 0] = VALUE_BOUND
             except Exception as err:  # noqa: BLE001 — any launch failure degrades
                 if attempt == last:
                     raise

@@ -3,17 +3,25 @@
 All schedules take a (possibly traced) time ``t`` (1-based) and return jnp
 scalars, so the whole simulation can live inside one ``lax.scan``.
 
-Integer-domain bounds (why int32 is exact here):
+Integer-domain bounds (when int32 is exact):
   Υ̂_e = ⌈ξ v̂_e⌉ ≤ ξ                      (v̂ ∈ [0,1])
   Σ̂²_e = ⌈ξ² g/(2n)⌉ ≤ ⌈ξ² g/2⌉          (n ≥ 1)
-  With the default schedules at T = 10⁵: ξ ≲ 60·m and g ≲ 200, so
-  Σ̂² ≲ 2.1e5·m² and the UNEXPLORED bonus (m+1)·⌈ξ²g/2⌉ with DP sums over
-  ‖x‖₁ ≤ Σ_k c_k stays far below 2³¹ for every configuration we run.
-  The DP therefore uses exact int32 arithmetic (no float accumulation error),
-  which is also the natural datatype for the TPU VPU — see kernels/budgeted_dp.
+  and an unexplored edge gets the bonus (m+1)·⌈ξ²g/2⌉, the largest Σ̂² of
+  its slot.  The DP (``core.dp`` and the Pallas kernel alike) adds the Σ̂²
+  of a selection to int32 planes whose sentinel is NEG = −2²⁹, so it is
+  exact only while every selectable sum stays below |NEG| = 2²⁹; nothing
+  about the schedules guarantees that.  The bonus grows with ξ², so with m
+  and the horizon, and the largest sum is the bonus times the largest
+  selectable set (one job per unit of the scarcest device type): at Fig.
+  5's largest graph (m = 126, T = 100, ``g_logt_only``) one bonus is
+  34,679,636 and six of them are 2.1e8.  :func:`sigma2_bound` gives the
+  largest Σ̂² of a horizon, and ``DispatchEngine`` refuses at construction
+  a deployment whose largest selectable sum of it reaches 2²⁹
+  (``kernels.budgeted_dp.ops.check_value_bound``).
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import Callable
 
@@ -24,7 +32,7 @@ __all__ = [
     "delta_default", "delta_fast", "delta_slow",
     "g_default", "g_no_logt", "g_logt_only",
     "xi_of", "s_cap_for_horizon", "u_max_for_horizon",
-    "horizon_for_s_cap", "scale_statistics",
+    "horizon_for_s_cap", "scale_statistics", "sigma2_bound",
     "DELTA_VARIANTS", "G_VARIANTS",
 ]
 
@@ -201,3 +209,21 @@ def scale_statistics(vhat, n, t, m, g_fn=g_default, delta_fn=delta_default):
     sigma2 = jnp.where(n > 0, sigma2_explored, unexp)
     s_limit = xi * m
     return upsilon, sigma2, xi, s_limit
+
+
+def sigma2_bound(T: int, m: int, g_fn=g_default, delta_fn=delta_default) -> int:
+    """The largest Σ̂² any slot t = 1 … T can produce: the unexplored bonus
+    (m+1)·⌈ξ²g/2⌉, evaluated by :func:`scale_statistics`' own float32
+    schedule at every t and maximized, the product taken in exact host
+    integers (so a bonus that would wrap int32 reads as the large number it
+    is)."""
+    return (int(m) + 1) * int(_max_explored(int(T), m, g_fn, delta_fn))
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3))
+def _max_explored(T, m, g_fn, delta_fn):
+    t = jnp.arange(1, T + 1, dtype=jnp.float32)
+    _, explored, _, _ = scale_statistics(
+        jnp.zeros_like(t), jnp.ones(t.shape, jnp.int32), t, m, g_fn=g_fn,
+        delta_fn=delta_fn)  # at n = 1 the explored Σ̂² is ⌈ξ²g/2⌉
+    return jnp.max(explored)

@@ -102,8 +102,12 @@ unchanged.  ``choose_tiling(..., batch=B)`` resolves the whole (block_b,
 block_e, block_s, block_c) split, shrinking the batch axis BEFORE the plane
 axes.
 
-Arithmetic is f32 with integer values; exactness holds for values < 2²⁴
-(ops.py enforces the bound — see core/stats.py for why defaults are ≪ 2²⁴).
+Value planes, the halo scratches and the per-edge gains are int32, as in
+``core.dp``, with the sentinel ``NEG = core.dp.NEG = −2²⁹``: every value is
+exact as long as every reachable sum stays below |NEG| (then a NEG-seeded
+chain stays negative and never overflows).  ``ops.check_value_bound``
+enforces that bound on concrete statistics, and ``DispatchEngine`` checks
+it once at construction for its whole horizon.
 
 Backend resolution: ``interpret=None`` (the default) compiles on TPU and
 falls back to the Pallas interpreter elsewhere — the kernel is never
@@ -125,6 +129,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ...core import dp as core_dp
+
 __all__ = ["NEG", "VMEM_BUDGET_BYTES", "MAX_BLOCK_E", "resolve_interpret",
            "packed_words", "unblocked_vmem_bytes", "c_blocked_tile_vmem_bytes",
            "tiled_vmem_bytes", "fused_tile_vmem_bytes", "batched_vmem_bytes",
@@ -132,7 +138,7 @@ __all__ = ["NEG", "VMEM_BUDGET_BYTES", "MAX_BLOCK_E", "resolve_interpret",
            "batched_modeled_hbm_bytes", "choose_tiling", "dp_forward_pallas",
            "dp_forward_pallas_batched"]
 
-NEG = -float(2 ** 24)
+NEG = int(core_dp.NEG)  # −2²⁹, the int32 sentinel of the reference DP
 
 # share of the TPU's default 16 MiB scoped-VMEM limit left to this kernel's
 # modeled footprint; the rest covers what the model does not count (the
@@ -496,9 +502,12 @@ def _clamp_or(first_row, y0, up):
 
 
 def _edge_update(cur, shifted, sig, live):
-    """One edge of the recurrence on a tile: returns (V′, decision)."""
+    """One edge of the recurrence on a tile: returns (V′, decision).  The
+    strict compare that makes the decision also selects V′ (ties keep the
+    current value), so the update costs one compare and one select."""
     take = jnp.where(live, shifted + sig, NEG)
-    return jnp.maximum(cur, take), take > cur
+    dec = take > cur
+    return jnp.where(dec, take, cur), dec
 
 
 # ---------------------------------------------------------------------------
@@ -542,7 +551,7 @@ def _dp_kernel(
                 e = e_hi - 1 - jj
                 u = jnp.minimum(ups_ref[b, 0, e], u_max)
                 off = jnp.minimum(offs_ref[e], off_max)
-                sig = sig_ref[b, 0, e].astype(jnp.float32)
+                sig = sig_ref[b, 0, e]
                 live = ((feas_ref[pl.ds(e, 1), :] > 0)
                         & (alw_ref[b, 0, e] > 0))
                 V = vout_ref[b]
@@ -598,7 +607,7 @@ def _whole_plane(
     V, dec = pl.pallas_call(
         kernel,
         grid=(Bp // bb,),
-        out_shape=(jax.ShapeDtypeStruct((Bp, Sp, Cp), jnp.float32),
+        out_shape=(jax.ShapeDtypeStruct((Bp, Sp, Cp), jnp.int32),
                    jax.ShapeDtypeStruct((Bp, W, Sp, Cp), jnp.int32)),
         in_specs=[
             inst,  # Υ̂ rows
@@ -642,7 +651,7 @@ def _edge_tile_kernel(
     """
     u = jnp.minimum(u_ref[0], u_max)
     off = off_ref[0]
-    sig = sig_ref[0].astype(jnp.float32)
+    sig = sig_ref[0]
     cur = vcur_ref[...]
     take = _row_shift(_lane_shift(cur, vleft_ref[...], off), None, u)
     Vn, dec = _edge_update(cur, take, sig, feas_ref[...] > 0)
@@ -673,7 +682,7 @@ def _edge_stile_kernel(
     those columns are c < offset_e, infeasible, masked)."""
     u = jnp.minimum(u_ref[0], u_max)
     off = off_ref[0]
-    sig = sig_ref[0].astype(jnp.float32)
+    sig = sig_ref[0]
     cur = vcur_ref[...]
     x = _lane_shift(cur, vleft_ref[...], off)
     up = _lane_shift(vup_cur_ref[...], vup_left_ref[...], off)
@@ -698,7 +707,7 @@ def _edge_call(
         return pl.pallas_call(
             kernel,
             grid=(Cp // block_c,),
-            out_shape=(jax.ShapeDtypeStruct((Sp, Cp), jnp.float32),
+            out_shape=(jax.ShapeDtypeStruct((Sp, Cp), jnp.int32),
                        jax.ShapeDtypeStruct((Sp, Cp), jnp.int32)),
             in_specs=scalar_specs + [
                 pl.BlockSpec((1, block_c), lambda j: (0, j)),
@@ -719,7 +728,7 @@ def _edge_call(
     return pl.pallas_call(
         kernel,
         grid=(Sp // block_s, Cp // block_c),
-        out_shape=(jax.ShapeDtypeStruct((Sp, Cp), jnp.float32),
+        out_shape=(jax.ShapeDtypeStruct((Sp, Cp), jnp.int32),
                    jax.ShapeDtypeStruct((Sp, Cp), jnp.int32)),
         in_specs=scalar_specs + [
             pl.BlockSpec((1, block_c), lambda i, j: (0, j)),
@@ -799,7 +808,7 @@ def _fused_chunk_kernel(
     def edge_step(k, _):
         u = jnp.minimum(ups_ref[k], u_max)
         off = jnp.minimum(offs_ref[k], off_max)
-        sig = sig_ref[k].astype(jnp.float32)
+        sig = sig_ref[k]
         bit = jnp.left_shift(jnp.int32(1), bitpos_ref[k])
         X = vout_ref[...]
         # left halo for edge k, then this tile's own boundary history
@@ -905,8 +914,8 @@ def _fused_scratch(block_e, bs, block_c, u_max, off_max, Cp, multi_row):
     is not charged 2·block_e·UH·Cp for it) and ``lefth``."""
     uh, lw = _halo_widths(bs, block_c, u_max, off_max)
     rowh_shape = (2 * block_e, uh, Cp) if multi_row else (1, 1, 1)
-    return [pltpu.VMEM(rowh_shape, jnp.float32),
-            pltpu.VMEM((block_e, bs, lw), jnp.float32)]
+    return [pltpu.VMEM(rowh_shape, jnp.int32),
+            pltpu.VMEM((block_e, bs, lw), jnp.int32)]
 
 
 def _dp_forward_fused(
@@ -950,7 +959,7 @@ def _dp_forward_fused(
     call = pl.pallas_call(
         kernel,
         grid=(Sp // bs, Cp // block_c),
-        out_shape=(jax.ShapeDtypeStruct((Sp, Cp), jnp.float32),
+        out_shape=(jax.ShapeDtypeStruct((Sp, Cp), jnp.int32),
                    jax.ShapeDtypeStruct((Sp, Cp), jnp.int32)),
         in_specs=scalar_specs + [
             pl.BlockSpec((block_e, block_c), lambda i, j: (0, j)),
@@ -1083,7 +1092,7 @@ def _dp_forward_fused_batched(
     call = pl.pallas_call(
         kernel,
         grid=(B, Sp // bs, Cp // block_c),
-        out_shape=(jax.ShapeDtypeStruct((B, Sp, Cp), jnp.float32),
+        out_shape=(jax.ShapeDtypeStruct((B, Sp, Cp), jnp.int32),
                    jax.ShapeDtypeStruct((B, Sp, Cp), jnp.int32)),
         in_specs=[
             inst_row,  # Υ̂ chunk
@@ -1203,8 +1212,10 @@ def dp_forward_pallas(
     block_e: int | None = None,
 ):
     """upsilon/sigma2/offsets: (E,) i32; feasible: (E, C) f32 0/1;
-    v0: (S, C) f32.  Returns (V_final (S, C) f32,
-    decisions (⌈E/32⌉, S, C) i32 — bit (e%32) of word (e//32) is edge e).
+    v0: (S, C) i32 (``NEG`` where unreachable).  Returns (V_final (S, C)
+    i32, decisions (⌈E/32⌉, S, C) i32 — bit (e%32) of word (e//32) is
+    edge e).  Values are exact while every reachable sum stays below
+    |NEG| (``ops.check_value_bound``).
 
     ``offsets[e]`` is the mixed-radix transition constant (next(c) = c −
     offsets[e] on feasible states; ``off_max`` ≥ max offsets); ``block_c``
@@ -1222,6 +1233,7 @@ def dp_forward_pallas(
             "block_e fuses edges into the blocked pipeline's grid and "
             "needs block_c (pass block_c=C for a single full-width tile)")
     _check_tiling(block_s, block_c, u_max, off_max, interp)
+    sigma2, v0 = sigma2.astype(jnp.int32), v0.astype(jnp.int32)
     if block_c is not None:
         if block_e is not None:
             return _dp_forward_fused(
@@ -1266,7 +1278,7 @@ def dp_forward_pallas_batched(
     (E,) are SHARED across the batch — per-instance eligibility rides the
     (B, E) ``allowed`` rows and multiplies into the feasibility mask
     INSIDE the kernel, so the plane is never replicated per instance.
-    Returns ``(V (B, S, C) f32, decisions (B, ⌈E/32⌉, S, C) i32)``.
+    Returns ``(V (B, S, C) i32, decisions (B, ⌈E/32⌉, S, C) i32)``.
 
     ``block_b`` instances advance per grid step (default: the whole
     batch in one step); ragged batches (B not a multiple of block_b) pad
@@ -1282,6 +1294,7 @@ def dp_forward_pallas_batched(
             f"block_b={bb} outside [1, {B}]: the batch grid advances "
             "block_b instances per step and cannot exceed the batch")
     allowed = jnp.asarray(allowed, jnp.int32)
+    sigma2, v0 = sigma2.astype(jnp.int32), v0.astype(jnp.int32)
     if block_e is not None and block_c is None:
         raise ValueError(
             "block_e fuses edges into the blocked pipeline's grid and "

@@ -39,11 +39,17 @@ Operand contract (what makes this usable from the hot path):
     offset arithmetic (cs − offsets[e]), per-edge constants streamed as
     lax.scan inputs instead of per-element table gathers.
 
-VALUE_BOUND contract: kernel arithmetic is f32, exact for integers < 2²⁴.
-Whenever this wrapper is called with CONCRETE statistics it verifies that no
-capacity-feasible subset can accumulate a value ≥ 2²⁴ and raises otherwise;
-traced calls (inside jit/scan) skip the check, which is why
-``tests/test_solver_equiv.py`` pins the default schedules under the bound.
+VALUE_BOUND contract: the kernel's value planes are int32 with the
+reference DP's sentinel ``core.dp.NEG = −2²⁹``, so every value is exact as
+long as every reachable sum stays below ``VALUE_BOUND = |NEG| = 2²⁹``: a
+NEG-seeded chain then stays negative (the s* rule reads it as infeasible)
+and no sum overflows.  The bound is the largest Σ̂²ᵀx over capacity-feasible
+x (:func:`max_achievable_value`); :func:`check_value_bound` raises when it
+is reached.  This wrapper checks it whenever it is called with CONCRETE
+statistics.  A traced call (inside jit/scan) cannot see its values, so the
+caller that traces it checks the bound once for its whole run:
+``DispatchEngine`` does so at construction, with every edge at the largest
+Σ̂² its horizon can produce (``stats.sigma2_bound``).
 """
 from __future__ import annotations
 
@@ -55,15 +61,15 @@ import numpy as np
 
 from ...core import dp as core_dp
 from ...core.dp import DPTables
-from .kernel import (NEG, choose_tiling, dp_forward_pallas,
+from .kernel import (choose_tiling, dp_forward_pallas,
                      dp_forward_pallas_batched, resolve_interpret)
 
 __all__ = ["VALUE_BOUND", "prepare_tables", "max_achievable_value",
-           "validate_value_row", "solve_budgeted_dp_pallas",
-           "solve_budgeted_dp_batched", "WarmPallasSolver",
-           "resolve_interpret"]
+           "check_value_bound", "validate_value_row",
+           "solve_budgeted_dp_pallas", "solve_budgeted_dp_batched",
+           "WarmPallasSolver", "resolve_interpret"]
 
-VALUE_BOUND = 2 ** 24  # f32-exact integer domain (kernel contract)
+VALUE_BOUND = -int(core_dp.NEG)  # 2²⁹: every reachable DP sum stays below
 
 
 def validate_value_row(value_row) -> "str | None":
@@ -77,8 +83,8 @@ def validate_value_row(value_row) -> "str | None":
 
       * source: ``value_row[0] >= 0`` — the empty selection achieves s=0;
       * NEG contract: every entry is ``>= 0`` or exactly the sentinel;
-      * VALUE_BOUND: feasible values stay ``< 2**24`` (the f32-exact
-        domain the kernel is allowed to produce);
+      * VALUE_BOUND: feasible values stay ``< 2**29`` (|NEG|: the domain
+        in which the int32 planes are exact);
       * prefix feasibility: feasible s form a prefix — any x with
         ``Υ̂ᵀx >= s`` also witnesses every ``s' < s``;
       * monotone: values are non-increasing in s over the feasible prefix
@@ -106,8 +112,8 @@ def validate_value_row(value_row) -> "str | None":
     over = feas & (row >= VALUE_BOUND)
     if over.any():
         s = int(np.flatnonzero(over)[0])
-        return (f"value-bound: value_row[{s}] = {row[s]} >= 2^24 "
-                "(outside the f32-exact domain)")
+        return (f"value-bound: value_row[{s}] = {row[s]} >= 2^29 "
+                "(outside the exact int32 domain)")
     n_feas = int(feas.sum())
     if not feas[:n_feas].all():
         s = int(np.flatnonzero(~feas)[0])
@@ -147,40 +153,40 @@ def prepare_tables(tables: DPTables):
 
 
 def max_achievable_value(sigma2, tables: DPTables) -> int:
-    """Upper bound on any DP partial sum: max Σ̂²ᵀx over capacity-feasible x.
+    """The largest DP sum: max Σ̂²ᵀx over capacity-feasible x (Ax ≤ c).
 
-    Per-edge requirements are recovered from the transition out of the
-    full-capacity state; if every usable edge consumes ≥ 1 device the
-    selection size is capped by Σ_k c_k, else by E.  The top-k sum of Σ̂²
-    then bounds every value the kernel can ever materialize (feasible or
-    not — infeasible states only accumulate subsets of the same sums).
+    An exact 0/1 knapsack over the capacity states in int64 on the host,
+    O(E·C).  Every value the forward solve materializes is at most this —
+    a state of remaining capacity c' ≤ c only admits subsets of the same
+    selections, and a NEG-seeded chain adds a subset of the same gains to
+    NEG.  With every Σ̂² equal to one value g it is g times the largest
+    selectable set.
     """
     sig = np.asarray(sigma2, dtype=np.int64)
-    E = sig.shape[0]
-    usable = np.asarray(tables.feasible)[tables.full_state]  # (E,)
-    if not usable.any():
-        return 0
-    cap = np.asarray(tables.cap_of_state, dtype=np.int64)
-    c = np.asarray(tables.radices, dtype=np.int64) - 1
-    nxt = np.asarray(tables.next_state)[tables.full_state]  # (E,)
-    req_total = (c[None, :] - cap[nxt]).sum(axis=1)  # (E,)
-    if np.all(req_total[usable] >= 1):
-        k = min(E, int(c.sum()))
-    else:
-        k = E
-    top = np.sort(sig[usable])[::-1][:k]
-    return int(top.sum())
+    feas = np.asarray(tables.feasible)  # (C, E)
+    offs = np.asarray(tables.offsets, dtype=np.int64)
+    V = np.zeros(tables.n_states, np.int64)
+    for e in range(sig.shape[0]):
+        states = np.flatnonzero(feas[:, e])
+        if states.size:
+            V[states] = np.maximum(V[states],
+                                   V[states - offs[e]] + sig[e])
+    return int(V[tables.full_state])
 
 
-def _check_value_bound(sigma2, tables: DPTables) -> None:
+def check_value_bound(sigma2, tables: DPTables) -> None:
+    """Raise unless every DP sum under ``sigma2`` stays below VALUE_BOUND.
+
+    Traced statistics cannot be checked: their caller checks a bound for
+    its whole run (``DispatchEngine`` at construction)."""
     if isinstance(sigma2, jax.core.Tracer):
-        return  # traced call — bound pinned by tests
+        return
     bound = max_achievable_value(sigma2, tables)
     if bound >= VALUE_BOUND:
         raise ValueError(
-            f"budgeted-DP values can reach {bound} ≥ 2^24: the Pallas "
-            "kernel's f32 arithmetic is no longer exact. Rescale Σ̂² or "
-            "use the 'reference' (int32) solver backend.")
+            f"budgeted-DP values can reach {bound} ≥ 2^29 = |NEG|: the "
+            "int32 planes would no longer tell reachable sums from the "
+            "sentinel. Rescale Σ̂² or shorten the horizon.")
 
 
 def _check_u_max(upsilon, u_max: int) -> None:
@@ -221,7 +227,7 @@ def _solve(
 ):
     E = upsilon.shape[0]
     S = s_cap + 1
-    v0 = jnp.full((S, feasible.shape[1]), NEG, jnp.float32).at[0, :].set(0.0)
+    v0 = core_dp.initial_plane(s_cap, feasible.shape[1])
 
     with jax.named_scope("esdp.forward"):
         V, decisions = dp_forward_pallas(
@@ -234,11 +240,10 @@ def _solve(
         s_vals = jnp.arange(S, dtype=jnp.int32)
         # feasible ⇔ value ≥ 0: Σ̂² ≥ 0 so reachable values are
         # non-negative, while NEG-seeded chains stay < 0 for any partial
-        # sum < 2²⁴ (the VALUE_BOUND contract) — sharper than
-        # thresholding at NEG/2.
+        # sum < 2²⁹ (the VALUE_BOUND contract) — the rule of core.dp
         ok = (v_row >= 0) & (s_vals <= s_limit)
         score = s_vals.astype(jnp.float32) + jnp.sqrt(
-            jnp.maximum(v_row, 0.0))
+            jnp.maximum(v_row, 0).astype(jnp.float32))
         s_star = jnp.argmax(jnp.where(ok, score, -jnp.inf)).astype(
             jnp.int32)
 
@@ -296,7 +301,7 @@ def _solve_batched(
     of each instance's packed-decision words."""
     B, E = upsilon.shape
     S = s_cap + 1
-    v0 = jnp.full((S, feasible.shape[1]), NEG, jnp.float32).at[0, :].set(0.0)
+    v0 = core_dp.initial_plane(s_cap, feasible.shape[1])
 
     with jax.named_scope("esdp.forward"):
         V, decisions = dp_forward_pallas_batched(
@@ -310,7 +315,7 @@ def _solve_batched(
         s_vals = jnp.arange(S, dtype=jnp.int32)
         ok = (v_row >= 0) & (s_vals[None, :] <= s_limit[:, None])
         score = (s_vals[None, :].astype(jnp.float32)
-                 + jnp.sqrt(jnp.maximum(v_row, 0.0)))
+                 + jnp.sqrt(jnp.maximum(v_row, 0).astype(jnp.float32)))
         s_star = jnp.argmax(jnp.where(ok, score, -jnp.inf),
                             axis=1).astype(jnp.int32)
 
@@ -466,7 +471,7 @@ def solve_budgeted_dp_pallas(
     every mapped instance through ONE batched kernel launch (see
     :func:`_vmappable_core`) — callers never need to opt in.
     """
-    _check_value_bound(sigma2, tables)
+    check_value_bound(sigma2, tables)
     feas, offs = prepare_tables(tables)
     if u_max is None:
         u_max = s_cap + 1
@@ -537,7 +542,7 @@ def solve_budgeted_dp_batched(
     """
     if not isinstance(sigma2, jax.core.Tracer):
         # worst case per edge across the batch bounds every instance
-        _check_value_bound(np.max(np.asarray(sigma2), axis=0), tables)
+        check_value_bound(np.max(np.asarray(sigma2), axis=0), tables)
     feas, offs = prepare_tables(tables)
     if u_max is None:
         u_max = s_cap + 1
@@ -583,7 +588,7 @@ class WarmPallasSolver:
     changes — only a host driver that splits the edge fold into fixed
     SEGMENTS of ``checkpoint_every`` fold steps and launches them chained
     (each segment's output plane seeds the next).  A chain of segment
-    launches executes the identical f32 op sequence as one launch, so the
+    launches executes the identical int32 op sequence as one launch, so the
     split itself is bit-invisible.  Across slots the driver keeps every
     inter-segment plane plus each segment's packed decision words: when a
     new solve's delta mask (vs the previous inputs, in FOLD order — edge
@@ -631,7 +636,6 @@ class WarmPallasSolver:
         self.interpret = resolve_interpret(interpret)
         self._feas, self._offs = feas, offs
         E = offs.shape[0]
-        S = self.s_cap + 1
         self._E = E
         self._off_max = int(offs.max()) if E else 0
 
@@ -658,8 +662,7 @@ class WarmPallasSolver:
         self._select_back = self._make_select_back()
 
         # carried fold artifacts (host side)
-        self._v0 = jnp.full((S, tables.n_states), NEG,
-                            jnp.float32).at[0, :].set(0.0)
+        self._v0 = core_dp.initial_plane(self.s_cap, tables.n_states)
         self._planes = [self._v0] + [None] * self._n_seg
         self._dec = [None] * self._n_seg
         self._dec_cat = None
@@ -706,7 +709,7 @@ class WarmPallasSolver:
             s_vals = jnp.arange(S, dtype=jnp.int32)
             ok = (v_row >= 0) & (s_vals <= s_limit)
             score = s_vals.astype(jnp.float32) + jnp.sqrt(
-                jnp.maximum(v_row, 0.0))
+                jnp.maximum(v_row, 0).astype(jnp.float32))
             s_star = jnp.argmax(jnp.where(ok, score,
                                           -jnp.inf)).astype(jnp.int32)
 
@@ -724,10 +727,9 @@ class WarmPallasSolver:
             (_, _), x = jax.lax.scan(
                 back, (s_star, jnp.int32(full_state)),
                 (upsilon, offs, w_rows, bits))
-            # contract sanitization: budget-infeasible entries become the
-            # CORE int32 sentinel (−2²⁹), not the kernel's f32 one
-            row = jnp.where(v_row >= 0, v_row,
-                            float(core_dp.NEG)).astype(jnp.int32)
+            # contract sanitization: every budget-infeasible entry reads
+            # exactly the sentinel
+            row = jnp.where(v_row >= 0, v_row, core_dp.NEG)
             return x, s_star, row
 
         return select_back
@@ -761,7 +763,7 @@ class WarmPallasSolver:
                 "inputs; inside jit/scan use the reference warm path "
                 "(core.incremental.solve_budgeted_dp_warm) or the solve "
                 "cache instead")
-        _check_value_bound(np.asarray(sigma2), self.tables)
+        check_value_bound(np.asarray(sigma2), self.tables)
         _check_u_max(np.asarray(upsilon), self.u_max)
 
         E = self._E

@@ -1,5 +1,5 @@
 """Pure-jnp oracle for the budgeted-DP kernel (mirrors core/dp._dp_forward
-in the kernel's f32 value domain, including the bit-packed decision words
+in the kernel's int32 value domain, including the bit-packed decision words
 and the offset-encoded capacity transition next(c) = c − offsets[e]).
 
 This oracle is the CONTRACT every kernel tiling must reproduce bit for
@@ -17,7 +17,7 @@ from .kernel import NEG, packed_words
 
 def dp_forward_ref(upsilon, sigma2, feasible, offsets, v0):
     """Same contract as kernel.dp_forward_pallas, computed with jnp gathers:
-    returns (V (S, C) f32, decisions (⌈E/32⌉, S, C) i32 bit-packed).
+    returns (V (S, C) i32, decisions (⌈E/32⌉, S, C) i32 bit-packed).
 
     The capacity gather clamps c − offsets[e] at 0; clamped reads are
     exactly the states with c < offsets[e], which are infeasible and masked
@@ -34,12 +34,12 @@ def dp_forward_ref(upsilon, sigma2, feasible, offsets, v0):
         off = offsets[e]
         shifted = V[jnp.maximum(rows - u, 0), :]
         take = shifted[:, jnp.maximum(cols - off, 0)] + sigma2[e].astype(
-            jnp.float32)
+            jnp.int32)
         take = jnp.where(feasible[e][None, :] > 0, take, NEG)
         dec = (take > V).astype(jnp.int32)
         return jnp.maximum(V, take), dec
 
-    V, decs = jax.lax.scan(body, v0, jnp.arange(E))
+    V, decs = jax.lax.scan(body, v0.astype(jnp.int32), jnp.arange(E))
     decs = decs[::-1]  # index by edge id
     # pack edge bits into int32 words: bit (e % 32) of word (e // 32)
     W = packed_words(E)

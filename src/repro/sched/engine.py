@@ -61,6 +61,7 @@ from ..core.dp import oracle_knapsack
 from ..core.env import Scenario
 from ..core.graph import Instance
 from ..core.solvers import Solver, get_solver
+from ..kernels.budgeted_dp.ops import check_value_bound
 from . import telemetry
 
 __all__ = ["BACKPRESSURE_POLICIES", "LOCKSTEP_POLICIES", "VariantSpec",
@@ -268,6 +269,13 @@ class DispatchEngine:
                 if getattr(v.solver, "scope", "") is None:
                     v.solver.scope = v.name  # per-variant stats scoping
                 self._solvers.append(v.solver)
+        if any(v.kind == "esdp" for v in cfg.variants):
+            # the stream scan's solves are traced and cannot check their
+            # values: check the horizon's largest selectable sum once, here
+            check_value_bound(np.full(
+                instance.n_edges,
+                stats_mod.sigma2_bound(self.T, self.m, g_fn=g_fn)),
+                self.tables)
         self._jit_cache: dict = {}
         self._calls = itertools.count()  # call ids of the telemetry spans
 

@@ -4,13 +4,17 @@ import numpy as np
 import pytest
 
 try:  # optional [test] extra — property tests skip cleanly without it
-    from hypothesis import given, settings, strategies as st
+    from hypothesis import example, given, settings, strategies as st
     HAS_HYPOTHESIS = True
 except ImportError:
     HAS_HYPOTHESIS = False
 
+from repro.core.dp import build_tables
 from repro.core.stats import (DELTA_VARIANTS, G_VARIANTS, horizon_for_s_cap,
-                              s_cap_for_horizon, scale_statistics, xi_of)
+                              s_cap_for_horizon, scale_statistics,
+                              sigma2_bound, xi_of)
+from repro.kernels.budgeted_dp.ops import (VALUE_BOUND, check_value_bound,
+                                           max_achievable_value)
 
 
 if HAS_HYPOTHESIS:
@@ -26,8 +30,10 @@ if HAS_HYPOTHESIS:
     @settings(max_examples=25, deadline=None)
     @given(st.integers(2, 10_000), st.integers(1, 40),
            st.integers(0, 2**31 - 1))
+    @example(36, 40, 39)  # (m+1)·Σ̂² = 41 · 52,927,925 passes 2^31
     def test_scaled_statistics_int32_bounds(t, m, seed):
-        """Υ̂, Σ̂² and the DP-sum bound stay far inside int32 (stats.py claim)."""
+        """Υ̂ and Σ̂² stay inside int32, and a DP sum that would reach
+        |NEG| is refused by the construction guard (stats.py claim)."""
         rng = np.random.default_rng(seed)
         E = int(rng.integers(1, 64))
         vhat = jnp.asarray(rng.uniform(0, 1, E), jnp.float32)
@@ -41,8 +47,23 @@ if HAS_HYPOTHESIS:
         unexplored = sig[np.asarray(n) == 0]
         if explored.size and unexplored.size:
             assert unexplored.min() > m * explored.max() * 0.99
-        # DP sums of ≤ m+1 values stay in int32
-        assert (m + 1) * int(sig.max()) < 2**31
+        # every Σ̂² of a slot t' ≤ t lies within the horizon's bound, which
+        # is exact (no int32 wrap) ...
+        bound = sigma2_bound(t, m)
+        assert int(sig.max()) <= bound < 2**31
+        # ... and a DP sum of m+1 such values runs only below |NEG| = 2^29:
+        # the construction guard raises for a deployment whose largest
+        # selectable set (one device type of capacity m+1, one device per
+        # job) reaches it
+        tables = build_tables(np.ones((1, m + 1), np.int64),
+                              np.array([m + 1]))
+        sig_T = np.full(m + 1, bound, np.int64)
+        assert max_achievable_value(sig_T, tables) == (m + 1) * bound
+        if (m + 1) * bound < VALUE_BOUND:
+            check_value_bound(sig_T, tables)
+        else:
+            with pytest.raises(ValueError, match="2\\^29"):
+                check_value_bound(sig_T, tables)
 else:
     def test_hypothesis_extra_missing():
         pytest.importorskip(

@@ -321,3 +321,36 @@ def test_failure_lockstep_per_variant_ledgers(inst):
         sum(np.asarray(fv[n]["dispatched"]) for n in out.variants),
         rtol=1e-6, atol=1e-6)
     assert_conserves(out)
+
+
+@pytest.mark.parametrize("solver", ["reference", "pallas_interpret"])
+def test_stream_matches_plain_reference_with_several_starts(solver):
+    """At capacity 6 per device type (Fig. 6's largest) the capacity check
+    packs several gang jobs into a slot; the stream path still equals the
+    benchmark's plain reference in every integer output (ledger, routing,
+    bandit counts), and its float sums of several terms agree to rounding."""
+    from bench.reference import Reference
+    from bench.traffic.gen import streams
+
+    inst = generate_instance(seed=1, n_ports=8, n_servers=24, edge_prob=0.2,
+                             c_lo=6, c_hi=6)
+    T, seed = 40, 5
+    eng = DispatchEngine(inst, T, EngineConfig(
+        variants=(VariantSpec("esdp", solver=solver),)))
+    trace = streams(inst.rho, inst.n_edges, T, seed)
+    out = eng.run(mode="stream", seed=seed, streams=trace)
+    ref = Reference.for_instance(
+        inst, T, {"queue_capacity": 4, "backpressure": "drop_oldest"})
+    assert ref.C == 343
+    want = ref.replay(trace[0], trace[1])
+    assert out.ledger["dispatched"].max() >= 2
+    assert out.ledger["dispatched"].mean() > 1
+    for key in ("arrivals", "rejected", "blocked", "dropped", "shed",
+                "admitted", "dispatched", "queue_len"):
+        np.testing.assert_array_equal(out.ledger[key], want[key], key)
+    np.testing.assert_array_equal(out.routed_variant, want["routed"])
+    np.testing.assert_array_equal(out.n, want["n"])
+    for got, key in ((out.sw, "sw"), (out.regret, "regret"),
+                     (out.dispatch_share, "share"), (out.sumz, "sumz")):
+        np.testing.assert_allclose(got, want[key], rtol=0, atol=1e-6,
+                                   err_msg=key)

@@ -128,7 +128,7 @@ def test_budgeted_dp_kernel_packed_decisions_match_ref(E):
     feas, offs = prepare_tables(tables)
     feas, offs = jnp.asarray(feas), jnp.asarray(offs)
     v0 = jnp.full((s_cap + 1, tables.n_states), NEG,
-                  jnp.float32).at[0, :].set(0.0)
+                  jnp.int32).at[0, :].set(0)
     V_k, dec_k = dp_forward_pallas(jnp.asarray(ups), jnp.asarray(sig), feas,
                                    offs, v0, n_edges=E,
                                    u_max=int(ups.max() + 1),
@@ -162,7 +162,7 @@ def test_budgeted_dp_blocked_grid_matches_ref(tile):
     off_max = int(offs.max())
     block_c = off_max if tile == "tight" else off_max + 3
     v0 = jnp.full((s_cap + 1, tables.n_states), NEG,
-                  jnp.float32).at[0, :].set(0.0)
+                  jnp.int32).at[0, :].set(0)
     V_b, dec_b = dp_forward_pallas(
         jnp.asarray(ups), jnp.asarray(sig), feas, offs, v0, n_edges=E,
         u_max=int(ups.max() + 1), off_max=off_max, interpret=True,
@@ -207,7 +207,7 @@ def test_budgeted_dp_s_tiled_grid_matches_ref(tile):
         "full_c": (u_max + 1, C),
         "single_s": (S + 3, off_max),
     }[tile]
-    v0 = jnp.full((S, C), NEG, jnp.float32).at[0, :].set(0.0)
+    v0 = jnp.full((S, C), NEG, jnp.int32).at[0, :].set(0)
     V_t, dec_t = dp_forward_pallas(
         jnp.asarray(ups), jnp.asarray(sig), feas, offs, v0, n_edges=len(ups),
         u_max=u_max, off_max=off_max, interpret=True,
@@ -230,7 +230,7 @@ def test_budgeted_dp_s_tiled_u_max_halo_edge():
     feas, offs = prepare_tables(tables)
     feas, offs = jnp.asarray(feas), jnp.asarray(offs)
     u_max = int(ups.max())  # no +1 margin
-    v0 = jnp.full((S, C), NEG, jnp.float32).at[0, :].set(0.0)
+    v0 = jnp.full((S, C), NEG, jnp.int32).at[0, :].set(0)
     V_t, dec_t = dp_forward_pallas(
         jnp.asarray(ups), jnp.asarray(sig), feas, offs, v0, n_edges=len(ups),
         u_max=u_max, off_max=int(offs.max()), interpret=True,
@@ -266,6 +266,72 @@ def test_budgeted_dp_s_tiled_solver_with_allowed_mask():
     np.testing.assert_array_equal(r1[r1 >= 0], r2[r2 >= 0].astype(np.int64))
 
 
+def _past_f32_problem(seed=29, E=12):
+    """Σ̂² between 2²⁴ and 2²⁸ that differ by 1, on three device types of
+    capacity 6 (C = 343 states: a 256-lane tile leaves two C tiles with a
+    left halo between them, a 128-lane tile three), so DP sums of up to six
+    of them lie past float32's exact integers and below |NEG| = 2²⁹."""
+    rng = np.random.default_rng(seed)
+    A = rng.integers(1, 3, (3, E))
+    c = np.full(3, 6)
+    ups = rng.integers(0, 5, E).astype(np.int32)
+    sig = (2 ** 26 + rng.integers(0, 3, E)).astype(np.int32)
+    sig[:3] = 2 ** 24 + np.arange(1, 4)
+    sig[3] = 2 ** 27 + 1
+    return A, c, ups, sig
+
+
+# every kernel path, forced: (batched, block_b, block_c, block_s, block_e)
+INT32_TILINGS = {
+    "whole_plane": (False, None, None, None, None),
+    "c_blocked_edge": (False, None, 128, None, None),
+    "s_tiled_edge": (False, None, 128, 16, None),
+    "fused_two_c_tiles": (False, None, 256, None, 4),
+    "fused_s_tiled_two_c_tiles": (False, None, 256, 16, 4),
+    "batched_whole_plane": (True, 2, None, None, None),
+    "batched_fused_two_c_tiles": (True, 1, 256, 16, 4),
+}
+
+
+@pytest.mark.parametrize("tiling", list(INT32_TILINGS))
+def test_budgeted_dp_int32_values_past_f32(tiling):
+    """Every kernel path holds values past 2²⁴ exactly: x, s* and the whole
+    raw value row (NEG-seeded chains included) equal ``core.dp``'s, on
+    gains that differ by 1 where float32 spacing is 2 to 32."""
+    batched, block_b, block_c, block_s, block_e = INT32_TILINGS[tiling]
+    A, c, ups, sig = _past_f32_problem()
+    tables = build_tables(A, c)
+    assert tables.n_states == 343 and int(tables.offsets.max()) <= 128
+    s_cap = int(ups.sum())
+    x_ref, info = solve_budgeted_dp(jnp.asarray(ups), jnp.asarray(sig),
+                                    tables, s_cap, jnp.int32(s_cap))
+    row = np.asarray(info["value_row"])
+    assert row.max() > 2 ** 25
+    assert np.any(row.astype(np.float32).astype(np.int64) != row)
+    kw = dict(u_max=int(ups.max() + 1), interpret=True, block_c=block_c,
+              block_s=block_s, block_e=block_e)
+    if batched:
+        # the second instance mirrors the statistics: another exact answer
+        x2_ref, info2 = solve_budgeted_dp(
+            jnp.asarray(ups[::-1]), jnp.asarray(sig[::-1]), tables, s_cap,
+            jnp.int32(s_cap))
+        x, got = solve_budgeted_dp_batched(
+            np.stack([ups, ups[::-1]]), np.stack([sig, sig[::-1]]), tables,
+            s_cap, s_cap, block_b=block_b, **kw)
+        np.testing.assert_array_equal(np.asarray(x), np.stack(
+            [np.asarray(x_ref), np.asarray(x2_ref)]))
+        np.testing.assert_array_equal(np.asarray(got["s_star"]), [
+            int(info["s_star"]), int(info2["s_star"])])
+        np.testing.assert_array_equal(np.asarray(got["value_row"]), np.stack(
+            [row, np.asarray(info2["value_row"])]))
+        return
+    x, got = solve_budgeted_dp_pallas(ups, sig, tables, s_cap, s_cap, **kw)
+    np.testing.assert_array_equal(np.asarray(x), np.asarray(x_ref))
+    assert int(got["s_star"]) == int(info["s_star"])
+    assert np.asarray(got["value_row"]).dtype == np.int32
+    np.testing.assert_array_equal(np.asarray(got["value_row"]), row)
+
+
 def test_budgeted_dp_s_tiled_halo_contract_errors():
     """Tiles thinner than the halos are rejected, and block_s without a
     concrete block_c is a usage error — never a silent wrong answer."""
@@ -277,7 +343,7 @@ def test_budgeted_dp_s_tiled_halo_contract_errors():
     off_max = int(offs.max())
     u_max = int(ups.max() + 1)
     v0 = jnp.full((s_cap + 1, tables.n_states), NEG,
-                  jnp.float32).at[0, :].set(0.0)
+                  jnp.int32).at[0, :].set(0)
     kwargs = dict(n_edges=len(ups), u_max=u_max, off_max=off_max,
                   interpret=True)
     with pytest.raises(ValueError, match="block_s"):
@@ -328,6 +394,30 @@ def test_choose_tiling_decision_table():
     assert be2 is None or be2 <= be
 
 
+
+@pytest.mark.parametrize("c,tiling,chunks", [
+    (None, (8, 1024, 128), 32),  # fig5_l16r160: c = (2, 1, 2), C = 18
+    (6, (4, 512, 256), 63),  # fig5_l16r160_c6: C = 343 on two C tiles
+])
+def test_choose_tiling_fig5_deployments(c, tiling, chunks):
+    """Fig. 5's largest graph at T = 100 resolves the fused S-tiled grid:
+    32 chunks of 8 edges at the benchmark's capacities, 63 chunks of 4 over
+    two 256-lane C tiles when every device type holds 6 units."""
+    from repro.core.graph import generate_instance
+    from repro.sched import DispatchEngine
+
+    kw = {} if c is None else {"c_lo": c, "c_hi": c}
+    inst = generate_instance(seed=1, n_ports=16, n_servers=160,
+                             edge_prob=0.1, **kw)
+    eng = DispatchEngine(inst, 100)
+    _, offs = prepare_tables(eng.tables)
+    got = choose_tiling(eng.s_cap + 1, eng.tables.n_states, offs.shape[0],
+                        eng.u_max, int(offs.max()))
+    assert got == tiling
+    assert -(-offs.shape[0] // got[0]) == chunks
+    if c is not None:  # the C axis pads past one tile: a halo between two
+        assert got[2] < eng.tables.n_states < 2 * got[2]
+
 def test_fused_hbm_model_cuts_traffic_blockwise():
     """The modeled HBM traffic of the fused pipeline drops ~block_e-fold vs
     the per-edge scan on the same plane tiling — the quantity dp_bench
@@ -366,7 +456,7 @@ def test_budgeted_dp_fused_grid_matches_ref(tile, block_e):
         "full_c": (u_max + 1, C),
         "single_s": (None, off_max),
     }[tile]
-    v0 = jnp.full((S, C), NEG, jnp.float32).at[0, :].set(0.0)
+    v0 = jnp.full((S, C), NEG, jnp.int32).at[0, :].set(0)
     V_f, dec_f = dp_forward_pallas(
         jnp.asarray(ups), jnp.asarray(sig), feas, offs, v0, n_edges=len(ups),
         u_max=u_max, off_max=off_max, interpret=True,
@@ -396,7 +486,7 @@ def test_budgeted_dp_fused_chunks_straddle_word_boundary(E):
     off_max = int(offs.max())
     u_max = int(ups.max() + 1)
     v0 = jnp.full((s_cap + 1, tables.n_states), NEG,
-                  jnp.float32).at[0, :].set(0.0)
+                  jnp.int32).at[0, :].set(0)
     V_f, dec_f = dp_forward_pallas(
         jnp.asarray(ups), jnp.asarray(sig), feas, offs, v0, n_edges=E,
         u_max=u_max, off_max=off_max, interpret=True,
@@ -418,7 +508,7 @@ def _fused_problem(seed, E, K=2):
     tables = build_tables(A, c)
     feas, offs = prepare_tables(tables)
     v0 = jnp.full((int(ups.sum()) + 1, tables.n_states), NEG,
-                  jnp.float32).at[0, :].set(0.0)
+                  jnp.int32).at[0, :].set(0)
     return (jnp.asarray(ups), jnp.asarray(sig), jnp.asarray(feas),
             jnp.asarray(offs), v0)
 
@@ -487,7 +577,7 @@ def test_budgeted_dp_fused_u_max_halo_tracks_in_chunk_updates():
     u_max = int(ups.max())  # exact bound, no margin
     off_max = int(offs.max())
     v0 = jnp.full((s_cap + 1, tables.n_states), NEG,
-                  jnp.float32).at[0, :].set(0.0)
+                  jnp.int32).at[0, :].set(0)
     V_f, dec_f = dp_forward_pallas(
         jnp.asarray(ups), jnp.asarray(sig), feas, offs, v0, n_edges=E,
         u_max=u_max, off_max=off_max, interpret=True,
@@ -509,7 +599,7 @@ def test_budgeted_dp_fused_contract_errors():
     off_max = int(offs.max())
     u_max = int(ups.max() + 1)
     v0 = jnp.full((s_cap + 1, tables.n_states), NEG,
-                  jnp.float32).at[0, :].set(0.0)
+                  jnp.int32).at[0, :].set(0)
     kwargs = dict(n_edges=len(ups), u_max=u_max, off_max=off_max,
                   interpret=True)
     with pytest.raises(ValueError, match="block_e"):
@@ -689,7 +779,7 @@ def test_batched_contract_errors():
     sig = jnp.broadcast_to(jnp.asarray(sig1), (B, E))
     alw = jnp.ones((B, E), jnp.int32)
     v0 = jnp.full((s_cap + 1, tables.n_states), NEG,
-                  jnp.float32).at[0, :].set(0.0)
+                  jnp.int32).at[0, :].set(0)
     kwargs = dict(n_edges=E, u_max=u_max, off_max=off_max, interpret=True)
     for bad_bb in (0, B + 1):
         with pytest.raises(ValueError, match="block_b"):
@@ -815,7 +905,7 @@ def test_batched_ragged_pad_instances_inert():
     assert not np.asarray(x[3]).any()
     # the all-masked instance's forward plane is v0, untouched
     feas, offs = prepare_tables(tables)
-    v0 = jnp.full((S, C), NEG, jnp.float32).at[0, :].set(0.0)
+    v0 = jnp.full((S, C), NEG, jnp.int32).at[0, :].set(0)
     V, dec = dp_forward_pallas_batched(
         jnp.asarray(ups), jnp.asarray(sig), jnp.asarray(alw),
         jnp.asarray(feas), jnp.asarray(offs), v0, n_edges=E, u_max=u_max,

@@ -314,6 +314,10 @@ def test_validate_value_row_rejects_corruption():
     assert "source" in validate_value_row(poisoned(0, -5))
     assert "neg-contract" in validate_value_row(poisoned(n_feas - 1, -5))
     assert "value-bound" in validate_value_row(poisoned(0, VALUE_BOUND))
+    assert VALUE_BOUND == 2 ** 29  # |NEG| of the int32 planes
+    # past 2^24 (float32's exact integers) a value is still a valid one
+    assert validate_value_row(poisoned(0, 2 ** 24 + 1)) is None
+    assert validate_value_row(poisoned(0, VALUE_BOUND - 1)) is None
     # NEG hole inside the feasible prefix
     assert "feasible-prefix" in validate_value_row(poisoned(n_feas // 2, NEG))
     # a value row must be non-increasing in the budget s

@@ -607,18 +607,27 @@ def test_get_solver_caches_identity():
 
 
 # ---------------------------------------------------------------------------
-# VALUE_BOUND contract (f32 exactness < 2^24)
+# VALUE_BOUND contract (int32 planes, every reachable sum < |NEG| = 2^29)
 # ---------------------------------------------------------------------------
 
 def test_value_bound_overflow_raises():
     rng = np.random.default_rng(8)
     A, c, ups, sig = _rand_problem(rng, 6, 2)
     sig = sig.astype(np.int32)
-    sig[0] = VALUE_BOUND  # a single value at the bound
     tables = build_tables(A, c)
-    with pytest.raises(ValueError, match="2\\^24"):
-        solve_budgeted_dp_pallas(ups, sig, tables, int(ups.sum()),
-                                 int(ups.sum()), interpret=True)
+    s_cap = int(ups.sum())
+    assert VALUE_BOUND == -int(NEG) == 2 ** 29
+    sig[0] = VALUE_BOUND  # a single value at the bound
+    with pytest.raises(ValueError, match="2\\^29"):
+        solve_budgeted_dp_pallas(ups, sig, tables, s_cap, s_cap,
+                                 interpret=True)
+    # 2^24, the float32 plane's old limit, now solves exactly
+    sig[0] = 2 ** 24 + 1
+    x, s_star, row = _solve_with(PAL, ups, sig, tables, s_cap, s_cap)
+    x_r, s_r, row_r = _solve_with(REF, ups, sig, tables, s_cap, s_cap)
+    np.testing.assert_array_equal(x, x_r)
+    assert s_star == s_r and row.max() >= 2 ** 24 + 1
+    np.testing.assert_array_equal(row, row_r)
 
 
 def test_max_achievable_value_topk():
@@ -631,10 +640,30 @@ def test_max_achievable_value_topk():
     assert max_achievable_value(sig, tables) == 90
 
 
+# the engine's deployments at their horizons: (generator arguments, T,
+# largest selectable set) — Table 2, Fig. 5's largest graph, and that graph
+# at Fig. 6's largest capacities
+DEPLOYMENTS = [
+    (dict(seed=0), 2_000, 1),
+    (dict(seed=0), 56_000, 1),
+    (dict(seed=1, n_ports=16, n_servers=160, edge_prob=0.1), 100, 1),
+    (dict(seed=1, n_ports=16, n_servers=160, edge_prob=0.1), 200, 1),
+    (dict(seed=1, n_ports=16, n_servers=160, edge_prob=0.1, c_lo=6,
+          c_hi=6), 50, 6),
+    (dict(seed=1, n_ports=16, n_servers=160, edge_prob=0.1, c_lo=6,
+          c_hi=6), 100, 6),
+    (dict(seed=1, n_ports=16, n_servers=160, edge_prob=0.1, c_lo=6,
+          c_hi=6), 200, 6),
+]
+
+
 def test_default_schedules_stay_under_value_bound():
-    """Pins the stats.scale_statistics outputs under 2^24 at the default
-    horizons (T=1500 benchmarks, T=10^5 stress), so the traced hot path —
-    where the runtime check cannot see concrete values — is safe."""
+    """Pins the stats.scale_statistics outputs under 2^29 at the default
+    horizons (T=1500 benchmarks, T=10^5 stress) of the Table-2 instance
+    (m = 17), and the engine's largest selectable sums in its deployments
+    (m = 17 and m = 126, whose values pass 2^24 at T = 100) — the sums
+    ``DispatchEngine`` checks at construction, since the traced hot path
+    cannot see concrete values."""
     inst = generate_instance(seed=0)  # paper Table-2 defaults
     tables = build_tables(inst.A, inst.c)
     m = inst.m
@@ -650,6 +679,32 @@ def test_default_schedules_stay_under_value_bound():
         jnp.zeros(E, jnp.float32), jnp.zeros(E, jnp.int32),
         jnp.float32(1.0), m)
     assert max_achievable_value(np.asarray(sig0), tables) < VALUE_BOUND
+    g = stats_mod.g_logt_only  # the engine's schedule
+    for kw, T, largest in DEPLOYMENTS:
+        inst = generate_instance(**kw)
+        tables = build_tables(inst.A, inst.c)
+        bonus = stats_mod.sigma2_bound(T, inst.m, g_fn=g)
+        _, sig, _, _ = stats_mod.scale_statistics(
+            jnp.zeros(inst.n_edges, jnp.float32),
+            jnp.zeros(inst.n_edges, jnp.int32), jnp.float32(T), inst.m,
+            g_fn=g)
+        assert int(np.max(sig)) == bonus  # the bonus of the last slot
+        total = max_achievable_value(np.full(inst.n_edges, bonus), tables)
+        assert total == largest * bonus < VALUE_BOUND
+    # Fig. 5's largest graph: one bonus already passes 2^24 at T = 100
+    assert stats_mod.sigma2_bound(100, 126, g_fn=g) > 2 ** 25
+
+
+def test_engine_refuses_a_horizon_past_the_value_bound():
+    """The construction guard: at Fig. 6's capacities six bonuses of
+    T = 10^4 reach 2^29, so the engine refuses the horizon before any
+    slot is traced; T = 200 runs."""
+    from repro.sched import DispatchEngine
+    inst = generate_instance(seed=1, n_ports=16, n_servers=160,
+                             edge_prob=0.1, c_lo=6, c_hi=6)
+    DispatchEngine(inst, 200)
+    with pytest.raises(ValueError, match="2\\^29"):
+        DispatchEngine(inst, 10_000)
 
 
 # ---------------------------------------------------------------------------

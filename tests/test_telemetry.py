@@ -138,3 +138,4 @@ def test_stream_program_carries_the_stage_scopes(solver):
         .as_text()
     missing = [s for s in STAGES if f"/{s}/" not in text]
     assert not missing
+

@@ -101,8 +101,52 @@ def test_kernel_regime_compiles_for_v5e(one_chip, regime, name, s_cap, want):
     compiled = jax.jit(fwd).lower(
         spec((E,), jnp.int32), spec((E,), jnp.int32),
         spec((E, C), jnp.float32), spec((E,), jnp.int32),
-        spec((S, C), jnp.float32)).compile()
+        spec((S, C), jnp.int32)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def _fig6_capacity_deployment():
+    """Fig. 5's largest graph at Fig. 6's largest capacity (6 per device
+    type): the ``fig5_l16r160_c6`` benchmark deployment."""
+    from repro.core.graph import generate_instance
+    return generate_instance(seed=1, n_ports=16, n_servers=160,
+                             edge_prob=0.1, c_lo=6, c_hi=6)
+
+
+@pytest.mark.parametrize("T,S,Sp", [(50, 41_329, 41_472),
+                                    (100, 43_345, 43_520)])
+def test_fused_two_c_tiles_compile_for_v5e(one_chip, T, S, Sp):
+    """At C = 343 (T = 50, and the benchmark cell's T = 100) the fused grid
+    runs 4-edge chunks over (512, 256) tiles, two C tiles with a lane halo
+    between them, on int32 value planes: the kernel compiles for a v5e and
+    reads and writes no float32 plane."""
+    from repro.core import stats
+    inst = _fig6_capacity_deployment()
+    tables = build_tables(inst.A, inst.c)
+    _, offs = prepare_tables(tables)
+    E, C, off_max = inst.n_edges, tables.n_states, int(offs.max())
+    assert stats.s_cap_for_horizon(T, inst.m) + 1 == S
+    u_max = stats.u_max_for_horizon(T, inst.m)
+    be, bs, bc = choose_tiling(S, C, E, u_max, off_max)
+    assert (C, be, bs, bc) == (343, 4, 512, 256)
+
+    def fwd(ups, sig, feas, offs, v0):
+        return dp_forward_pallas(ups, sig, feas, offs, v0, n_edges=E,
+                                 u_max=u_max, off_max=off_max,
+                                 interpret=False, block_c=bc, block_s=bs,
+                                 block_e=be)
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    text = jax.jit(fwd).lower(
+        spec((E,), jnp.int32), spec((E,), jnp.int32),
+        spec((E, C), jnp.float32), spec((E,), jnp.int32),
+        spec((S, C), jnp.int32)).compile().as_text()
+    kernels = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert kernels
+    outs = [ln.split("custom-call(")[0] for ln in kernels]
+    assert all(f"s32[{Sp},512]" in o and "f32" not in o for o in outs)
 
 
 def test_batched_vmap_solve_compiles_to_one_kernel(one_chip, compiled_pallas):
@@ -176,7 +220,7 @@ def test_fused_merge_writes_owned_words_for_v5e(one_chip, block_e, owned):
     the carry is a parameter, tuple element, the zero broadcast or a
     ``dynamic-update-slice``, and each of those updates one (Sp, Cp) word
     plane — no fusion or copy of all W planes per chunk, and no layout
-    copy of a word plane on its way in or out of the carry."""
+    copy of a word or bits plane on its way in or out of the carry."""
     E, S, C, Sp, Cp = 72, 256, 18, 256, 128
     W = (E + 31) // 32
 
@@ -191,7 +235,7 @@ def test_fused_merge_writes_owned_words_for_v5e(one_chip, block_e, owned):
     text = jax.jit(fwd).lower(
         spec((E,), jnp.int32), spec((E,), jnp.int32),
         spec((E, C), jnp.float32), spec((E,), jnp.int32),
-        spec((S, C), jnp.float32)).compile().as_text()
+        spec((S, C), jnp.int32)).compile().as_text()
     defs = _HLO_DEF.findall(text)
     sizes = {name: math.prod(int(d) for d in dims.split(",") if d)
              for name, _, dims, _, _ in defs}
@@ -203,5 +247,13 @@ def test_fused_merge_writes_owned_words_for_v5e(one_chip, block_e, owned):
                if op == "dynamic-update-slice"]
     assert len(updates) == owned
     assert all(sizes[u] == Sp * Cp for u in updates)
+    # the int32 value plane is the one (Sp, Cp) plane that may be copied
+    # (into the kernel's last operand, and out of the scan before its
+    # final (S, C) slice); no word or bits plane is
+    vin = {ln.split("custom-call(")[1].split(")")[0].split("%")[-1]
+           for ln in text.splitlines() if "tpu_custom_call" in ln}
+    vout = {args.lstrip("%") for _, _, dims, op, args in defs
+            if op == "slice" and dims == f"{S},{C}"}
     assert not [name for name, dtype, _, op, _ in defs
-                if dtype == "s32" and op == "copy" and sizes[name] == Sp * Cp]
+                if dtype == "s32" and op == "copy" and sizes[name] == Sp * Cp
+                and name not in vin | vout]
