@@ -74,15 +74,24 @@ iterations):
     the up-left corner) and writes bank i mod 2, so row i's writes never
     clobber the corner history row i+1 still needs.
 
-Decision bits for the whole chunk pack into ONE (S, C) int32 word-plane
-per tile (bit Υ = global edge id mod 32 — legal because block_e ≤ 32 keeps
-in-chunk bit positions distinct); the host scan ORs each chunk word into
-only the packed word planes that chunk owns, one (S, C) plane read and
-written in place through a dynamic slice of the scan's (⌈E/32⌉·S, C)
-carry.
-When block_e divides 32 the inert pad edges take the top edge ids, so every
-chunk lies inside one 32-bit word; otherwise a chunk may straddle two words
-and the scan merges into both, through static per-chunk word masks.
+Every chunk owns exactly ONE packed decision word: each word's edges are
+padded up to whole chunks with inert edges (:func:`_chunk_layout`), so a
+chunk never straddles two words whatever ``block_e`` is, and its bits
+(global edge id mod 32) are distinct because block_e ≤ 32.  The scan's
+flat (⌈E/32⌉·S, C) decision carry is an operand of each chunk's
+pallas_call, and its BlockSpec picks tile (w·S/block_s + i, j) of the
+chunk's word w, a scalar-prefetched index: the tile starts from the word's
+bits so far and each edge ORs its decisions into it, in VMEM.  The word
+plane and the value plane are both aliased input to output
+(``input_output_aliases``), so a chunk reads and writes one value plane
+and one word plane in place and no XLA op touches the carry between
+launches.  Aliasing is safe here, unlike for the per-edge-scan kernels
+(which read neighbor tiles of the plane they write, and therefore map
+V → V′ functionally): grid step (i, j) reads and writes only its own (i,
+j) tile of V and of the word plane, and every halo comes from the
+``rowh``/``lefth`` VMEM scratches, never from a neighbor tile in HBM.
+The pipeline's prefetch of step t+1's tiles therefore never meets a tile
+that a step ≤ t has written.
 
 ``dp_forward_pallas_batched`` runs a FLEET of B independent solves in one
 pallas_call.  The batch rides the grid: ``block_b`` instances advance per
@@ -143,7 +152,7 @@ NEG = int(core_dp.NEG)  # −2²⁹, the int32 sentinel of the reference DP
 # share of the TPU's default 16 MiB scoped-VMEM limit left to this kernel's
 # modeled footprint; the rest covers what the model does not count (the
 # compiler's internal scratch and spilled temporaries)
-VMEM_BUDGET_BYTES = 12 * 2 ** 20
+VMEM_BUDGET_BYTES = 13 * 2 ** 20
 
 # fused chunks pack their decision bits into ONE int32 word-plane, so
 # in-chunk bit positions (global edge id mod 32) must be distinct
@@ -220,7 +229,7 @@ def fused_tile_vmem_bytes(
     block_e: int, block_s: int, block_c: int, u_max: int, off_max: int, S: int, C: int
 ) -> int:
     """Per-grid-step VMEM of the edge-fused pipeline: the (block_s,
-    block_c) input tile and two output tiles (value + chunk bits) plus the
+    block_c) value and owned-word tiles, each in and out, plus the
     per-chunk feasibility block, double-buffered; four tile-sized shift
     temporaries; and the two persistent halo-history scratches —
     ``lefth`` (block_e, block_s, LW) and the double-banked ``rowh``
@@ -233,7 +242,7 @@ def fused_tile_vmem_bytes(
     Cp = _pad_to(C, block_c)
     tile = _tile_words(block_s, block_c)
     rowh = 0 if block_s >= S else 2 * block_e * _tile_words(uh, Cp)
-    return 4 * (2 * (3 * tile + _tile_words(block_e, block_c))
+    return 4 * (2 * (4 * tile + _tile_words(block_e, block_c))
                 + 4 * tile
                 + rowh
                 + block_e * _tile_words(block_s, lw))
@@ -288,10 +297,10 @@ def modeled_hbm_bytes(
 
     Counts the plane-sized flows only (operand vectors are O(E)): value
     blocks read/written by the pallas pipeline, the per-step feasibility
-    blocks, and the host-side merge of decision bits into the packed
-    (⌈E/32⌉, S, C) words (a read-modify-write of one word plane per edge
-    for the scan pipelines, of the one or two word planes each chunk owns
-    for the fused one — see :func:`_chunk_layout`).
+    blocks, and the decision bits of the packed (⌈E/32⌉, S, C) words (the
+    scan pipelines write a bits plane per edge and the host ORs it into
+    one word plane; a fused chunk reads and writes the one word plane it
+    owns inside the kernel — see :func:`_chunk_layout`).
     The whole-plane kernel streams everything exactly once.  This is the
     ``hbm_bytes_streamed`` model `benchmarks/dp_bench.py` records — a
     traffic model for the perf trend, not a measurement.
@@ -312,13 +321,10 @@ def modeled_hbm_bytes(
         views = 2 if block_s is None else 4
         per_edge = (views + 2) * plane + 2 * plane + 4 * Cp
         return n_edges * per_edge
-    # fused: each chunk streams the plane in/out ONCE and writes its bits
-    # plane; the merge reads those bits and read-modify-writes each word
-    # plane the chunk owns (3 planes per owned word)
-    _, _, words, _ = _chunk_layout(n_edges, block_e)
-    n_chunks, owned = words.shape
-    per_chunk = (1 + 2) * plane + 3 * owned * plane + 4 * block_e * Cp
-    return n_chunks * per_chunk
+    # fused: each chunk streams the value plane and its owned word plane
+    # in and out ONCE, plus its feasibility block
+    n_chunks = len(_chunk_layout(n_edges, block_e)[1])
+    return n_chunks * (2 * plane + 2 * plane + 4 * block_e * Cp)
 
 
 def batched_modeled_hbm_bytes(
@@ -348,7 +354,7 @@ def batched_modeled_hbm_bytes(
         if block_e is None:
             shared = 4 * n_edges * Cp  # feasibility tiles per edge
         else:
-            shared = 4 * -(-n_edges // block_e) * block_e * Cp
+            shared = 4 * _chunk_layout(n_edges, block_e)[0].size * Cp
     return shared + batch * (per - shared)
 
 
@@ -749,14 +755,16 @@ def _edge_call(
 # ---------------------------------------------------------------------------
 
 def _fused_chunk_kernel(
+    word_ref,
     ups_ref,
     offs_ref,
     sig_ref,
     bitpos_ref,
     feas_ref,
     vin_ref,
+    win_ref,
     vout_ref,
-    bits_ref,
+    wout_ref,
     rowh_ref,
     lefth_ref,
     *,
@@ -781,17 +789,19 @@ def _fused_chunk_kernel(
     left halo) exactly like the unfused kernels; C-tile 0's left halo is
     garbage by construction — every read landing there is a state c <
     offset_e, infeasible, masked to NEG.  Decision bits of the whole chunk
-    OR into one int32 word plane at bit ``bitpos[k]`` (global edge id mod
-    32).
+    OR at bit ``bitpos[k]`` (global edge id mod 32) into the tile of the
+    one word plane the chunk owns: ``wout`` starts from ``win``, the
+    word's bits so far.  ``word_ref`` (that word's index) is read only by
+    the index maps.
 
     Batched reuse: the batched pipeline prepends the batch as grid axis 0
     (``grid_base=1`` shifts the (i, j) grid ids right) and passes the
     per-instance eligibility row as ``alw_ref`` — everything else is
-    identical, because each instance re-initializes the tile, bits, and
-    halo state at its own (i=0, j=0) corner: the tile reloads from ``vin``
-    every step, the clamp-row branch covers i=0 without using ``rowh``,
-    and the j=0 ``lefth`` columns are only ever read by infeasible
-    (masked) states."""
+    identical, because each instance re-initializes the tiles and halo
+    state at its own (i=0, j=0) corner: the tiles reload from ``vin`` and
+    ``win`` every step, the clamp-row branch covers i=0 without using
+    ``rowh``, and the j=0 ``lefth`` columns are only ever read by
+    infeasible (masked) states."""
     Bs, Bc = vin_ref.shape
     lw = lefth_ref.shape[-1]
     i = pl.program_id(grid_base)
@@ -803,7 +813,7 @@ def _fused_chunk_kernel(
     # garbage that only infeasible states c < offset_e ever read)
     corner = pl.multiple_of(jnp.maximum(j * Bc - lw, 0), math.gcd(Bc, lw))
     vout_ref[...] = vin_ref[...]
-    bits_ref[...] = jnp.zeros((Bs, Bc), jnp.int32)
+    wout_ref[...] = win_ref[...]
 
     def edge_step(k, _):
         u = jnp.minimum(ups_ref[k], u_max)
@@ -831,7 +841,7 @@ def _fused_chunk_kernel(
         if alw_ref is not None:
             live = live & (alw_ref[k] > 0)
         Xn, dec = _edge_update(X, take, sig, live)
-        bits_ref[...] = bits_ref[...] | jnp.where(dec, bit, 0)
+        wout_ref[...] = wout_ref[...] | jnp.where(dec, bit, 0)
         vout_ref[...] = Xn
         return 0
 
@@ -839,65 +849,31 @@ def _fused_chunk_kernel(
 
 
 def _chunk_layout(n_edges: int, block_e: int):
-    """Static edge layout of the fused pipeline: ``(pads, bitpos, words,
-    masks)``.
+    """Static edge layout of the fused pipeline: ``(order, words)``.
 
-    Edges are processed in reverse (E-1 … 0) in chunks of ``block_e``,
-    padded up to whole chunks with inert edges (feasible ≡ 0 masks them to
-    NEG everywhere: they leave V unchanged and set no bit).  When
-    ``block_e`` divides 32 the pad edges take the ids E … above edge E-1
-    (still under ⌈E/32⌉·32), so every chunk starts on a multiple of
-    ``block_e`` and lies inside ONE 32-bit word; otherwise the pad follows
-    edge 0 and a chunk may straddle two words.  ``pads`` is (pad edges
-    before E-1, pad edges after 0).
+    Edges are processed in reverse (E-1 … 0), word by word: each word's
+    edges are padded up to whole chunks of ``block_e`` with inert edges
+    (feasible ≡ 0 masks them to NEG everywhere: they leave V unchanged and
+    set no bit), placed ahead of the word's highest edge.  Every chunk then
+    lies inside ONE 32-bit word, whatever ``block_e`` is.  When ``block_e``
+    divides 32 only the top word takes pads, at the ids above E-1.
 
-    ``bitpos`` (n_chunks, block_e) int32 is each slot's bit (edge id mod
-    32; trailing pads read 0).  ``words`` and ``masks`` (n_chunks, k)
-    int32 name the k word planes each chunk owns and its bits in each —
-    k = 1 when no chunk straddles, else 2, a one-word chunk repeating its
-    word (ORing the same bits twice is idempotent)."""
-    W = packed_words(n_edges)
-    n_chunks = -(-n_edges // block_e)
-    pad = n_chunks * block_e - n_edges
-    top = pad if 32 % block_e == 0 else 0
-    bitpos = np.zeros(n_chunks * block_e, np.int32)
-    owned = np.zeros((n_chunks, W), np.uint32)
-    for idx, e in enumerate(range(n_edges - 1 + top, -1, -1)):
-        bitpos[idx] = e % 32
-        if e < n_edges:
-            owned[idx // block_e, e // 32] |= np.uint32(1) << np.uint32(e % 32)
-    per_chunk = [np.flatnonzero(row) for row in owned]
-    k = max(len(ws) for ws in per_chunk)
-    words = np.array([np.resize(ws, k) for ws in per_chunk], np.int32)
-    masks = np.take_along_axis(owned, words, axis=1).view(np.int32)
-    return (top, pad - top), bitpos.reshape(n_chunks, block_e), words, masks
+    ``order`` (n_chunks, block_e) int32 is each slot's edge id, E for a
+    pad; ``words`` (n_chunks,) int32 is the word each chunk owns."""
+    order, words = [], []
+    for w in range(packed_words(n_edges) - 1, -1, -1):
+        edges = list(range(min(32 * w + 32, n_edges) - 1, 32 * w - 1, -1))
+        row = [n_edges] * (-len(edges) % block_e) + edges
+        order += row
+        words += [w] * (len(row) // block_e)
+    return (np.array(order, np.int32).reshape(-1, block_e),
+            np.array(words, np.int32))
 
 
-def _edge_chunks(arr, pads, block_e: int):
-    """A per-edge (E, …) operand in processing order (E-1 … 0), inert-padded
-    by ``pads`` and cut into (n_chunks, block_e, …) chunks."""
-    padded = jnp.pad(arr[::-1], (pads,) + ((0, 0),) * (arr.ndim - 1))
-    return padded.reshape((-1, block_e) + arr.shape[1:])
-
-
-def _merge_chunk_bits(dec, bits, words, masks):
-    """OR one fused chunk's decision bits into the word planes it owns.
-
-    ``dec`` (…, W·Sp, Cp) stacks the packed word planes along its rows,
-    ``bits`` is (…, Sp, Cp), and ``words``/``masks`` (k,) come from
-    :func:`_chunk_layout`.  Each owned word is sliced out, ORed with its
-    masked bits and written back in place: a chunk moves 3·k planes, never
-    all W.  The carry is flat, not (W, Sp, Cp), so that its layout stays
-    row-major whatever the consumer of the decisions prefers and one word
-    is a contiguous run of whole tiles: given a word axis, the TPU layout
-    assignment may tile it together with the budget axis, and every word
-    slice then turns strided."""
-    rows = bits.shape[-2]
-    for w, mask in zip(words, masks):
-        word = jax.lax.dynamic_slice_in_dim(dec, w * rows, rows, -2)
-        dec = jax.lax.dynamic_update_slice_in_dim(dec, word | (bits & mask),
-                                                  w * rows, -2)
-    return dec
+def _edge_chunks(arr, order):
+    """A per-edge (E, …) operand laid out as ``order`` (n_chunks, block_e,
+    …), with zeros — inert — in the pad slots."""
+    return jnp.concatenate([arr, jnp.zeros_like(arr[:1])])[order]
 
 
 def _check_block_e(block_e: int) -> None:
@@ -943,41 +919,43 @@ def _dp_forward_fused(
     W = packed_words(n_edges)
     dec0 = jnp.zeros((W * Sp, Cp), jnp.int32)
 
-    # edges processed E-1 … 0 in whole chunks, inert pads laid out by
-    # _chunk_layout (top ids when block_e divides 32, else after edge 0)
-    pads, bitpos, words, masks = _chunk_layout(n_edges, block_e)
-    chunked = functools.partial(_edge_chunks, pads=pads, block_e=block_e)
-    xs = (chunked(upsilon), chunked(offsets), chunked(sigma2),
-          jnp.asarray(bitpos), chunked(feas_p), jnp.asarray(words),
-          jnp.asarray(masks))
+    # edges processed E-1 … 0 in whole chunks, one word per chunk
+    order, words = _chunk_layout(n_edges, block_e)
+    chunked = functools.partial(_edge_chunks, order=order)
+    xs = (jnp.asarray(words)[:, None], chunked(upsilon), chunked(offsets),
+          chunked(sigma2), jnp.asarray(order % 32), chunked(feas_p))
 
-    multi_row = Sp // bs > 1
+    n_s = Sp // bs
     kernel = functools.partial(_fused_chunk_kernel, n_chunk=block_e,
                                u_max=u_max, off_max=off_max,
-                               multi_row=multi_row)
-    scalar_specs = [pl.BlockSpec(memory_space=pltpu.SMEM)] * 4
+                               multi_row=n_s > 1)
+    tile = pl.BlockSpec((bs, block_c), lambda i, j, w: (i, j))
+    # tile (i, j) of the chunk's word plane in the flat (W·Sp, Cp) carry
+    word_tile = pl.BlockSpec((bs, block_c),
+                             lambda i, j, w: (w[0] * n_s + i, j))
     call = pl.pallas_call(
         kernel,
-        grid=(Sp // bs, Cp // block_c),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(n_s, Cp // block_c),
+            in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)] * 4 + [
+                pl.BlockSpec((block_e, block_c), lambda i, j, w: (0, j)),
+                tile,
+                word_tile,
+            ],
+            out_specs=(tile, word_tile),
+            scratch_shapes=_fused_scratch(block_e, bs, block_c, u_max,
+                                          off_max, Cp, n_s > 1),
+        ),
         out_shape=(jax.ShapeDtypeStruct((Sp, Cp), jnp.int32),
-                   jax.ShapeDtypeStruct((Sp, Cp), jnp.int32)),
-        in_specs=scalar_specs + [
-            pl.BlockSpec((block_e, block_c), lambda i, j: (0, j)),
-            pl.BlockSpec((bs, block_c), lambda i, j: (i, j)),
-        ],
-        out_specs=(pl.BlockSpec((bs, block_c), lambda i, j: (i, j)),
-                   pl.BlockSpec((bs, block_c), lambda i, j: (i, j))),
-        scratch_shapes=_fused_scratch(block_e, bs, block_c, u_max, off_max,
-                                      Cp, multi_row),
+                   jax.ShapeDtypeStruct((W * Sp, Cp), jnp.int32)),
+        input_output_aliases={6: 0, 7: 1},  # V and the word carry, in place
         interpret=interpret,
         name="dp_forward_fused",
     )
 
     def body(carry, x):
-        V, dec = carry
-        *operands, words_c, masks_c = x
-        Vn, bits = call(*operands, V)
-        return (Vn, _merge_chunk_bits(dec, bits, words_c, masks_c)), None
+        return tuple(call(*x, *carry)), None
 
     (V, dec), _ = jax.lax.scan(body, (V0, dec0), xs)
     return V[:S, :C], dec.reshape(W, Sp, Cp)[:, :S, :C]
@@ -1014,6 +992,7 @@ def _row(ref):
 
 
 def _batched_fused_kernel(
+    word_ref,
     ups_ref,
     offs_ref,
     sig_ref,
@@ -1021,8 +1000,9 @@ def _batched_fused_kernel(
     alw_ref,
     feas_ref,
     vin_ref,
+    win_ref,
     vout_ref,
-    bits_ref,
+    wout_ref,
     rowh_ref,
     lefth_ref,
     *,
@@ -1038,10 +1018,11 @@ def _batched_fused_kernel(
     ``allowed`` row masking every edge.  Scratches are per-instance state
     and stay unbatched."""
     _fused_chunk_kernel(
-        _row(ups_ref), offs_ref, _row(sig_ref), bitpos_ref, feas_ref,
-        _Lead0(vin_ref), _Lead0(vout_ref), _Lead0(bits_ref), rowh_ref,
-        lefth_ref, n_chunk=n_chunk, u_max=u_max, off_max=off_max,
-        multi_row=multi_row, grid_base=1, alw_ref=_row(alw_ref))
+        word_ref, _row(ups_ref), offs_ref, _row(sig_ref), bitpos_ref,
+        feas_ref, _Lead0(vin_ref), _Lead0(win_ref), _Lead0(vout_ref),
+        _Lead0(wout_ref), rowh_ref, lefth_ref, n_chunk=n_chunk, u_max=u_max,
+        off_max=off_max, multi_row=multi_row, grid_base=1,
+        alw_ref=_row(alw_ref))
 
 
 def _dp_forward_fused_batched(
@@ -1073,53 +1054,58 @@ def _dp_forward_fused_batched(
     W = packed_words(n_edges)
     dec0 = jnp.zeros((B, W * Sp, Cp), jnp.int32)
 
-    pads, bitpos, words, masks = _chunk_layout(n_edges, block_e)
-    chunked = functools.partial(_edge_chunks, pads=pads, block_e=block_e)
+    order, words = _chunk_layout(n_edges, block_e)
+    chunked = functools.partial(_edge_chunks, order=order)
 
     def inst_chunked(arr):  # (B, E) → (n_chunks, B, 1, block_e)
         return chunked(arr.T).transpose(0, 2, 1)[:, :, None, :]
 
-    xs = (inst_chunked(upsilon), chunked(offsets), inst_chunked(sigma2),
-          jnp.asarray(bitpos), inst_chunked(allowed), chunked(feas_p),
-          jnp.asarray(words), jnp.asarray(masks))
+    xs = (jnp.asarray(words)[:, None], inst_chunked(upsilon),
+          chunked(offsets), inst_chunked(sigma2), jnp.asarray(order % 32),
+          inst_chunked(allowed), chunked(feas_p))
 
-    multi_row = Sp // bs > 1
+    n_s = Sp // bs
     kernel = functools.partial(_batched_fused_kernel, n_chunk=block_e,
                                u_max=u_max, off_max=off_max,
-                               multi_row=multi_row)
-    inst_row = pl.BlockSpec((1, 1, block_e), lambda b, i, j: (b, 0, 0),
+                               multi_row=n_s > 1)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    inst_row = pl.BlockSpec((1, 1, block_e), lambda b, i, j, w: (b, 0, 0),
                             memory_space=pltpu.SMEM)
+    tile = pl.BlockSpec((1, bs, block_c), lambda b, i, j, w: (b, i, j))
+    word_tile = pl.BlockSpec((1, bs, block_c),
+                             lambda b, i, j, w: (b, w[0] * n_s + i, j))
     call = pl.pallas_call(
         kernel,
-        grid=(B, Sp // bs, Cp // block_c),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B, n_s, Cp // block_c),
+            in_specs=[
+                inst_row,  # Υ̂ chunk
+                smem,  # offsets
+                inst_row,  # Σ̂² chunk
+                smem,  # bit positions
+                inst_row,  # allowed chunk
+                pl.BlockSpec((block_e, block_c), lambda b, i, j, w: (0, j)),
+                tile,
+                word_tile,
+            ],
+            out_specs=(tile, word_tile),
+            scratch_shapes=_fused_scratch(block_e, bs, block_c, u_max,
+                                          off_max, Cp, n_s > 1),
+        ),
         out_shape=(jax.ShapeDtypeStruct((B, Sp, Cp), jnp.int32),
-                   jax.ShapeDtypeStruct((B, Sp, Cp), jnp.int32)),
-        in_specs=[
-            inst_row,  # Υ̂ chunk
-            pl.BlockSpec(memory_space=pltpu.SMEM),  # offsets
-            inst_row,  # Σ̂² chunk
-            pl.BlockSpec(memory_space=pltpu.SMEM),  # bit positions
-            inst_row,  # allowed chunk
-            pl.BlockSpec((block_e, block_c), lambda b, i, j: (0, j)),
-            pl.BlockSpec((1, bs, block_c), lambda b, i, j: (b, i, j)),
-        ],
-        out_specs=(
-            pl.BlockSpec((1, bs, block_c), lambda b, i, j: (b, i, j)),
-            pl.BlockSpec((1, bs, block_c), lambda b, i, j: (b, i, j))),
-        scratch_shapes=_fused_scratch(block_e, bs, block_c, u_max, off_max,
-                                      Cp, multi_row),
+                   jax.ShapeDtypeStruct((B, W * Sp, Cp), jnp.int32)),
+        input_output_aliases={7: 0, 8: 1},  # V and the word carry, in place
         interpret=interpret,
         name="dp_forward_fused_batched",
     )
 
     def body(carry, x):
-        V, dec = carry
-        *operands, words_c, masks_c = x
-        Vn, bits = call(*operands, V)
-        return (Vn, _merge_chunk_bits(dec, bits, words_c, masks_c)), None
+        return tuple(call(*x, *carry)), None
 
     (V, dec), _ = jax.lax.scan(body, (V0, dec0), xs)
     return V[:, :S, :C], dec.reshape(B, W, Sp, Cp)[:, :, :S, :C]
+
 
 
 def _dp_forward_blocked(

@@ -11,7 +11,8 @@ from repro.kernels.ssd.ops import ssd_op
 from repro.kernels.ssd.ref import ssd_ref
 from repro.core.dp import build_tables, solve_budgeted_dp
 from repro.kernels.budgeted_dp.kernel import (
-    MAX_BLOCK_E, NEG, VMEM_BUDGET_BYTES, batched_fused_tile_vmem_bytes,
+    MAX_BLOCK_E, NEG, VMEM_BUDGET_BYTES, _chunk_layout,
+    batched_fused_tile_vmem_bytes,
     batched_modeled_hbm_bytes, batched_vmem_bytes,
     c_blocked_tile_vmem_bytes, choose_tiling, dp_forward_pallas,
     dp_forward_pallas_batched, fused_tile_vmem_bytes, modeled_hbm_bytes,
@@ -833,14 +834,17 @@ def test_budgeted_dp_fused_batched_merges_owned_words(E, block_e):
 
 
 @pytest.mark.parametrize("batched", [False, True], ids=["single", "batched"])
-@pytest.mark.parametrize("block_e,owned", [(8, 1), (5, 2)],
-                         ids=["one_word", "two_words"])
-def test_fused_chunk_scan_updates_only_owned_words(batched, block_e, owned):
+@pytest.mark.parametrize("block_e", [8, 5], ids=["one_word", "two_words"])
+def test_fused_chunk_scan_updates_only_owned_words(batched, block_e):
     """Structural guard on the fused chunk scan at E=72 (W=3 packed
-    words): its decision carry is updated only by dynamic_update_slice of
-    one (Sp, Cp) word plane per owned word — one for word-aligned chunks,
-    two for chunks that may straddle — and no other op of the scan body
-    writes a carry-sized array, so an OR over all W planes fails here."""
+    words): the chunk's pallas_call takes the value plane and the flat
+    (W·Sp, Cp) decision carry and returns both in place
+    (``input_output_aliases``), no other op of the scan body writes a
+    carry-sized array (no dynamic_update_slice merge), and the call's one
+    scalar-prefetched operand is the index of the ONE word the chunk owns:
+    block_e=8 divides 32, and block_e=5 (``two_words``: without padding,
+    its chunks would straddle two words) pads each word's edges to whole
+    chunks, so the scan runs one chunk per (word, chunk) of that layout."""
     E = 72
     ups, sig, feas, offs, v0 = _fused_problem(67, E)
     off_max, u_max = int(offs.max()), int(ups.max() + 1)
@@ -860,17 +864,45 @@ def test_fused_chunk_scan_updates_only_owned_words(batched, block_e, owned):
              and any(b.primitive.name == "pallas_call"
                      for b in e.params["jaxpr"].jaxpr.eqns)]
     assert len(scans) == 1
+    assert scans[0].params["length"] == sum(
+        -(-min(32, E - 32 * w) // block_e) for w in range(3))
     body = scans[0].params["jaxpr"].jaxpr
     call = next(b for b in body.eqns if b.primitive.name == "pallas_call")
-    plane = call.outvars[1].aval.shape  # the chunk's bits, (…, Sp, Cp)
-    carry = plane[:-2] + (3 * plane[-2], plane[-1])
-    updates = [b for b in body.eqns
-               if b.primitive.name == "dynamic_update_slice"
-               and b.invars[0].aval.shape == carry]
-    assert [u.invars[1].aval.shape for u in updates] == [plane] * owned
+    plane, carry = (v.aval.shape for v in call.outvars)  # V, word carry
+    assert carry == plane[:-2] + (3 * plane[-2], plane[-1])
+    n_in = len(call.invars)
+    assert dict(call.params["input_output_aliases"]) == {n_in - 2: 0,
+                                                         n_in - 1: 1}
+    assert [v.aval.shape for v in call.invars[-2:]] == [plane, carry]
+    assert call.params["grid_mapping"].num_index_operands == 1
+    assert call.invars[0].aval.shape == (1,)  # one word index per chunk
     writers = {b.primitive.name for b in body.eqns
                for v in b.outvars if v.aval.shape == carry}
-    assert writers == {"dynamic_update_slice"}
+    assert writers == {"pallas_call"}
+    assert not [b for b in body.eqns
+                if b.primitive.name == "dynamic_update_slice"]
+
+
+@pytest.mark.parametrize("block_e", [3, 4, 5, 8, 14])
+@pytest.mark.parametrize("E", [33, 40, 72, 252])
+def test_chunk_layout_one_word_per_chunk(E, block_e):
+    """The fused pipeline's edge layout: every chunk lies in the one word
+    it names, the real edges appear once each in processing order E-1 …
+    0, and when block_e divides 32 the chunks are those of one inert pad
+    run above edge E-1 (Fig. 5's E=252: 32 chunks of 8, 63 of 4)."""
+    order, words = _chunk_layout(E, block_e)
+    assert order.shape == (len(words), block_e)
+    real = order < E
+    assert (order[~real] == E).all()
+    np.testing.assert_array_equal(order[real], np.arange(E - 1, -1, -1))
+    for row, w in zip(order, words):
+        assert (row[row < E] // 32 == w).all()
+    if 32 % block_e == 0:
+        top = -E % block_e
+        flat = np.minimum(np.arange(E - 1 + top, -1, -1), E)
+        np.testing.assert_array_equal(order, flat.reshape(-1, block_e))
+    if E == 252 and block_e in (4, 8):
+        assert len(words) == {8: 32, 4: 63}[block_e]
 
 
 def test_batched_ragged_pad_instances_inert():

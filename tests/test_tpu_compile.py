@@ -149,6 +149,42 @@ def test_fused_two_c_tiles_compile_for_v5e(one_chip, T, S, Sp):
     assert all(f"s32[{Sp},512]" in o and "f32" not in o for o in outs)
 
 
+def test_fused_fig5_tiling_compiles_for_v5e(one_chip):
+    """Fig. 5's largest graph at T = 100 (S = 43,345, C = 18) resolves the
+    fused grid (8, 1024, 128) under the VMEM charge of a value tile and a
+    word tile in and out, and that kernel compiles for a v5e: a tiling
+    whose tiles overflow the chip's scoped VMEM fails here."""
+    from repro.core import stats
+    from repro.core.graph import generate_instance
+    inst = generate_instance(seed=1, n_ports=16, n_servers=160,
+                             edge_prob=0.1)
+    tables = build_tables(inst.A, inst.c)
+    _, offs = prepare_tables(tables)
+    E, C, off_max = inst.n_edges, tables.n_states, int(offs.max())
+    S = stats.s_cap_for_horizon(100, inst.m) + 1
+    u_max = stats.u_max_for_horizon(100, inst.m)
+    be, bs, bc = choose_tiling(S, C, E, u_max, off_max)
+    assert (S, C, be, bs, bc) == (43_345, 18, 8, 1024, 128)
+
+    def fwd(ups, sig, feas, offs, v0):
+        return dp_forward_pallas(ups, sig, feas, offs, v0, n_edges=E,
+                                 u_max=u_max, off_max=off_max,
+                                 interpret=False, block_c=bc, block_s=bs,
+                                 block_e=be)
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    text = jax.jit(fwd).lower(
+        spec((E,), jnp.int32), spec((E,), jnp.int32),
+        spec((E, C), jnp.float32), spec((E,), jnp.int32),
+        spec((S, C), jnp.int32)).compile().as_text()
+    kernels = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert len(kernels) == 1
+    assert "(s32[44032,128]" in kernels[0]
+    assert "s32[352256,128]" in kernels[0]
+
+
 def test_batched_vmap_solve_compiles_to_one_kernel(one_chip, compiled_pallas):
     """jax.vmap of the compiled solve at B=64 (the fleet batch) is ONE
     kernel launch in the compiled program."""
@@ -212,22 +248,26 @@ _HLO_DEF = re.compile(r"^\s*(?:ROOT\s+)?%(\S+) = (\w+)\[([0-9,]*)\]\S* "
                       r"([\w-]+)\(([^)]*)\)", re.M)
 
 
-@pytest.mark.parametrize("block_e,owned", [(8, 1), (5, 2)],
-                         ids=["one_word", "two_words"])
-def test_fused_merge_writes_owned_words_for_v5e(one_chip, block_e, owned):
-    """Compiled for a v5e, the fused forward's decision carry (E=72, so
-    W=3 word planes) is written only in place: every int32 op the size of
-    the carry is a parameter, tuple element, the zero broadcast or a
-    ``dynamic-update-slice``, and each of those updates one (Sp, Cp) word
-    plane — no fusion or copy of all W planes per chunk, and no layout
-    copy of a word or bits plane on its way in or out of the carry."""
+@pytest.mark.parametrize("block_e", [8, 5], ids=["one_word", "two_words"])
+def test_fused_merge_writes_owned_words_for_v5e(one_chip, block_e):
+    """Compiled for a v5e, each fused chunk ORs its decision bits into the
+    one word plane it owns inside the kernel (E=72, so W=3 word planes;
+    block_e=5, ``two_words``, pads each word's edges to whole chunks, where
+    its chunks would otherwise straddle two words): the
+    ``tpu_custom_call`` aliases the value plane and the flat (W·Sp, Cp)
+    decision carry to its two outputs, every int32 op the size of the
+    carry is a parameter, tuple element, the zero broadcast or that custom
+    call, and no (Sp, Cp) plane is copied at all.  The forward is taken
+    as the solve consumes it: the value column of one state and the
+    packed words."""
     E, S, C, Sp, Cp = 72, 256, 18, 256, 128
     W = (E + 31) // 32
 
     def fwd(ups, sig, feas, offs, v0):
-        return dp_forward_pallas(ups, sig, feas, offs, v0, n_edges=E,
-                                 u_max=8, off_max=17, interpret=False,
-                                 block_c=Cp, block_s=64, block_e=block_e)
+        V, dec = dp_forward_pallas(ups, sig, feas, offs, v0, n_edges=E,
+                                   u_max=8, off_max=17, interpret=False,
+                                   block_c=Cp, block_s=64, block_e=block_e)
+        return V[:, C - 1], dec
 
     def spec(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -236,24 +276,19 @@ def test_fused_merge_writes_owned_words_for_v5e(one_chip, block_e, owned):
         spec((E,), jnp.int32), spec((E,), jnp.int32),
         spec((E, C), jnp.float32), spec((E,), jnp.int32),
         spec((S, C), jnp.int32)).compile().as_text()
+    kernels = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert len(kernels) == 1
+    out, operands = kernels[0].split(" custom-call(", 1)
+    assert f"(s32[{Sp},{Cp}]" in out and f"s32[{W * Sp},{Cp}]" in out
+    n_in = operands.split(")")[0].count("%")
+    assert (f"output_to_operand_aliasing={{{{0}}: ({n_in - 2}, {{}}), "
+            f"{{1}}: ({n_in - 1}, {{}})}}") in kernels[0]
     defs = _HLO_DEF.findall(text)
     sizes = {name: math.prod(int(d) for d in dims.split(",") if d)
              for name, _, dims, _, _ in defs}
-    carry = [(op, args) for name, dtype, _, op, args in defs
-             if dtype == "s32" and sizes[name] == W * Sp * Cp]
-    assert {op for op, _ in carry} <= {"parameter", "get-tuple-element",
-                                       "broadcast", "dynamic-update-slice"}
-    updates = [args.split(", ")[1].lstrip("%") for op, args in carry
-               if op == "dynamic-update-slice"]
-    assert len(updates) == owned
-    assert all(sizes[u] == Sp * Cp for u in updates)
-    # the int32 value plane is the one (Sp, Cp) plane that may be copied
-    # (into the kernel's last operand, and out of the scan before its
-    # final (S, C) slice); no word or bits plane is
-    vin = {ln.split("custom-call(")[1].split(")")[0].split("%")[-1]
-           for ln in text.splitlines() if "tpu_custom_call" in ln}
-    vout = {args.lstrip("%") for _, _, dims, op, args in defs
-            if op == "slice" and dims == f"{S},{C}"}
+    carry = {op for name, dtype, _, op, _ in defs
+             if dtype == "s32" and sizes[name] == W * Sp * Cp}
+    assert carry <= {"parameter", "get-tuple-element", "broadcast",
+                     "custom-call"}
     assert not [name for name, dtype, _, op, _ in defs
-                if dtype == "s32" and op == "copy" and sizes[name] == Sp * Cp
-                and name not in vin | vout]
+                if dtype == "s32" and op == "copy" and sizes[name] == Sp * Cp]
