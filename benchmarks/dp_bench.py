@@ -58,7 +58,9 @@ reference loop before it is timed, and the batched record carries
 vectorized XLA loops, so wall-clock parity is expected there; the
 launch-grid advantage is the HBM model and launch count, measured on
 real TPUs), and ``hbm_reduction_vs_vmapped`` — the modeled shared-vs-
-replicated traffic ratio (``kernel.batched_modeled_hbm_bytes``).
+replicated traffic ratio (``kernel.batched_modeled_hbm_bytes``).  A
+fleet of tiled planes maps the single solve and shares nothing, so its
+``hbm_reduction_vs_vmapped`` is 1.0: the baselines run the same tiling.
 
 Configs with ``incremental: True`` additionally time the CROSS-SLOT
 INCREMENTAL legs (``incr_*`` backends) over a recorded post-exploration
@@ -309,7 +311,7 @@ def _bench_batched(
     # the replicated-operand lowering the custom batching rule replaces
     single_kw = dict(s_cap=s_cap, u_max=u_max, off_max=off_max,
                      full_state=tables.full_state, interpret=interpret,
-                     block_c=None, block_s=None, block_e=None)
+                     block_c=bc, block_s=bs, block_e=be)
     feas_j, offs_j = jnp.asarray(feas), jnp.asarray(offs)
 
     def one(u, s, l, a):
@@ -335,7 +337,7 @@ def _bench_batched(
                 err_msg=f"{leg} B={B} instance {b}")
             assert int(s_star[b]) == int(info_ref["s_star"]), (leg, B, b)
 
-    one_hbm = modeled_hbm_bytes(S, C, E, u_max, off_max, None, None, None)
+    one_hbm = modeled_hbm_bytes(S, C, E, u_max, off_max, be, bs, bc)
     batched_hbm = batched_modeled_hbm_bytes(S, C, E, u_max, off_max, B,
                                             be, bs, bc)
     recs = {}

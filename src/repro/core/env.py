@@ -343,9 +343,9 @@ def simulate_batch(
 
     With a batch-aware DP backend (``Solver.accepts_batch`` — the Pallas
     backends), the vmap over seeds triggers the solve core's custom
-    batching rule: each slot issues ONE fleet-batched kernel launch for
-    the whole seed batch, with the DP-table operands shared across seeds
-    rather than replicated per instance."""
+    batching rule: on a whole plane each slot issues ONE fleet-batched
+    kernel launch for the whole seed batch, with the DP-table operands
+    shared across seeds rather than replicated per instance."""
     tables, scenario, params = _scenario_args(instance, tables, scenario)
     keys = jnp.stack([jax.random.PRNGKey(int(s)) for s in seeds])
     (sw, sw_star, regret, nd), pfinal = _run_batch(

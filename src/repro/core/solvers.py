@@ -31,8 +31,10 @@ Registry:
                      never changes results.  Batch-aware
                      (``accepts_batch``): under ``jax.vmap`` the solve
                      core's custom batching rule runs every mapped
-                     instance in ONE fleet-batched kernel launch with the
-                     DP-table operands shared across the batch.  See
+                     instance of a whole plane in ONE fleet-batched kernel
+                     launch with the DP-table operands shared across the
+                     batch, and a tiled plane's instances one after
+                     another through the single solve.  See
                      ``docs/kernel_pipeline.md`` for the kernel internals.
   pallas_interpret — the same kernel forced through the interpreter on any
                      backend; what differential tests run on CPU CI.
@@ -146,9 +148,10 @@ class Solver:
 
         Backends with ``accepts_batch`` carry a custom batching rule on
         the solve core: ``jax.vmap`` of this call dispatches all mapped
-        instances through ONE batched kernel launch with the DP-table
-        operands shared (never replicated per instance) — results stay
-        bit-exact with a per-instance loop.  Other backends vmap
+        instances of a whole plane through ONE batched kernel launch with
+        the DP-table operands shared (never replicated per instance), and
+        maps a tiled plane's instances through the single solve — results
+        stay bit-exact with a per-instance loop.  Other backends vmap
         conventionally (per-instance computation, replicated operands)."""
         return self._fn(upsilon, sigma2, tables, s_cap, s_limit, allowed,
                         u_max)

@@ -103,13 +103,11 @@ way folding per-instance eligibility into the feasibility plane under
 ``allowed``) stream as (block_b, 1, E) SMEM rows.  The single-instance
 whole-plane solve is this kernel at B = 1.  Ragged batches pad with inert
 instances (``allowed ≡ 0`` masks every edge to NEG, so the pads compute v0
-and zero decisions).  When the per-instance plane outgrows VMEM the batch
-instead becomes the OUTERMOST grid dimension of the edge-fused pipeline
-(block_b pinned to 1): each instance re-initializes the halo-history
-scratches at its own (i=0, j=0) corner, so the fused kernel body is reused
-unchanged.  ``choose_tiling(..., batch=B)`` resolves the whole (block_b,
-block_e, block_s, block_c) split, shrinking the batch axis BEFORE the plane
-axes.
+and zero decisions).  A fleet of tiled planes maps the single-instance
+solve over its instances (``jax.lax.map``), each with its ``allowed`` row
+folded into the feasibility plane.  ``choose_tiling(..., batch=B)``
+resolves the whole (block_b, block_e, block_s, block_c) split, shrinking
+the batch axis BEFORE the plane axes.
 
 Value planes, the halo scratches and the per-edge gains are int32, as in
 ``core.dp``, with the sentinel ``NEG = core.dp.NEG = −2²⁹``: every value is
@@ -143,9 +141,8 @@ from ...core import dp as core_dp
 __all__ = ["NEG", "VMEM_BUDGET_BYTES", "MAX_BLOCK_E", "resolve_interpret",
            "packed_words", "unblocked_vmem_bytes", "c_blocked_tile_vmem_bytes",
            "tiled_vmem_bytes", "fused_tile_vmem_bytes", "batched_vmem_bytes",
-           "batched_fused_tile_vmem_bytes", "modeled_hbm_bytes",
-           "batched_modeled_hbm_bytes", "choose_tiling", "dp_forward_pallas",
-           "dp_forward_pallas_batched"]
+           "modeled_hbm_bytes", "batched_modeled_hbm_bytes", "choose_tiling",
+           "dp_forward_pallas", "dp_forward_pallas_batched"]
 
 NEG = int(core_dp.NEG)  # −2²⁹, the int32 sentinel of the reference DP
 
@@ -266,30 +263,6 @@ def batched_vmem_bytes(
     return 4 * (2 * (block_b * per + shared) + 4 * plane)
 
 
-def batched_fused_tile_vmem_bytes(
-    block_e: int,
-    block_s: int,
-    block_c: int,
-    u_max: int,
-    off_max: int,
-    S: int,
-    C: int,
-    block_b: int,
-) -> int:
-    """Per-grid-step VMEM of the BATCHED edge-fused pipeline: the shared
-    per-chunk feasibility block loads once; everything per-instance — the
-    plane tiles, the shift temporaries and both halo-history scratches —
-    charges × ``block_b``.  The batched driver pins ``block_b = 1`` on this
-    path (one instance per grid step — the per-instance halo histories are
-    what overflowed the budget in the first place), but the model keeps
-    the general form so the batched decision rule charges the batch axis
-    uniformly.  All 4-byte, in whole (8, 128) tiles."""
-    shared = 2 * _tile_words(block_e, block_c)
-    per = fused_tile_vmem_bytes(block_e, block_s, block_c, u_max, off_max,
-                                S, C) // 4 - shared
-    return 4 * (block_b * per + shared)
-
-
 def modeled_hbm_bytes(
     S: int, C: int, n_edges: int, u_max: int, off_max: int, block_e, block_s, block_c
 ) -> int:
@@ -344,17 +317,14 @@ def batched_modeled_hbm_bytes(
     shared operands per instance (vmap folds per-instance eligibility
     into ``batch`` copies of the feasibility plane), so its model is
     simply ``batch · modeled_hbm_bytes(...)`` — the ratio of the two is
-    the ``hbm_reduction_vs_vmapped`` figure dp_bench records."""
+    the ``hbm_reduction_vs_vmapped`` figure dp_bench records.  A tiled
+    plane shares nothing: each mapped instance reads its own feasibility
+    blocks, so its fleet streams exactly ``batch`` single solves."""
     per = modeled_hbm_bytes(S, C, n_edges, u_max, off_max,
                             block_e, block_s, block_c)
-    if block_c is None:
-        shared = 4 * (S * C + n_edges * C)  # v0 + feasibility plane
-    else:
-        Cp = -(-C // block_c) * block_c
-        if block_e is None:
-            shared = 4 * n_edges * Cp  # feasibility tiles per edge
-        else:
-            shared = 4 * _chunk_layout(n_edges, block_e)[0].size * Cp
+    if block_c is not None:
+        return batch * per
+    shared = 4 * (S * C + n_edges * C)  # v0 + feasibility plane
     return shared + batch * (per - shared)
 
 
@@ -393,8 +363,8 @@ def choose_tiling(
     full plane VMEM-resident — a smaller fleet per grid step is always
     cheaper than giving up plane residency.  Only when even ``block_b =
     1`` overflows does the per-instance plane tile (by the 3-tuple rule
-    below) with ``block_b`` pinned to 1 (the fused pipeline batches as
-    the outermost grid dimension, one instance per step).
+    below) with ``block_b`` pinned to 1 (the instances then run one after
+    another through the single-instance solve).
 
     Returns ``(None, None, None)`` when the whole-plane kernel fits the
     VMEM budget (edges already run inside one pallas_call there — nothing
@@ -772,8 +742,6 @@ def _fused_chunk_kernel(
     u_max: int,
     off_max: int,
     multi_row: bool,
-    grid_base: int = 0,
-    alw_ref=None,
 ):
     """``n_chunk`` consecutive edges on one (block_s, block_c) tile.
 
@@ -792,20 +760,11 @@ def _fused_chunk_kernel(
     OR at bit ``bitpos[k]`` (global edge id mod 32) into the tile of the
     one word plane the chunk owns: ``wout`` starts from ``win``, the
     word's bits so far.  ``word_ref`` (that word's index) is read only by
-    the index maps.
-
-    Batched reuse: the batched pipeline prepends the batch as grid axis 0
-    (``grid_base=1`` shifts the (i, j) grid ids right) and passes the
-    per-instance eligibility row as ``alw_ref`` — everything else is
-    identical, because each instance re-initializes the tiles and halo
-    state at its own (i=0, j=0) corner: the tiles reload from ``vin`` and
-    ``win`` every step, the clamp-row branch covers i=0 without using
-    ``rowh``, and the j=0 ``lefth`` columns are only ever read by
-    infeasible (masked) states."""
+    the index maps."""
     Bs, Bc = vin_ref.shape
     lw = lefth_ref.shape[-1]
-    i = pl.program_id(grid_base)
-    j = pl.program_id(grid_base + 1)
+    i = pl.program_id(0)
+    j = pl.program_id(1)
     rd = (i + 1) % 2  # rowh bank written by S-row i-1
     wr = i % 2
     col = pl.multiple_of(j * Bc, Bc)
@@ -838,8 +797,6 @@ def _fused_chunk_kernel(
             # single-S-row grid: no up neighbors exist, no history to keep
             take = _row_shift(x, None, u)
         live = feas_ref[pl.ds(k, 1), :] > 0
-        if alw_ref is not None:
-            live = live & (alw_ref[k] > 0)
         Xn, dec = _edge_update(X, take, sig, live)
         wout_ref[...] = wout_ref[...] | jnp.where(dec, bit, 0)
         vout_ref[...] = Xn
@@ -870,30 +827,6 @@ def _chunk_layout(n_edges: int, block_e: int):
             np.array(words, np.int32))
 
 
-def _edge_chunks(arr, order):
-    """A per-edge (E, …) operand laid out as ``order`` (n_chunks, block_e,
-    …), with zeros — inert — in the pad slots."""
-    return jnp.concatenate([arr, jnp.zeros_like(arr[:1])])[order]
-
-
-def _check_block_e(block_e: int) -> None:
-    if not 1 <= block_e <= MAX_BLOCK_E:
-        raise ValueError(
-            f"block_e={block_e} outside [1, {MAX_BLOCK_E}]: a fused chunk "
-            "packs its decision bits into one int32 word plane, so "
-            "in-chunk bit positions (edge id mod 32) must stay distinct")
-
-
-def _fused_scratch(block_e, bs, block_c, u_max, off_max, Cp, multi_row):
-    """The halo-history scratches: ``rowh`` (a token buffer on a
-    single-S-row grid, which never reads it — so the large-C fused regime
-    is not charged 2·block_e·UH·Cp for it) and ``lefth``."""
-    uh, lw = _halo_widths(bs, block_c, u_max, off_max)
-    rowh_shape = (2 * block_e, uh, Cp) if multi_row else (1, 1, 1)
-    return [pltpu.VMEM(rowh_shape, jnp.int32),
-            pltpu.VMEM((block_e, bs, lw), jnp.int32)]
-
-
 def _dp_forward_fused(
     upsilon,
     sigma2,
@@ -909,7 +842,6 @@ def _dp_forward_fused(
     block_c: int,
     interpret: bool,
 ):
-    _check_block_e(block_e)
     S, C = v0.shape
     Cp = -(-C // block_c) * block_c
     bs = _pad_to(S, _SUBLANES) if block_s is None else block_s
@@ -921,7 +853,10 @@ def _dp_forward_fused(
 
     # edges processed E-1 … 0 in whole chunks, one word per chunk
     order, words = _chunk_layout(n_edges, block_e)
-    chunked = functools.partial(_edge_chunks, order=order)
+
+    def chunked(arr):  # (E, …) → (n_chunks, block_e, …), pads inert zeros
+        return jnp.concatenate([arr, jnp.zeros_like(arr[:1])])[order]
+
     xs = (jnp.asarray(words)[:, None], chunked(upsilon), chunked(offsets),
           chunked(sigma2), jnp.asarray(order % 32), chunked(feas_p))
 
@@ -929,6 +864,11 @@ def _dp_forward_fused(
     kernel = functools.partial(_fused_chunk_kernel, n_chunk=block_e,
                                u_max=u_max, off_max=off_max,
                                multi_row=n_s > 1)
+    # halo-history scratches: ``rowh`` is a token buffer on a single-S-row
+    # grid, which never reads it (so the large-C fused regime is not
+    # charged 2·block_e·UH·Cp for it), and ``lefth``
+    uh, lw = _halo_widths(bs, block_c, u_max, off_max)
+    rowh_shape = (2 * block_e, uh, Cp) if n_s > 1 else (1, 1, 1)
     tile = pl.BlockSpec((bs, block_c), lambda i, j, w: (i, j))
     # tile (i, j) of the chunk's word plane in the flat (W·Sp, Cp) carry
     word_tile = pl.BlockSpec((bs, block_c),
@@ -944,8 +884,8 @@ def _dp_forward_fused(
                 word_tile,
             ],
             out_specs=(tile, word_tile),
-            scratch_shapes=_fused_scratch(block_e, bs, block_c, u_max,
-                                          off_max, Cp, n_s > 1),
+            scratch_shapes=[pltpu.VMEM(rowh_shape, jnp.int32),
+                            pltpu.VMEM((block_e, bs, lw), jnp.int32)],
         ),
         out_shape=(jax.ShapeDtypeStruct((Sp, Cp), jnp.int32),
                    jax.ShapeDtypeStruct((W * Sp, Cp), jnp.int32)),
@@ -959,153 +899,6 @@ def _dp_forward_fused(
 
     (V, dec), _ = jax.lax.scan(body, (V0, dec0), xs)
     return V[:S, :C], dec.reshape(W, Sp, Cp)[:, :S, :C]
-
-
-class _Lead0:
-    """Fixed-leading-index view of a batch-blocked ref.
-
-    The batched fused pipeline blocks per-instance operands as (1, …)
-    slabs; this adapter lets the 2-D fused-kernel body run on them
-    unchanged (every read/write gains a leading 0)."""
-
-    def __init__(self, ref):
-        self._ref = ref
-
-    @property
-    def shape(self):
-        return self._ref.shape[1:]
-
-    @staticmethod
-    def _at(idx):
-        return (0,) + (idx if isinstance(idx, tuple) else (idx,))
-
-    def __getitem__(self, idx):
-        return self._ref[self._at(idx)]
-
-    def __setitem__(self, idx, val):
-        self._ref[self._at(idx)] = val
-
-
-def _row(ref):
-    """The (block_e,) view of a (1, 1, block_e) per-instance SMEM row."""
-    return _Lead0(_Lead0(ref))
-
-
-def _batched_fused_kernel(
-    word_ref,
-    ups_ref,
-    offs_ref,
-    sig_ref,
-    bitpos_ref,
-    alw_ref,
-    feas_ref,
-    vin_ref,
-    win_ref,
-    vout_ref,
-    wout_ref,
-    rowh_ref,
-    lefth_ref,
-    *,
-    n_chunk: int,
-    u_max: int,
-    off_max: int,
-    multi_row: bool,
-):
-    """Batch-blocked adapter around :func:`_fused_chunk_kernel`: the body
-    runs unchanged on the (1, …) instance blocks through
-    fixed-leading-index views, with the (i, j) grid ids shifted one axis
-    right (batch is the outermost grid dimension) and the per-instance
-    ``allowed`` row masking every edge.  Scratches are per-instance state
-    and stay unbatched."""
-    _fused_chunk_kernel(
-        word_ref, _row(ups_ref), offs_ref, _row(sig_ref), bitpos_ref,
-        feas_ref, _Lead0(vin_ref), _Lead0(win_ref), _Lead0(vout_ref),
-        _Lead0(wout_ref), rowh_ref, lefth_ref, n_chunk=n_chunk, u_max=u_max,
-        off_max=off_max, multi_row=multi_row, grid_base=1,
-        alw_ref=_row(alw_ref))
-
-
-def _dp_forward_fused_batched(
-    upsilon,
-    sigma2,
-    allowed,
-    feasible,
-    offsets,
-    v0,
-    *,
-    n_edges: int,
-    u_max: int,
-    off_max: int,
-    block_e: int,
-    block_s,
-    block_c: int,
-    interpret: bool,
-):
-    _check_block_e(block_e)
-    B = upsilon.shape[0]
-    S, C = v0.shape
-    Cp = -(-C // block_c) * block_c
-    bs = _pad_to(S, _SUBLANES) if block_s is None else block_s
-    Sp = -(-S // bs) * bs
-    V0 = jnp.broadcast_to(
-        jnp.pad(v0, ((0, Sp - S), (0, Cp - C)), constant_values=NEG)[None],
-        (B, Sp, Cp))
-    feas_p = jnp.pad(feasible, ((0, 0), (0, Cp - C)))  # pad states masked
-    W = packed_words(n_edges)
-    dec0 = jnp.zeros((B, W * Sp, Cp), jnp.int32)
-
-    order, words = _chunk_layout(n_edges, block_e)
-    chunked = functools.partial(_edge_chunks, order=order)
-
-    def inst_chunked(arr):  # (B, E) → (n_chunks, B, 1, block_e)
-        return chunked(arr.T).transpose(0, 2, 1)[:, :, None, :]
-
-    xs = (jnp.asarray(words)[:, None], inst_chunked(upsilon),
-          chunked(offsets), inst_chunked(sigma2), jnp.asarray(order % 32),
-          inst_chunked(allowed), chunked(feas_p))
-
-    n_s = Sp // bs
-    kernel = functools.partial(_batched_fused_kernel, n_chunk=block_e,
-                               u_max=u_max, off_max=off_max,
-                               multi_row=n_s > 1)
-    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
-    inst_row = pl.BlockSpec((1, 1, block_e), lambda b, i, j, w: (b, 0, 0),
-                            memory_space=pltpu.SMEM)
-    tile = pl.BlockSpec((1, bs, block_c), lambda b, i, j, w: (b, i, j))
-    word_tile = pl.BlockSpec((1, bs, block_c),
-                             lambda b, i, j, w: (b, w[0] * n_s + i, j))
-    call = pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(B, n_s, Cp // block_c),
-            in_specs=[
-                inst_row,  # Υ̂ chunk
-                smem,  # offsets
-                inst_row,  # Σ̂² chunk
-                smem,  # bit positions
-                inst_row,  # allowed chunk
-                pl.BlockSpec((block_e, block_c), lambda b, i, j, w: (0, j)),
-                tile,
-                word_tile,
-            ],
-            out_specs=(tile, word_tile),
-            scratch_shapes=_fused_scratch(block_e, bs, block_c, u_max,
-                                          off_max, Cp, n_s > 1),
-        ),
-        out_shape=(jax.ShapeDtypeStruct((B, Sp, Cp), jnp.int32),
-                   jax.ShapeDtypeStruct((B, W * Sp, Cp), jnp.int32)),
-        input_output_aliases={7: 0, 8: 1},  # V and the word carry, in place
-        interpret=interpret,
-        name="dp_forward_fused_batched",
-    )
-
-    def body(carry, x):
-        return tuple(call(*x, *carry)), None
-
-    (V, dec), _ = jax.lax.scan(body, (V0, dec0), xs)
-    return V[:, :S, :C], dec.reshape(B, W, Sp, Cp)[:, :, :S, :C]
-
 
 
 def _dp_forward_blocked(
@@ -1154,14 +947,23 @@ def _dp_forward_blocked(
     return V[:S, :C], dec[:, :S, :C]
 
 
-def _check_tiling(block_s, block_c, u_max: int, off_max: int, interpret: bool) -> None:
-    """Halo floors (always) and vreg-tile alignment (compiled mode)."""
-    if block_s is not None and block_c is None:
-        raise ValueError(
-            "block_s tiles the budget axis of the blocked pipeline and "
-            "needs block_c (pass block_c=C for a single full-width tile)")
+def _check_tiling(
+    block_e, block_s, block_c, u_max: int, off_max: int, interpret: bool
+) -> None:
+    """A plane tiling for ``block_e``/``block_s``, the fused chunk's word
+    bound, the halo floors (always) and vreg-tile alignment (compiled
+    mode)."""
     if block_c is None:
+        if block_e is not None or block_s is not None:
+            raise ValueError(
+                "block_e and block_s tile the blocked pipeline's plane and "
+                "need block_c (pass block_c=C for a single full-width tile)")
         return
+    if block_e is not None and not 1 <= block_e <= MAX_BLOCK_E:
+        raise ValueError(
+            f"block_e={block_e} outside [1, {MAX_BLOCK_E}]: a fused chunk "
+            "packs its decision bits into one int32 word plane, so "
+            "in-chunk bit positions (edge id mod 32) must stay distinct")
     if block_c < off_max:
         raise ValueError(
             f"block_c={block_c} < off_max={off_max}: the offset shift "
@@ -1214,11 +1016,7 @@ def dp_forward_pallas(
     ``interpret=None`` resolves via :func:`resolve_interpret` (compiled on
     TPU, interpreter elsewhere)."""
     interp = resolve_interpret(interpret)
-    if block_e is not None and block_c is None:
-        raise ValueError(
-            "block_e fuses edges into the blocked pipeline's grid and "
-            "needs block_c (pass block_c=C for a single full-width tile)")
-    _check_tiling(block_s, block_c, u_max, off_max, interp)
+    _check_tiling(block_e, block_s, block_c, u_max, off_max, interp)
     sigma2, v0 = sigma2.astype(jnp.int32), v0.astype(jnp.int32)
     if block_c is not None:
         if block_e is not None:
@@ -1258,20 +1056,24 @@ def dp_forward_pallas_batched(
     block_s: int | None = None,
     block_e: int | None = None,
 ):
-    """B independent DP forwards in ONE pallas_call.
+    """B independent DP forwards against SHARED tables.
 
     upsilon/sigma2/allowed: (B, E); ``feasible`` (E, C) and ``offsets``
-    (E,) are SHARED across the batch — per-instance eligibility rides the
-    (B, E) ``allowed`` rows and multiplies into the feasibility mask
-    INSIDE the kernel, so the plane is never replicated per instance.
-    Returns ``(V (B, S, C) i32, decisions (B, ⌈E/32⌉, S, C) i32)``.
+    (E,) are shared across the batch.  Returns ``(V (B, S, C) i32,
+    decisions (B, ⌈E/32⌉, S, C) i32)``.
 
-    ``block_b`` instances advance per grid step (default: the whole
-    batch in one step); ragged batches (B not a multiple of block_b) pad
-    with inert ``allowed ≡ 0`` instances whose outputs are dropped.  With
-    a plane tiling (``block_c`` + ``block_e``) the batch becomes the
-    outermost grid dimension of the edge-fused pipeline and ``block_b``
-    must be 1.  ``choose_tiling(..., batch=B)`` picks all four."""
+    On the whole-plane kernel (``block_c=None``) the batch rides ONE
+    pallas_call's grid: ``block_b`` instances advance per grid step
+    (default: the whole batch in one step), per-instance eligibility rides
+    the (B, E) ``allowed`` rows and multiplies into the feasibility mask
+    INSIDE the kernel, so the plane is never replicated per instance, and
+    ragged batches (B not a multiple of block_b) pad with inert ``allowed
+    ≡ 0`` instances whose outputs are dropped.  With a plane tiling
+    (``block_c``, fused or per-edge) the instances run one after another
+    through :func:`dp_forward_pallas` on that tiling (``jax.lax.map``),
+    each folding its ``allowed`` row into the feasibility plane, and
+    ``block_b`` must be 1.  ``choose_tiling(..., batch=B)`` picks all
+    four."""
     interp = resolve_interpret(interpret)
     B = upsilon.shape[0]
     bb = B if block_b is None else block_b
@@ -1280,30 +1082,24 @@ def dp_forward_pallas_batched(
             f"block_b={bb} outside [1, {B}]: the batch grid advances "
             "block_b instances per step and cannot exceed the batch")
     allowed = jnp.asarray(allowed, jnp.int32)
-    sigma2, v0 = sigma2.astype(jnp.int32), v0.astype(jnp.int32)
-    if block_e is not None and block_c is None:
-        raise ValueError(
-            "block_e fuses edges into the blocked pipeline's grid and "
-            "needs block_c (pass block_c=C for a single full-width tile)")
-    _check_tiling(block_s, block_c, u_max, off_max, interp)
     if block_c is not None:
-        if block_e is None:
-            raise ValueError(
-                "batched dispatch supports the whole-plane kernel "
-                "(block_c=None) and the edge-fused pipeline (block_e "
-                "set); the per-edge-scan pipelines re-stream the plane "
-                "once per edge and gain nothing from sharing a launch — "
-                "run those instances sequentially instead")
         if bb != 1:
             raise ValueError(
-                f"block_b={bb}: the fused pipeline batches as the "
-                "outermost grid dimension with one instance per grid "
-                "step (block_b=1) — the per-instance halo histories are "
-                "what overflowed the VMEM budget in the first place")
-        return _dp_forward_fused_batched(
-            upsilon, sigma2, allowed, feasible, offsets, v0,
-            n_edges=n_edges, u_max=u_max, off_max=off_max, block_e=block_e,
-            block_s=block_s, block_c=block_c, interpret=interp)
+                f"block_b={bb}: a fleet of tiled planes runs one instance "
+                "at a time (block_b=1) — a tiled plane is one that "
+                "overflowed the VMEM budget in the first place")
+
+        def one(x):
+            ups, sig, alw = x
+            return dp_forward_pallas(
+                ups, sig, feasible * alw.astype(jnp.float32)[:, None],
+                offsets, v0, n_edges=n_edges, u_max=u_max, off_max=off_max,
+                interpret=interp, block_c=block_c, block_s=block_s,
+                block_e=block_e)
+
+        return jax.lax.map(one, (upsilon, sigma2, allowed))
+    _check_tiling(block_e, block_s, None, u_max, off_max, interp)
+    sigma2, v0 = sigma2.astype(jnp.int32), v0.astype(jnp.int32)
     return _whole_plane(upsilon, sigma2, allowed, feasible, offsets, v0,
                         n_edges=n_edges, u_max=u_max, off_max=off_max,
                         block_b=bb, interpret=interp)

@@ -23,7 +23,8 @@ Operand contract (what makes this usable from the hot path):
   * operands are prepared with HOST numpy so repeated traces never leak a
     tracer; ``prepare_tables`` is a cheap pure function of the tables;
   * the whole wrapper is vmap-safe AND batch-aware: a ``custom_vmap``
-    rule on the solve core dispatches every mapped instance through ONE
+    rule on the solve core dispatches every mapped instance of a whole
+    plane through ONE
     :func:`repro.kernels.budgeted_dp.kernel.dp_forward_pallas_batched`
     launch — ``simulate_batch``/``simulate_grid`` mapping it over seed
     batches get one fleet-batched kernel per slot instead of B replicated
@@ -31,7 +32,9 @@ Operand contract (what makes this usable from the hot path):
     constant (per-instance eligibility multiplies into the mask inside
     the kernel, never folded into B feasibility copies), and
     ``prepare_tables`` derives the host operands exactly once per tables
-    object (identity-cached).  :func:`solve_budgeted_dp_batched` is the
+    object (identity-cached).  A fleet of tiled planes runs its instances
+    one after another through the single solve.
+    :func:`solve_budgeted_dp_batched` is the
     explicit batched entry point for callers that already hold stacked
     (B, E) statistics;
   * decisions come back packed (⌈E/32⌉, S, C) int32 — 32× less memory than
@@ -205,6 +208,49 @@ def _check_u_max(upsilon, u_max: int) -> None:
             "schedules) or leave u_max=None.")
 
 
+def _select_backtrack(V, decisions, upsilon, offsets, s_limit, full_state, words=None):
+    """The eq.-17 s* rule on the forward plane, then the backtrack.
+
+    ``V`` (S, C) and ``decisions`` (words, S, C) are one solve's forward
+    outputs; returns ``(x (E,), s*, value row (S,))``.  The backtrack runs
+    on offset arithmetic from (s*, ``full_state``): the per-edge constants
+    (Υ̂, offset, word row, bit) stream in as scan inputs, so the loop body
+    is scalar arithmetic plus ONE 1-element dynamic slice of the packed
+    words — no per-element gathers from (E, C) transition tables.
+    ``words`` is each edge's (word row, bit) in ``decisions``; ``None``
+    is the kernel's packing, word e // 32 and bit e % 32."""
+    with jax.named_scope("esdp.select"):
+        v_row = V[:, full_state]
+        s_vals = jnp.arange(V.shape[0], dtype=jnp.int32)
+        # feasible ⇔ value ≥ 0: Σ̂² ≥ 0 so reachable values are
+        # non-negative, while NEG-seeded chains stay < 0 for any partial
+        # sum < 2²⁹ (the VALUE_BOUND contract) — the rule of core.dp
+        ok = (v_row >= 0) & (s_vals <= s_limit)
+        score = s_vals.astype(jnp.float32) + jnp.sqrt(
+            jnp.maximum(v_row, 0).astype(jnp.float32))
+        s_star = jnp.argmax(jnp.where(ok, score, -jnp.inf)).astype(
+            jnp.int32)
+
+    if words is None:
+        e_ids = jnp.arange(upsilon.shape[0], dtype=jnp.int32)
+        words = (e_ids // 32, e_ids % 32)
+
+    def back(carry, x):
+        s, cs = carry
+        u, off, w, b = x
+        word = jax.lax.dynamic_slice(decisions, (w, s, cs), (1, 1, 1))
+        d = (word[0, 0, 0] >> b) & 1
+        taken = d > 0
+        s = jnp.where(taken, jnp.maximum(s - u, 0), s)
+        cs = jnp.where(taken, cs - off, cs)
+        return (s, cs), d
+
+    with jax.named_scope("esdp.backtrack"):
+        (_, _), x = jax.lax.scan(
+            back, (s_star, jnp.int32(full_state)), (upsilon, offsets, *words))
+    return x, s_star, v_row
+
+
 @functools.partial(jax.jit,
                    static_argnames=("s_cap", "u_max", "off_max", "full_state",
                                     "interpret", "block_c", "block_s",
@@ -226,7 +272,6 @@ def _solve(
     block_e: int | None,
 ):
     E = upsilon.shape[0]
-    S = s_cap + 1
     v0 = core_dp.initial_plane(s_cap, feasible.shape[1])
 
     with jax.named_scope("esdp.forward"):
@@ -235,39 +280,8 @@ def _solve(
             n_edges=E, u_max=u_max, off_max=off_max, interpret=interpret,
             block_c=block_c, block_s=block_s, block_e=block_e)
 
-    with jax.named_scope("esdp.select"):
-        v_row = V[:, full_state]
-        s_vals = jnp.arange(S, dtype=jnp.int32)
-        # feasible ⇔ value ≥ 0: Σ̂² ≥ 0 so reachable values are
-        # non-negative, while NEG-seeded chains stay < 0 for any partial
-        # sum < 2²⁹ (the VALUE_BOUND contract) — the rule of core.dp
-        ok = (v_row >= 0) & (s_vals <= s_limit)
-        score = s_vals.astype(jnp.float32) + jnp.sqrt(
-            jnp.maximum(v_row, 0).astype(jnp.float32))
-        s_star = jnp.argmax(jnp.where(ok, score, -jnp.inf)).astype(
-            jnp.int32)
-
-    # backtrack on offset arithmetic: the per-edge constants (Υ̂, offset,
-    # word id, bit id) stream in as scan inputs, so the loop body is scalar
-    # arithmetic plus ONE 1-element dynamic slice of the packed words — no
-    # per-element gathers from (E, C) transition tables
-    e_ids = jnp.arange(E, dtype=jnp.int32)
-
-    def back(carry, x):
-        s, cs = carry
-        u, off, w, b = x
-        word = jax.lax.dynamic_slice(decisions, (w, s, cs), (1, 1, 1))
-        d = (word[0, 0, 0] >> b) & 1
-        taken = d > 0
-        s = jnp.where(taken, jnp.maximum(s - u, 0), s)
-        cs = jnp.where(taken, cs - off, cs)
-        return (s, cs), d
-
-    with jax.named_scope("esdp.backtrack"):
-        (_, _), x = jax.lax.scan(
-            back, (s_star, jnp.int32(full_state)),
-            (upsilon, offsets, e_ids // 32, e_ids % 32))
-    return x, s_star, v_row
+    return _select_backtrack(V, decisions, upsilon, offsets, s_limit,
+                             full_state)
 
 
 @functools.partial(jax.jit,
@@ -292,15 +306,14 @@ def _solve_batched(
     block_s: int | None,
     block_e: int | None,
 ):
-    """Batched :func:`_solve`: B solves through ONE kernel launch.
+    """Batched :func:`_solve`: B solves through one batched forward.
 
     upsilon/sigma2/allowed are (B, E), ``s_limit`` is (B,); the tables
-    operands stay SHARED (unbatched).  The eq.-17 selection runs across
-    the batch axis, and the backtrack scans all B walks in lockstep —
-    per-edge constants stream once, each step reads one 1-element slice
-    of each instance's packed-decision words."""
-    B, E = upsilon.shape
-    S = s_cap + 1
+    operands stay SHARED (unbatched).  The eq.-17 selection and the
+    backtrack are :func:`_select_backtrack` under ``jax.vmap``: the walks
+    run in lockstep, each step reading one 1-element slice of each
+    instance's packed-decision words."""
+    E = upsilon.shape[1]
     v0 = core_dp.initial_plane(s_cap, feasible.shape[1])
 
     with jax.named_scope("esdp.forward"):
@@ -310,34 +323,10 @@ def _solve_batched(
             block_b=block_b, block_c=block_c, block_s=block_s,
             block_e=block_e)
 
-    with jax.named_scope("esdp.select"):
-        v_row = V[:, :, full_state]  # (B, S)
-        s_vals = jnp.arange(S, dtype=jnp.int32)
-        ok = (v_row >= 0) & (s_vals[None, :] <= s_limit[:, None])
-        score = (s_vals[None, :].astype(jnp.float32)
-                 + jnp.sqrt(jnp.maximum(v_row, 0).astype(jnp.float32)))
-        s_star = jnp.argmax(jnp.where(ok, score, -jnp.inf),
-                            axis=1).astype(jnp.int32)
-
-    e_ids = jnp.arange(E, dtype=jnp.int32)
-
-    def back(carry, x):
-        s, cs = carry  # (B,) each
-        u, off, w, b = x  # u (B,); rest scalar
-        word = jax.vmap(
-            lambda d, s_, c_: jax.lax.dynamic_slice(
-                d, (w, s_, c_), (1, 1, 1))[0, 0, 0])(decisions, s, cs)
-        d = (word >> b) & 1
-        taken = d > 0
-        s = jnp.where(taken, jnp.maximum(s - u, 0), s)
-        cs = jnp.where(taken, cs - off, cs)
-        return (s, cs), d
-
-    with jax.named_scope("esdp.backtrack"):
-        (_, _), x = jax.lax.scan(
-            back, (s_star, jnp.full((B,), full_state, jnp.int32)),
-            (upsilon.T, offsets, e_ids // 32, e_ids % 32))
-    return x.T, s_star, v_row
+    return jax.vmap(
+        lambda V_, d, u, sl: _select_backtrack(V_, d, u, offsets, sl,
+                                               full_state))(
+        V, decisions, upsilon, s_limit)
 
 
 @functools.lru_cache(maxsize=None)
@@ -350,22 +339,22 @@ def _vmappable_core(
     block_c,
     block_s,
     block_e,
-    auto_tiling: bool,
     n_edges: int,
     n_states: int,
 ):
     """The solve core for one static kernel config, with a custom vmap rule.
 
     The single-instance path folds ``allowed`` into the feasibility plane
-    and runs :func:`_solve` exactly as before.  Under ``jax.vmap`` the
-    rule fires instead and routes ALL mapped instances through ONE
-    :func:`dp_forward_pallas_batched` launch: the shared (E, C)
-    feasibility plane stays an unbatched constant (vmapping the fold
-    would materialize B per-instance copies of it), per-instance
-    eligibility rides the (B, E) ``allowed`` rows, and when the tiling is
-    auto it re-resolves for the batch via ``choose_tiling(batch=B)``.
-    Cached per static config so repeated solver calls reuse one
-    ``custom_vmap`` object and its jit traces."""
+    and runs :func:`_solve`.  Under ``jax.vmap`` the rule fires instead.
+    A whole plane routes ALL mapped instances through ONE
+    :func:`dp_forward_pallas_batched` launch (``block_b`` from
+    ``choose_tiling(batch=B)``): the shared (E, C) feasibility plane
+    stays an unbatched constant (vmapping the fold would materialize B
+    per-instance copies of it) and per-instance eligibility rides the
+    (B, E) ``allowed`` rows.  A tiled plane, fused or per-edge, runs the
+    instances one after another through the single solve.  Cached per
+    static config so repeated solver calls reuse one ``custom_vmap``
+    object and its jit traces."""
 
     def plain(upsilon, sigma2, s_limit, allowed, feasible, offsets):
         feas = feasible * allowed.astype(jnp.float32)[:, None]
@@ -397,27 +386,17 @@ def _vmappable_core(
         sl = bcast(s_limit, sl_b)
         alw = bcast(allowed, al_b)
 
-        if auto_tiling:
-            bb, be, bs, bc = choose_tiling(
-                s_cap + 1, n_states, n_edges, u_max, off_max, batch=B)
+        if block_c is not None:
+            outs = jax.lax.map(
+                lambda t: plain(*t, feasible, offsets), (ups, sig, sl, alw))
         else:
-            be, bs, bc = block_e, block_s, block_c
-            if bc is not None and be is None:
-                # a forced per-edge-scan tiling has no batched pipeline
-                # (re-streaming the plane per edge gains nothing from a
-                # shared launch) — run the instances sequentially, one
-                # trace, bit-exact by construction
-                outs = jax.lax.map(
-                    lambda t: plain(t[0], t[1], t[2], t[3], feasible,
-                                    offsets), (ups, sig, sl, alw))
-                return outs, (True, True, True)
-            bb = 1 if bc is not None else choose_tiling(
-                s_cap + 1, n_states, n_edges, u_max, off_max, batch=B)[0]
-        outs = _solve_batched(
-            ups, sig, alw, feasible, offsets, sl,
-            s_cap=s_cap, u_max=u_max, off_max=off_max,
-            full_state=full_state, interpret=interpret, block_b=bb,
-            block_c=bc, block_s=bs, block_e=be)
+            bb = choose_tiling(s_cap + 1, n_states, n_edges, u_max, off_max,
+                               batch=B)[0]
+            outs = _solve_batched(
+                ups, sig, alw, feasible, offsets, sl,
+                s_cap=s_cap, u_max=u_max, off_max=off_max,
+                full_state=full_state, interpret=interpret, block_b=bb,
+                block_c=None, block_s=block_s, block_e=block_e)
         return outs, (True, True, True)
 
     return core
@@ -468,8 +447,9 @@ def solve_budgeted_dp_pallas(
       "value_row"}``, bit-exact vs the reference backend for every tiling.
 
     Under ``jax.vmap`` the solve core's custom batching rule dispatches
-    every mapped instance through ONE batched kernel launch (see
-    :func:`_vmappable_core`) — callers never need to opt in.
+    every mapped instance of a whole plane through ONE batched kernel
+    launch, and maps a tiled plane's instances through the single solve
+    (see :func:`_vmappable_core`) — callers never need to opt in.
     """
     check_value_bound(sigma2, tables)
     feas, offs = prepare_tables(tables)
@@ -478,8 +458,7 @@ def solve_budgeted_dp_pallas(
     _check_u_max(upsilon, int(u_max))
     E = offs.shape[0]
     off_max = int(offs.max()) if E else 0
-    auto = block_c == "auto"
-    if auto:
+    if block_c == "auto":
         if block_s is not None or block_e is not None:
             forced = "block_s" if block_s is not None else "block_e"
             raise ValueError(
@@ -491,8 +470,8 @@ def solve_budgeted_dp_pallas(
             s_cap + 1, tables.n_states, E, int(u_max), off_max)
     core = _vmappable_core(
         s_cap, int(u_max), off_max, tables.full_state,
-        resolve_interpret(interpret), block_c, block_s, block_e, auto,
-        E, tables.n_states)
+        resolve_interpret(interpret), block_c, block_s, block_e, E,
+        tables.n_states)
     alw = (jnp.ones((E,), jnp.int32) if allowed is None
            else jnp.asarray(allowed, jnp.int32))
     x, s_star, v_row = core(
@@ -516,11 +495,12 @@ def solve_budgeted_dp_batched(
     block_s: int | None = None,
     block_e: int | None = None,
 ):
-    """B solves against SHARED tables in ONE kernel launch.
+    """B solves against SHARED tables: ONE kernel launch on a whole
+    plane, the single solve mapped over the instances on a tiled plane.
 
     The explicit batched entry point for callers that already hold
     stacked statistics (``jax.vmap`` of :func:`solve_budgeted_dp_pallas`
-    reaches the same kernel through the custom batching rule).
+    reaches the same forward through the custom batching rule).
 
     Args:
       upsilon, sigma2: (B, E) int32 per-instance statistics.
@@ -699,38 +679,16 @@ class WarmPallasSolver:
 
     def _make_select_back(self):
         offs = jnp.asarray(self._offs)
-        w_rows, bits = jnp.asarray(self._w_rows), jnp.asarray(self._bits)
+        words = (jnp.asarray(self._w_rows), jnp.asarray(self._bits))
         full_state = self.tables.full_state
-        S = self.s_cap + 1
 
         @jax.jit
         def select_back(V, decisions, upsilon, s_limit):
-            v_row = V[:, full_state]
-            s_vals = jnp.arange(S, dtype=jnp.int32)
-            ok = (v_row >= 0) & (s_vals <= s_limit)
-            score = s_vals.astype(jnp.float32) + jnp.sqrt(
-                jnp.maximum(v_row, 0).astype(jnp.float32))
-            s_star = jnp.argmax(jnp.where(ok, score,
-                                          -jnp.inf)).astype(jnp.int32)
-
-            def back(carry, x):
-                s, cs = carry
-                u, off, w, b = x
-                word = jax.lax.dynamic_slice(decisions, (w, s, cs),
-                                             (1, 1, 1))
-                d = (word[0, 0, 0] >> b) & 1
-                taken = d > 0
-                s = jnp.where(taken, jnp.maximum(s - u, 0), s)
-                cs = jnp.where(taken, cs - off, cs)
-                return (s, cs), d
-
-            (_, _), x = jax.lax.scan(
-                back, (s_star, jnp.int32(full_state)),
-                (upsilon, offs, w_rows, bits))
+            x, s_star, v_row = _select_backtrack(
+                V, decisions, upsilon, offs, s_limit, full_state, words)
             # contract sanitization: every budget-infeasible entry reads
             # exactly the sentinel
-            row = jnp.where(v_row >= 0, v_row, core_dp.NEG)
-            return x, s_star, row
+            return x, s_star, jnp.where(v_row >= 0, v_row, core_dp.NEG)
 
         return select_back
 

@@ -669,7 +669,8 @@ class DispatchEngine:
     def run_batch(self, seeds, mode: str = "stream") -> "list[EngineOutput]":
         """One trace per seed, fleet-batched: ONE vmapped jitted scan, so
         batch-aware solver backends collapse each slot's fleet of solves
-        into a single batched kernel launch (the PR 6 dispatch path).
+        into a single batched kernel launch on a whole plane (a tiled
+        plane maps the single solve over the seeds).
         Stream-only; every seed shares the schedule, as in
         ``ClusterSim.run_batch``."""
         if mode != "stream":

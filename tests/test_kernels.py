@@ -12,7 +12,6 @@ from repro.kernels.ssd.ref import ssd_ref
 from repro.core.dp import build_tables, solve_budgeted_dp
 from repro.kernels.budgeted_dp.kernel import (
     MAX_BLOCK_E, NEG, VMEM_BUDGET_BYTES, _chunk_layout,
-    batched_fused_tile_vmem_bytes,
     batched_modeled_hbm_bytes, batched_vmem_bytes,
     c_blocked_tile_vmem_bytes, choose_tiling, dp_forward_pallas,
     dp_forward_pallas_batched, fused_tile_vmem_bytes, modeled_hbm_bytes,
@@ -710,7 +709,7 @@ def test_choose_tiling_batched_decision_table():
     tiles — full fleet per step when it fits, the largest power-of-two
     sub-fleet when it doesn't, and only when even one instance's plane
     overflows does the tiling fall back to the 3-tuple rule with block_b
-    pinned to 1 (batch as the fused pipeline's outermost grid dim)."""
+    pinned to 1 (the instances then map through the single solve)."""
     # paper-default sizes: the whole 32-fleet fits in one grid step
     assert choose_tiling(110, 27, 40, 9, 13, batch=32) == \
         (32, None, None, None)
@@ -736,17 +735,19 @@ def test_choose_tiling_batched_decision_table():
     four = choose_tiling(S, C, E, u_max, off_max, batch=32)
     assert four == (1,) + choose_tiling(S, C, E, u_max, off_max)
     _, be, bs, bc = four
-    assert batched_fused_tile_vmem_bytes(be, bs, bc, u_max, off_max, S, C,
-                                         1) <= VMEM_BUDGET_BYTES
+    assert fused_tile_vmem_bytes(be, bs, bc, u_max, off_max, S, C) <= \
+        VMEM_BUDGET_BYTES
     with pytest.raises(ValueError, match="batch"):
         choose_tiling(110, 27, 40, 9, 13, batch=0)
 
 
 def test_batched_modeled_hbm_shares_tables_once():
-    """The batched traffic model: shared operands stream once, so B
-    batched solves always model strictly under B× the single-solve
-    traffic, and the saving is exactly the (B−1)-fold shared-operand
-    re-stream a vmapped-single-launch lowering would pay."""
+    """The batched traffic model: on the whole-plane kernel the shared
+    operands stream once, so B batched solves always model strictly under
+    B× the single-solve traffic, and the saving is exactly the (B−1)-fold
+    shared-operand re-stream a vmapped-single-launch lowering would pay.
+    A tiled plane maps the single solve, each instance reading its own
+    feasibility blocks: its fleet models exactly B single solves."""
     for (S, C, E, u_max, off_max), (be, bs, bc) in (
             ((110, 27, 40, 9, 13), (None, None, None)),
             ((4096, 512, 16, 4, 73), choose_tiling(4096, 512, 16, 4, 73))):
@@ -755,6 +756,9 @@ def test_batched_modeled_hbm_shares_tables_once():
             batched = batched_modeled_hbm_bytes(S, C, E, u_max, off_max, B,
                                                 be, bs, bc)
             vmapped = B * one
+            if bc is not None:
+                assert batched == vmapped
+                continue
             assert batched < vmapped
             shared = vmapped - batched
             assert shared % (B - 1) == 0  # (B−1) shared re-streams saved
@@ -764,9 +768,9 @@ def test_batched_modeled_hbm_shares_tables_once():
 
 def test_batched_contract_errors():
     """Every illegal batched configuration is a loud ValueError — block_b
-    outside [1, B], a forced block under auto tiling, the fused pipeline
-    with block_b ≠ 1, and the per-edge-scan tilings that gain nothing
-    from sharing a launch — never a silent wrong answer."""
+    outside [1, B], a forced block under auto tiling, a tiled plane with
+    block_b ≠ 1 — never a silent wrong answer; and a per-edge-scan tiling
+    maps bit for bit to the per-instance ``dp_forward_pallas``."""
     A, c, ups1, sig1 = _tiling_problem(seed=23)
     E = len(ups1)
     tables = build_tables(A, c)
@@ -786,14 +790,22 @@ def test_batched_contract_errors():
         with pytest.raises(ValueError, match="block_b"):
             dp_forward_pallas_batched(ups, sig, alw, feas, offs, v0,
                                       block_b=bad_bb, **kwargs)
-    # fused pipeline: batch is the outermost grid dim, one instance/step
+    # a tiled plane runs one instance at a time
     with pytest.raises(ValueError, match="block_b"):
         dp_forward_pallas_batched(ups, sig, alw, feas, offs, v0, block_b=2,
                                   block_c=off_max, block_e=4, **kwargs)
-    # per-edge-scan tilings don't share anything worth batching
-    with pytest.raises(ValueError, match="block_e"):
-        dp_forward_pallas_batched(ups, sig, alw, feas, offs, v0,
-                                  block_c=off_max, **kwargs)
+    # a per-edge-scan tiling maps the single forward over the instances,
+    # each with its own allowed row folded into the feasibility plane
+    alw_mix = jnp.asarray(np.random.default_rng(23).integers(0, 2, (B, E)),
+                          jnp.int32)
+    V, dec = dp_forward_pallas_batched(ups, sig, alw_mix, feas, offs, v0,
+                                       block_b=1, block_c=off_max, **kwargs)
+    for b in range(B):
+        V1, dec1 = dp_forward_pallas(
+            ups[b], sig[b], feas * alw_mix[b][:, None].astype(jnp.float32),
+            offs, v0, block_c=off_max, **kwargs)
+        np.testing.assert_array_equal(np.asarray(V[b]), np.asarray(V1))
+        np.testing.assert_array_equal(np.asarray(dec[b]), np.asarray(dec1))
     with pytest.raises(ValueError, match="block_c"):
         dp_forward_pallas_batched(ups, sig, alw, feas, offs, v0,
                                   block_s=u_max, **kwargs)
@@ -810,10 +822,10 @@ def test_batched_contract_errors():
 @pytest.mark.parametrize("E,block_e", [(60, 8), (40, 5)],
                          ids=["one_word", "two_words"])
 def test_budgeted_dp_fused_batched_merges_owned_words(E, block_e):
-    """The batched fused pipeline merges through the same owned-word
-    helper as the single solve: word-aligned chunks (block_e=8) and chunks
-    straddling a word boundary (block_e=5) both match the oracle per
-    instance, each with its own ``allowed`` row folded into the
+    """A fleet on the fused pipeline maps the single solve, which merges
+    each chunk into the one word it owns: word-aligned chunks (block_e=8)
+    and chunks padded to whole words (block_e=5) both match the oracle
+    per instance, each with its own ``allowed`` row folded into the
     feasibility plane."""
     ups1, sig1, feas, offs, v0 = _fused_problem(61, E)
     off_max, u_max = int(offs.max()), int(ups1.max() + 1)
@@ -844,7 +856,9 @@ def test_fused_chunk_scan_updates_only_owned_words(batched, block_e):
     scalar-prefetched operand is the index of the ONE word the chunk owns:
     block_e=8 divides 32, and block_e=5 (``two_words``: without padding,
     its chunks would straddle two words) pads each word's edges to whole
-    chunks, so the scan runs one chunk per (word, chunk) of that layout."""
+    chunks, so the scan runs one chunk per (word, chunk) of that layout.
+    A fleet (``batched``) maps the single solve: the same chunk scan sits
+    inside a scan over its two instances."""
     E = 72
     ups, sig, feas, offs, v0 = _fused_problem(67, E)
     off_max, u_max = int(offs.max()), int(ups.max() + 1)
@@ -864,6 +878,12 @@ def test_fused_chunk_scan_updates_only_owned_words(batched, block_e):
              and any(b.primitive.name == "pallas_call"
                      for b in e.params["jaxpr"].jaxpr.eqns)]
     assert len(scans) == 1
+    if batched:
+        maps = [e for e in _iter_eqns(jaxpr.jaxpr)
+                if e.primitive.name == "scan" and e.params["length"] == 2
+                and any(b is scans[0]
+                        for b in _iter_eqns(e.params["jaxpr"].jaxpr))]
+        assert len(maps) == 1
     assert scans[0].params["length"] == sum(
         -(-min(32, E - 32 * w) // block_e) for w in range(3))
     body = scans[0].params["jaxpr"].jaxpr

@@ -458,9 +458,9 @@ if HAS_HYPOTHESIS:
         """Random legal 4-tuple (block_b, block_e, block_s, block_c)
         tilings: the whole-plane kernel under every ``block_b`` ∈ [1, B]
         (ragged batches pad with inert instances), and the edge-fused
-        pipeline with the batch as the outermost grid dimension under
-        random block_e / block_s / block_c — all bit-exact vs the
-        per-instance reference loop."""
+        pipeline, mapped over the instances, under random block_e /
+        block_s / block_c — all bit-exact vs the per-instance reference
+        loop."""
         rng = np.random.default_rng(seed)
         E = int(rng.choice([6, 10]))
         K = int(rng.integers(1, 3))
@@ -474,7 +474,7 @@ if HAS_HYPOTHESIS:
         u_max = int(ups.max()) + int(rng.integers(1, 3))
         if rng.integers(0, 2):  # whole-plane, batch-tiled grid
             kw = dict(block_b=int(rng.integers(1, B + 1)), block_c=None)
-        else:  # edge-fused, batch-outermost grid
+        else:  # edge-fused, the single solve mapped over the fleet
             kw = dict(block_c=int(rng.integers(max(off_max, 1), C + 3)),
                       block_e=int(rng.integers(1, 33)),
                       block_s=(None if rng.integers(0, 2) else

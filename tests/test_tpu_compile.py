@@ -70,24 +70,50 @@ def _dp_bench_problem(name, s_cap=None):
     return tables, s_cap, int(ups.max() + 1), int(offs.max())
 
 
-# (regime, dp_bench config, budget-axis override, expected tiling class) —
-# the shapes of chip_smoke.py's kernel phase
+def _fig5_problem(c, T):
+    """Fig. 5's largest graph with ``c`` units of every device type, at
+    horizon ``T``: (tables, s_cap, u_max, off_max) as the engine sizes
+    them."""
+    from repro.core import stats
+    from repro.core.graph import generate_instance
+    inst = generate_instance(seed=1, n_ports=16, n_servers=160,
+                             edge_prob=0.1, c_lo=c, c_hi=c)
+    tables = build_tables(inst.A, inst.c)
+    _, offs = prepare_tables(tables)
+    return (tables, stats.s_cap_for_horizon(T, inst.m),
+            stats.u_max_for_horizon(T, inst.m), int(offs.max()))
+
+
+# (regime, problem, expected tiling class, kernel name) — the shapes of
+# chip_smoke.py's kernel phase, and Fig. 5's largest graph at c = 12 per
+# type and T = 100 (S = 43,345, C = 2,197, u_max = 345), where even a
+# one-edge chunk overflows the VMEM budget and the plane takes the per-edge
+# scan
 REGIMES = [
-    ("whole_plane", "E16_C512", None, "whole"),
-    ("fused_c_blocked", "E16_C4096", 127, "c_blocked"),
-    ("fused_s_tiled", "E16_C512_S4096", None, "s_tiled"),
+    ("whole_plane", lambda: _dp_bench_problem("E16_C512"), "whole",
+     "dp_forward"),
+    ("fused_c_blocked", lambda: _dp_bench_problem("E16_C4096", 127),
+     "c_blocked", "dp_forward_fused"),
+    ("fused_s_tiled", lambda: _dp_bench_problem("E16_C512_S4096"),
+     "s_tiled", "dp_forward_fused"),
+    ("edge_s_tiled", lambda: _fig5_problem(12, 100), "s_tiled_edge",
+     "dp_forward_edge_tiled"),
 ]
 
 
-@pytest.mark.parametrize("regime,name,s_cap,want", REGIMES,
+@pytest.mark.parametrize("regime,problem,want,kernel", REGIMES,
                          ids=[r[0] for r in REGIMES])
-def test_kernel_regime_compiles_for_v5e(one_chip, regime, name, s_cap, want):
-    tables, s_cap, u_max, off_max = _dp_bench_problem(name, s_cap)
+def test_kernel_regime_compiles_for_v5e(one_chip, regime, problem, want, kernel):
+    tables, s_cap, u_max, off_max = problem()
     S, C = s_cap + 1, tables.n_states
     E = int(np.asarray(tables.offsets).shape[0])
     be, bs, bc = choose_tiling(S, C, E, u_max, off_max)
-    got = "whole" if bc is None else "c_blocked" if bs is None else "s_tiled"
-    assert got == want and (want == "whole" or be is not None)
+    got = ("whole" if bc is None else "c_blocked" if bs is None
+           else "s_tiled") + ("_edge" if bc is not None and be is None else "")
+    assert got == want
+    if regime == "edge_s_tiled":
+        assert (S, C, E, u_max) == (43_345, 2_197, 252, 345)
+        assert (be, bs, bc) == (None, 512, 512)
 
     def fwd(ups, sig, feas, offs, v0):
         return dp_forward_pallas(ups, sig, feas, offs, v0, n_edges=E,
@@ -102,7 +128,9 @@ def test_kernel_regime_compiles_for_v5e(one_chip, regime, name, s_cap, want):
         spec((E,), jnp.int32), spec((E,), jnp.int32),
         spec((E, C), jnp.float32), spec((E,), jnp.int32),
         spec((S, C), jnp.int32)).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    kernels = [ln for ln in compiled.as_text().splitlines()
+               if "tpu_custom_call" in ln]
+    assert kernels and all(f"/{kernel}/" in ln for ln in kernels)
 
 
 def _fig6_capacity_deployment():
